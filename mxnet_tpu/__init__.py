@@ -32,9 +32,9 @@ from . import leakcheck  # noqa: F401
 
 leakcheck.install_from_env()
 
-# arm the persistent XLA compilation cache (MXNET_COMPILE_CACHE) before
-# anything can trigger a compile — jax reads the cache dir at compile time,
-# so this must precede the first jitted call anywhere in the process
+# give the persistent XLA compilation cache a directory (runtime.py has the
+# rule) before anything can trigger a compile — jax reads the cache dir at
+# compile time, so this must precede the first jitted call in the process
 from .runtime import init_compile_cache as _init_compile_cache
 
 _init_compile_cache()

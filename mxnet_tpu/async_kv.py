@@ -341,9 +341,12 @@ class AsyncKVClient:
             import jax
             from jax._src import distributed
 
+            if not jax.distributed.is_initialized():
+                raise RuntimeError(
+                    "dist_async needs jax.distributed (use tools/launch.py)")
+            # the coordination service's key-value client has no public
+            # accessor
             client = distributed.global_state.client
-            assert client is not None, \
-                "dist_async needs jax.distributed (use tools/launch.py)"
             if jax.process_index() == 0:
                 self._server = _Server(("0.0.0.0", 0))
                 port = self._server.server_address[1]

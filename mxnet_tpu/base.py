@@ -59,13 +59,13 @@ def dtype_code(dtype):
     return _DTYPE_TO_CODE[np.dtype(dtype)]
 
 
-try:  # private but stable across the jax versions we support; resolved
-    # at import so a relocation fails LOUDLY here instead of silently
-    # disabling every tracer-poisoning guard built on in_user_trace()
+try:  # private (jax has no public spelling); resolved at import so a
+    # relocation fails LOUDLY here instead of silently disabling every
+    # tracer-poisoning guard built on in_user_trace()
     from jax._src.core import trace_state_clean as _trace_state_clean
-except ImportError as _e:  # pragma: no cover - depends on jax version
+except ImportError as _e:  # pragma: no cover
     raise ImportError(
-        "jax._src.core.trace_state_clean moved in this jax version; "
+        "jax._src.core.trace_state_clean moved; "
         "update mxnet_tpu.base.in_user_trace for the new location "
         "(the trace-escape guards in registry/random/SymbolBlock "
         "depend on it): %s" % _e)

@@ -73,13 +73,6 @@ class _Config:
              "in-place memory planning. Donated pre-step buffers are "
              "invalidated; reading one afterwards raises. Set 0 to "
              "fall back to copy-on-step."),
-        Knob("MXNET_COMPILE_CACHE", str, "",
-             "Persistent XLA compilation-cache directory so jitted "
-             "modules survive process restarts (maps onto JAX's "
-             "jax_compilation_cache_dir). '' disables; '1'/'auto' uses "
-             "~/.cache/mxnet_tpu/xla-cache; any other value is the "
-             "directory. Must be set before the first compilation "
-             "(mxnet_tpu arms it at import)."),
         Knob("MXNET_SHAPE_BUCKETS", str, "",
              "Leading-batch-dim bucketing for the io/DataLoader "
              "boundary and FusedTrainStep: pad ragged batches up to the "
@@ -145,13 +138,6 @@ class _Config:
              "bandwidth-utilization gauges are published with zero "
              "device syncs. Costs one extra (non-compiling) trace per "
              "TrackedJit; set 0 to skip."),
-        Knob("MXNET_TELEMETRY_PEAK_FLOPS", float, 197e12,
-             "Accelerator peak FLOP/s the MFU gauges divide by. Default "
-             "is TPU v5e bf16 peak (197 TFLOP/s); set to your part's "
-             "number when running elsewhere."),
-        Knob("MXNET_TELEMETRY_PEAK_HBM_GBS", float, 819.0,
-             "Accelerator peak HBM bandwidth (GB/s) the hbm_util gauge "
-             "divides by. Default is TPU v5e (819 GB/s)."),
         Knob("MXTPU_EXPLAIN_RECOMPILES", str, "record",
              "Recompile flight recorder (docs/OBSERVABILITY.md diagnosis "
              "plane): on every TrackedJit retrace, diff the call "

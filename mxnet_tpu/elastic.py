@@ -453,8 +453,8 @@ def active_watchdog():
 class Watchdog:
     """Hang detector: a daemon thread that calls ``on_stall`` (default:
     ``os._exit(WATCHDOG_EXIT_CODE)``) if ``kick()`` is not called within
-    ``timeout`` seconds.  A wedged XLA collective or a dead tunnel hangs
-    forever without raising — exiting with a distinctive status converts
+    ``timeout`` seconds.  A wedged XLA collective hangs forever
+    without raising — exiting with a distinctive status converts
     the hang into a restartable failure for :func:`supervise`.
 
     ``start()`` on an already-started watchdog raises (a silent double

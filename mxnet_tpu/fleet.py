@@ -654,6 +654,12 @@ class WorkerSupervisor:
     monitor, iterated by ``stop()``/``alive()``/``kill_worker()`` from
     other threads — is guarded by ``_procs_lock``; the lock is never held across
     ``Popen``/``wait`` (snapshot-copy, then block outside it).
+
+    Workers inherit ``env`` (default: this process's environment) and
+    initialise JAX on whatever it shows them.  A chip belongs to one
+    process at a time: a supervisor that has itself used JAX on the chip
+    must spawn workers that do not need it (``JAX_PLATFORMS=cpu`` in
+    ``env``).
     """
 
     def __init__(self, specs, registry=None, service="default",

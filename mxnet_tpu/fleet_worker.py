@@ -1,7 +1,10 @@
-"""Fleet worker process: one device-subset server behind the gateway.
+"""Fleet worker process: one server process behind the gateway.
 
 The cross-process half of the fleet layer (docs/SHARDED_SERVING.md
-"Deployment").  One worker process owns one device subset, builds a
+"Deployment").  One worker process owns the devices its environment
+shows it (nothing here hands it a subset of a host's chips, and a chip
+belongs to one process at a time — see "One process for each chip" in
+that document), builds a
 sharded :class:`~mxnet_tpu.serving.ModelServer` or
 :class:`~mxnet_tpu.generation.GenerationServer` from a ``--builder``
 factory, serves it over a slim stdlib HTTP/JSON endpoint, and publishes

@@ -450,12 +450,11 @@ class GenerationEngine:
     slot count for decode) and :meth:`warm` compiles every member, so the
     steady state never retraces.  Both callables go through
     `dispatch.TrackedJit` — the same ``recompile`` / ``jit_cache_*``
-    counters the rest of the runtime uses — and donate the page arrays on
-    TPU so XLA updates the cache in place in HBM.
+    counters the rest of the runtime uses — and donate the page arrays so
+    XLA updates the cache in place.
     """
 
     def __init__(self, model, params, config=None):
-        import jax
         import jax.numpy as jnp
 
         self._jnp = jnp
@@ -474,13 +473,12 @@ class GenerationEngine:
                                          self.cfg.max_slots)
         self.prefill_chain = _resolve_chain(self.cfg.prefill_buckets,
                                             self.max_seq)
-        # donation makes the HBM page update in-place; on CPU it only
-        # produces copy warnings, so gate it on the backend
-        donate = (1, 2) if jax.default_backend() == "tpu" else ()
+        # donating the page pools makes the cache update in-place; the
+        # same on every backend, so CPU tests run the path the chip runs
         self._prefill_jit = _dispatch.TrackedJit(
-            self._prefill_fn, donate_argnums=donate, label="gen_prefill")
+            self._prefill_fn, donate_argnums=(1, 2), label="gen_prefill")
         self._decode_jit = _dispatch.TrackedJit(
-            self._decode_fn, donate_argnums=donate, label="gen_decode")
+            self._decode_fn, donate_argnums=(1, 2), label="gen_decode")
         # tagged memory accounting (docs/OBSERVABILITY.md): the engine
         # owns the model params and the KV page pool, the two dominant
         # HBM residents of a decode server (weakly held — a collected
@@ -951,13 +949,22 @@ class GenerationServer:
             if rec is None:
                 raise KeyError("unknown or expired migration handle %r"
                                % handle)
-            # capture the current page-array version under the lock; jax
-            # arrays are immutable, so the gather below is race-free even
-            # while the scheduler keeps decoding other streams
-            k_pages, v_pages = self.engine.k_pages, self.engine.v_pages
         pages = [int(p) for p in rec["table"][:rec["n_pages"]]]
-        k_block = np.asarray(k_pages)[:, pages]
-        v_block = np.asarray(v_pages)[:, pages]
+        eng = self.engine
+
+        def gather():
+            idx = eng._jnp.asarray(np.asarray(pages, np.int32))
+            return (np.asarray(eng.k_pages[:, idx]),
+                    np.asarray(eng.v_pages[:, idx]))
+
+        # the page arrays are donated to every prefill/decode call, so a
+        # handle captured here could be deleted before it is read: gather
+        # on the scheduler thread, between iterations.  The host copy is
+        # the only copy from here on, whether or not the gather succeeded
+        try:
+            k_block, v_block = self._run_on_scheduler(gather)
+        finally:
+            eng.allocator.free(pages)
         header = {
             "prompt": [int(t) for t in rec["prompt"]],
             "generated": rec["generated"],
@@ -977,7 +984,6 @@ class GenerationServer:
             "page_size": int(self.engine.page_size),
         }
         blob = pack_kv_blob(header, k_block, v_block)
-        self.engine.allocator.free(pages)
         with self._cv:
             self.stats["migrated_out"] += 1
         _profiler.dispatch_count("gen_migrated_out")
@@ -999,11 +1005,11 @@ class GenerationServer:
         eng = self.engine
         n_pages = int(header["n_pages"])
         shape = k_block.shape
-        want = np.asarray(eng.k_pages).shape
+        want = tuple(eng.k_pages.shape)
         if (int(header["page_size"]) != eng.page_size
                 or shape[0] != want[0] or shape[1] != n_pages
                 or shape[2:] != want[2:]
-                or str(k_block.dtype) != str(np.asarray(eng.k_pages).dtype)):
+                or str(k_block.dtype) != str(eng.k_pages.dtype)):
             raise ValueError(
                 "KV blob: incompatible geometry %s/%s page_size=%s for "
                 "engine %s page_size=%d"

@@ -96,12 +96,9 @@ def select_impl(name):
                 fn, impl = entry["fallback"], "fallback"
         else:
             fn, impl = entry["pallas"], "pallas"
-    try:
-        from ... import telemetry as _telemetry
-        _telemetry.registry().counter(
-            "pallas.select.%s.%s" % (name, impl)).inc()
-    except Exception:
-        pass
+    from ... import telemetry as _telemetry
+    _telemetry.registry().counter(
+        "pallas.select.%s.%s" % (name, impl)).inc()
     return fn, impl
 
 

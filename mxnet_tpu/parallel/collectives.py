@@ -10,38 +10,13 @@ importing lax everywhere.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:  # jax>=0.4.30 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import lax, shard_map
 
 __all__ = ["allreduce", "allgather", "reduce_scatter", "ppermute_shift",
            "all_to_all", "axis_index", "axis_size", "pmean", "broadcast",
            "shard_map"]
-
-
-@functools.wraps(_shard_map)
-def shard_map(*args, **kwargs):
-    # jax renamed check_rep -> check_vma; accept either and translate to
-    # whatever the installed jax understands, so callers can use the
-    # current spelling against older runtimes.
-    try:
-        return _shard_map(*args, **kwargs)
-    except TypeError as e:
-        msg = str(e)
-        if "check_vma" in kwargs and "check_vma" in msg:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-            return _shard_map(*args, **kwargs)
-        if "check_rep" in kwargs and "check_rep" in msg:
-            kwargs["check_vma"] = kwargs.pop("check_rep")
-            return _shard_map(*args, **kwargs)
-        raise
 
 
 def allreduce(x, axis_name, op="sum"):

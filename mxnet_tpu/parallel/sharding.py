@@ -149,14 +149,7 @@ def constraint(x, *spec_entries, mesh=None):
     # this is what lets mesh-aware model code (e.g. transformer blocks
     # with dp/sp/tp activation constraints) run unchanged as a pipeline
     # stage under shard_map
-    try:
-        manual = set(jax.sharding.get_abstract_mesh().manual_axes)
-    except AttributeError:  # older jax: shard_map binds its axes in the
-        try:                # tracer axis env instead
-            from jax._src import core as _core
-            manual = set(_core.get_axis_env().axis_names())
-        except Exception:  # pragma: no cover
-            manual = set()
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
     if manual:
         def strip(e):
             if isinstance(e, (tuple, list)):

@@ -22,10 +22,7 @@ __all__ = ["PallasModule", "PallasKernel", "CudaModule"]
 def _on_tpu():
     import jax
 
-    try:
-        return jax.local_devices()[0].platform != "cpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 class PallasModule:

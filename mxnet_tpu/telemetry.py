@@ -486,18 +486,6 @@ def init_from_env():
 # ---------------------------------------------------------------------------
 # cost-analysis step accounting
 # ---------------------------------------------------------------------------
-def _peak_flops():
-    from .config import config
-
-    return float(config.telemetry_peak_flops)
-
-
-def _peak_hbm_gbs():
-    from .config import config
-
-    return float(config.telemetry_peak_hbm_gbs)
-
-
 class StepAccountant:
     """Live MFU / HBM-bandwidth-utilization / throughput gauges with
     zero device syncs.
@@ -513,9 +501,10 @@ class StepAccountant:
     time into the rate).
 
     Gauges published under ``prefix.``: ``steps_per_sec``,
-    ``items_per_sec``, and — when the cost dict is known — ``mfu``
-    (vs ``MXNET_TELEMETRY_PEAK_FLOPS``), ``hbm_gbs`` and ``hbm_util``
-    (vs ``MXNET_TELEMETRY_PEAK_HBM_GBS``).
+    ``items_per_sec``, and — when the cost dict is known — ``hbm_gbs``
+    plus ``mfu`` and ``hbm_util`` against the device's row of
+    ``runtime.DEVICE_PEAKS``.  A device that is not in that table (the
+    CPU, an unlisted chip) gets neither utilization gauge.
     """
 
     def __init__(self, prefix, reg=None, alpha=0.25):
@@ -523,6 +512,7 @@ class StepAccountant:
         self._reg = reg or registry()
         self._alpha = float(alpha)
         self._cost = None
+        self._peaks = None
         self._last_t = None
         self._ewma_dt = None
 
@@ -530,6 +520,10 @@ class StepAccountant:
         """``{"flops": float, "bytes_accessed": float}`` per execution
         (or None to disable the derived gauges)."""
         self._cost = dict(cost) if cost else None
+        if self._cost:
+            from .runtime import device_peaks
+
+            self._peaks = device_peaks()
         return self
 
     @property
@@ -557,12 +551,15 @@ class StepAccountant:
         if self._cost:
             flops = float(self._cost.get("flops") or 0.0)
             nbytes = float(self._cost.get("bytes_accessed") or 0.0)
-            if flops > 0:
-                g(self.prefix + ".mfu").set(flops * sps / _peak_flops())
+            peaks = self._peaks
+            if flops > 0 and peaks:
+                g(self.prefix + ".mfu").set(
+                    flops * sps / peaks["bf16_flops"])
             if nbytes > 0:
-                gbs = nbytes * sps / 1e9
-                g(self.prefix + ".hbm_gbs").set(gbs)
-                g(self.prefix + ".hbm_util").set(gbs / _peak_hbm_gbs())
+                g(self.prefix + ".hbm_gbs").set(nbytes * sps / 1e9)
+                if peaks:
+                    g(self.prefix + ".hbm_util").set(
+                        nbytes * sps / peaks["hbm_bytes_per_s"])
         return sps
 
 

@@ -15,9 +15,12 @@ Usage (CLI mirrors the reference)::
 ``--platform cpu`` runs the CPU-emulation harness (gloo collectives, for
 tests/CI on one machine — the analogue of the reference's
 ``--launcher local`` ps-lite testing trick, tests/nightly/dist_sync_*).
-On a real TPU pod each host launches its own worker and the TPU runtime
-discovers the coordinator itself; this launcher is then only needed to
-fan out ssh commands, which is out of scope (use gcloud / xpk).
+It is the only way to run more than one local worker: a chip belongs to
+one process at a time and no worker is told which chip is its own, so on a
+TPU host ONE process drives all local chips.  On a real TPU pod each host
+launches its own worker and the TPU runtime discovers the coordinator
+itself; this launcher is then only needed to fan out ssh commands, which
+is out of scope (use gcloud / xpk).
 """
 from __future__ import annotations
 
@@ -45,12 +48,23 @@ def launch_local(num_workers, command, platform=None, local_devices=None,
     by ``mxnet_tpu._dist.init_from_env`` at import), so any script that
     does ``import mxnet_tpu`` becomes a distributed worker unmodified —
     the reference's "launch.py wraps an ordinary training script" contract.
+
+    More than one worker needs the workers on the CPU platform
+    (``platform="cpu"``, or ``JAX_PLATFORMS=cpu`` in their environment):
+    otherwise each would initialise JAX on the same local accelerators.
     """
+    worker_env = {**os.environ, **(env or {})}
+    on_cpu = (platform or worker_env.get("JAX_PLATFORMS", "")) == "cpu"
+    if num_workers > 1 and not on_cpu:
+        raise ValueError(
+            "%d local workers would each initialise JAX on the same local "
+            "accelerator(s), and a chip belongs to one process at a time: "
+            "one process drives all local chips. Pass --platform cpu for "
+            "the multi-worker CPU emulation harness." % num_workers)
     port = port or _free_port()
     procs = []
     for i in range(num_workers):
-        e = dict(os.environ)
-        e.update(env or {})
+        e = dict(worker_env)
         e["MXNET_TPU_COORDINATOR"] = "localhost:%d" % port
         e["MXNET_TPU_NUM_WORKERS"] = str(num_workers)
         e["MXNET_TPU_WORKER_ID"] = str(i)

@@ -344,25 +344,46 @@ def test_no_tree_flatten_in_steady_state():
 
 # ------------------------------------------------- persistent compile cache
 
-def test_persistent_compile_cache_populates(tmp_path):
-    """MXNET_COMPILE_CACHE=dir arms jax's persistent compilation cache at
-    import time; a fresh process writes cache entries a second process can
-    reuse (survives restarts)."""
+def test_compile_cache_placed_by_environment(tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, entries land there and the
+    program sets no directory in code."""
     cache = str(tmp_path / "xla-cache")
     child = (
+        "import os, jax\n"
+        "updates, real = [], jax.config.update\n"
+        "def spy(name, val):\n"
+        "    updates.append(name)\n"
+        "    return real(name, val)\n"
+        "jax.config.update = spy\n"
         "import mxnet_tpu as mx, numpy as np\n"
-        "assert mx.runtime.compile_cache_dir(), 'cache not armed'\n"
+        "assert 'jax_compilation_cache_dir' not in updates, updates\n"
+        "want = os.environ['JAX_COMPILATION_CACHE_DIR']\n"
+        "assert mx.runtime.compile_cache_dir() == want\n"
         "out = (mx.nd.array(np.ones(4, np.float32)) * 3.0).asnumpy()\n"
         "assert out.tolist() == [3.0] * 4\n"
     )
-    env = subprocess_env(MXNET_COMPILE_CACHE=cache)
+    # JAX's own threshold keeps sub-second compiles out of the cache; the
+    # test's program is tiny, so lower it the way JAX offers
+    env = subprocess_env(JAX_COMPILATION_CACHE_DIR=cache,
+                         JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
     r = subprocess.run([sys.executable, "-c", child], env=env,
                        capture_output=True, text=True, timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
-    entries = os.listdir(cache)
-    assert entries, "persistent compile cache wrote no entries"
-    # second process: same computation, cache already populated — still
-    # correct, and the directory is not re-written from scratch
-    r2 = subprocess.run([sys.executable, "-c", child], env=env,
-                       capture_output=True, text=True, timeout=240)
-    assert r2.returncode == 0, r2.stderr[-2000:]
+    assert os.listdir(cache), "persistent compile cache wrote no entries"
+
+
+def test_compile_cache_default_is_fixed_path_in_checkout(monkeypatch):
+    """Where the variable is not set the cache is one fixed directory
+    inside the checkout — not derived from a pid, a time or a temp name."""
+    import jax
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        assert mx.runtime.init_compile_cache() == \
+            os.path.join(repo, ".xla_cache")
+        assert mx.runtime.compile_cache_dir() == \
+            os.path.join(repo, ".xla_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -35,6 +35,10 @@ def test_launch_cli_errors():
         launch.main(["-n", "2", "--launcher", "ssh", "--", "true"])
     with pytest.raises(SystemExit):
         launch.main(["-n", "2"])  # no command
+    # several local workers off the cpu platform would each initialise JAX
+    # on the same chips: refused before anything is spawned
+    with pytest.raises(ValueError, match="one process drives all local"):
+        launch.launch_local(2, ["true"], env={"JAX_PLATFORMS": ""})
 
 
 def test_dist_async_kvstore(tmp_path):

@@ -5,6 +5,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu.config import config, describe
@@ -58,6 +59,19 @@ def test_runtime_features():
     assert not feats.is_enabled("CUDA")  # no CUDA analogue on TPU builds
     names = {f.name for f in mx.runtime.feature_list()}
     assert {"TPU", "OPENCV", "INT8"} <= names
+
+
+def test_explicit_accelerator_context_never_hides_the_device():
+    """``mx.tpu()``/``mx.gpu()`` with no accelerator, or an index past the
+    device count, raises; the default context follows the backend."""
+    for ctx in (mx.Context("tpu", 0), mx.gpu(0), mx.tpu(5)):
+        with pytest.raises(ValueError, match="0 accelerator device"):
+            ctx.jax_device()
+    assert mx.cpu(0).jax_device().platform == "cpu"
+    assert mx.current_context() == mx.cpu(0)
+    # the CPU has no row in the peaks table, so no utilization is derived
+    assert mx.runtime.device_peaks() is None
+    assert "TPU v5 lite" in mx.runtime.DEVICE_PEAKS
 
 
 def test_profiler_autostart_env():

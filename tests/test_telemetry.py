@@ -261,24 +261,33 @@ def test_profiler_ring_buffer_drops(tmp_path):
 # ---------------------------------------------------------------------------
 # step accounting
 # ---------------------------------------------------------------------------
-def test_step_accountant_gauges():
-    reg = MetricsRegistry()
-    acc = telemetry.StepAccountant("t.step", reg=reg, alpha=1.0)
-    acc.set_cost({"flops": 1.0e9, "bytes_accessed": 1.0e8})
-    assert acc.on_step(32) is None    # first call only arms the clock
-    time.sleep(0.02)
-    sps = acc.on_step(32)
-    assert sps and sps > 0
-    g = {n: m.value for n, m in reg.find("t.step.")}
+def test_step_accountant_gauges(monkeypatch):
+    from mxnet_tpu import runtime
+
+    def run(prefix):
+        reg = MetricsRegistry()
+        acc = telemetry.StepAccountant(prefix, reg=reg, alpha=1.0)
+        acc.set_cost({"flops": 1.0e9, "bytes_accessed": 1.0e8})
+        assert acc.on_step(32) is None    # first call only arms the clock
+        time.sleep(0.02)
+        sps = acc.on_step(32)
+        assert sps and sps > 0
+        return reg, sps, {n: m.value for n, m in reg.find(prefix + ".")}
+
+    # the CPU is not in the peaks table: rates, but no utilization at all
+    assert runtime.device_peaks() is None
+    reg, sps, g = run("t.step")
     assert g["t.step.steps_per_sec"] == pytest.approx(sps)
     assert g["t.step.items_per_sec"] == pytest.approx(32 * sps)
-    from mxnet_tpu.config import config
-
-    assert g["t.step.mfu"] == pytest.approx(
-        1.0e9 * sps / float(config.telemetry_peak_flops))
     assert g["t.step.hbm_gbs"] == pytest.approx(1.0e8 * sps / 1e9)
-    assert g["t.step.hbm_util"] == pytest.approx(
-        g["t.step.hbm_gbs"] / float(config.telemetry_peak_hbm_gbs))
+    assert "t.step.mfu" not in g and "t.step.hbm_util" not in g
+    # a device_kind the table lists: utilization against ITS peaks
+    v5e = runtime.DEVICE_PEAKS["TPU v5 lite"]
+    monkeypatch.setattr(runtime, "device_peaks", lambda device=None: v5e)
+    _, sps, g = run("t.v5e")
+    assert g["t.v5e.mfu"] == pytest.approx(1.0e9 * sps / v5e["bf16_flops"])
+    assert g["t.v5e.hbm_util"] == pytest.approx(
+        1.0e8 * sps / v5e["hbm_bytes_per_s"])
     # without a cost dict only the rate gauges publish
     acc2 = telemetry.StepAccountant("t.nocost", reg=reg)
     acc2.on_step()
@@ -340,9 +349,11 @@ def test_trace_ids_roundtrip(tmp_path):
 # bench harness: a timed-out leg must not sink the round
 # ---------------------------------------------------------------------------
 def test_bench_leg_timeout_isolated(tmp_path):
-    """Force the serving leg over budget: the round must still exit 0,
-    print one parseable JSON line, and carry records for the OTHER legs
-    — including the cost-analysis-derived transformer ``mfu``."""
+    """Force the serving leg over budget: the round must still print one
+    parseable JSON line and carry records for the OTHER legs — including
+    the transformer leg's cost-analysis FLOPs — and exit non-zero, because
+    a leg that ran did not end ok.  On the CPU no MFU is published: its
+    ``device_kind`` has no row in the peaks table."""
     partial = str(tmp_path / "partial.jsonl")
     env = subprocess_env(
         BENCH_LEGS="serving,transformer",
@@ -354,28 +365,51 @@ def test_bench_leg_timeout_isolated(tmp_path):
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"), "--quick"],
         capture_output=True, text=True, timeout=280, env=env, cwd=REPO)
-    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.returncode == 1, proc.stderr[-2000:]
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     extra = result["extra"]
     assert extra["serving_status"].startswith("timeout"), extra
     assert extra["transformer_status"] == "ok", extra
-    # the acceptance metric: XLA-cost-analysis MFU in the record
-    assert extra["mfu"] > 0
+    # every record names its device
+    assert extra["platform"] == "cpu" and extra["device_count"] >= 1
+    assert extra["device_kind"]
+    # XLA's cost analysis is in the record; a utilization is not
+    assert extra["transformer_flops_per_step"] > 0
     assert extra["mfu_source"] == "xla_cost_analysis"
+    assert extra["mfu"] is None
+    assert extra["transformer_mfu_vs_v5e_peak"] is None
     assert extra["transformer_train_tokens_per_sec"] > 0
     # incremental flush: both legs on disk, timed-out one marked
     legs = {json.loads(l)["leg"]: json.loads(l)
             for l in open(partial) if l.strip()}
     assert legs["serving"]["status"].startswith("timeout")
     assert legs["transformer"]["status"] == "ok"
-    assert legs["transformer"]["record"]["mfu"] > 0
+    assert legs["transformer"]["platform"] == "cpu"
+    assert legs["transformer"]["record"]["transformer_flops_per_step"] > 0
+
+
+def test_bench_refuses_non_tpu_without_quick(tmp_path):
+    """The round's numbers are device metrics: on a non-TPU backend, with
+    quick mode not asked for by name, bench.py is an error — it still
+    prints its JSON line, naming the device, and measures nothing."""
+    env = subprocess_env(BENCH_PARTIAL_PATH=str(tmp_path / "partial.jsonl"))
+    env.pop("BENCH_QUICK", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")],
+        capture_output=True, text=True, timeout=120, env=env, cwd=REPO)
+    assert proc.returncode == 1, proc.stderr[-2000:]
+    result = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert "found platform 'cpu'" in result["error"], result
+    assert result["extra"]["platform"] == "cpu"
+    assert result["value"] == 0.0
+    assert not [k for k in result["extra"] if k.endswith("_status")]
 
 
 def test_bench_sigterm_still_emits_summary(tmp_path):
     """r05 regression: the driver's kill timer SIGTERMs a mid-flight
     round — bench must still print one parseable JSON summary line and
-    exit promptly within the kill grace, instead of dying silently (r05:
-    rc 124, zero output, `parsed: null`)."""
+    exit promptly (non-zero) within the kill grace, instead of dying
+    silently (r05: rc 124, zero output, `parsed: null`)."""
     import signal
 
     partial = str(tmp_path / "partial.jsonl")
@@ -392,7 +426,8 @@ def test_bench_sigterm_still_emits_summary(tmp_path):
     finally:
         if proc.poll() is None:
             proc.kill()
-    assert proc.returncode == 0, (proc.returncode, err[-2000:])
+    # a round cut short is not a measurement: summary printed, exit non-zero
+    assert proc.returncode == 1, (proc.returncode, err[-2000:])
     result = json.loads(out.strip().splitlines()[-1])
     assert result["extra"].get("budget_exceeded") == "SIGTERM from driver"
 
