@@ -240,8 +240,9 @@ def leg_lm_train(cfg_kw, batch, seq, steps=3, expect_impl="pallas"):
                          for k, v in selected.items()}}
 
 
-def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100),
-                      head_dims=(64, 128), norm_shape=(4, 256, 2048),
+def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100, 1100),
+                      head_dims=(64, 128), cell_shape=(4, 2048, 16, 128),
+                      cross_seqs=(512, 1024), norm_shape=(4, 256, 2048),
                       xent_rows=1024, vocab=32000,
                       mm_shapes=((512, 768, 1024), (100, 70, 200))):
     """Every Pallas kernel (compiled unless ``interpret``) against its lax
@@ -332,8 +333,10 @@ def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100),
     def weighted(w):
         return lambda out: (out.astype(jnp.float32) * w).sum()
 
-    # -- flash attention: causal and not; aligned, ragged and sub-tile
-    # sequences; float32, and the bfloat16 the models feed it ---------------
+    # -- flash attention: causal and not; aligned, ragged (one that the
+    # larger tiles do not divide) and sub-tile sequences; float32, and the
+    # bfloat16 the models feed it, at the benchmark cell's own shape too;
+    # queries and keys of different lengths ---------------------------------
     def attention(causal):
         def kernel(q, k, v):
             return flash_attention(q, k, v, causal=causal,
@@ -342,6 +345,18 @@ def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100),
         def reference(q, k, v):
             return blockwise_attention(q, k, v, causal=causal)
         return kernel, reference
+
+    def dense_reference(causal):
+        # blockwise_attention takes one length; this one takes two, with
+        # the kernels' top-left-aligned causal mask (q_pos >= k_pos)
+        def reference(q, k, v):
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / q.shape[-1] ** 0.5
+            if causal:
+                keep = (jnp.arange(q.shape[1])[:, None]
+                        >= jnp.arange(k.shape[1])[None, :])
+                s = jnp.where(keep, s, -1e30)
+            return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+        return reference
 
     for T in seqs:
         for D in head_dims:
@@ -352,11 +367,21 @@ def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100),
                         % (T, D, causal), *attention(causal), qkv, w,
                         (0, 1, 2), (2e-5, 2e-5), (2e-4, 2e-4))
     T = seqs[0]
-    for D in head_dims:
-        qkv = [rand(i, (2, T, 2, D), jnp.bfloat16) for i in (1, 2, 3)]
-        compare("flash_attention bfloat16 T=%d D=%d" % (T, D),
-                *attention(True), qkv, weighted(rand(4, (2, T, 2, D))),
-                (0, 1, 2))
+    for shape in [(2, T, 2, D) for D in head_dims] + [cell_shape]:
+        qkv = [rand(i, shape, jnp.bfloat16) for i in (1, 2, 3)]
+        compare("flash_attention bfloat16 %s" % "x".join(map(str, shape)),
+                *attention(True), qkv, weighted(rand(4, shape)), (0, 1, 2))
+    Tq, Tk = cross_seqs
+    D = head_dims[-1]
+    for causal in (True, False):
+        for tq, tk in ((Tq, Tk), (Tk, Tq)):
+            compare("flash_attention Tq=%d Tk=%d D=%d causal=%s"
+                    % (tq, tk, D, causal),
+                    attention(causal)[0], dense_reference(causal),
+                    [rand(1, (2, tq, 2, D))]
+                    + [rand(i, (2, tk, 2, D)) for i in (2, 3)],
+                    weighted(rand(4, (2, tq, 2, D))), (0, 1, 2),
+                    (2e-5, 2e-5), (2e-4, 2e-4))
 
     # -- flash_attention_lse: a loss on both outputs -----------------------
     D = head_dims[0]

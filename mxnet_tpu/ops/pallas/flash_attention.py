@@ -7,15 +7,28 @@ flash attention entirely; its kernel corpus lives in
 
 * layout [B, T, H, D] at the API (matching `parallel/ring_attention.py`),
   [B, H, T, D] inside the kernels;
-* grid (B, H, num_q_blocks, num_k_blocks) — the innermost grid dim is
-  sequential on TPU, so f32 VMEM scratch accumulators implement the
-  streaming-softmax recurrence across k blocks exactly like the lax
-  fallback (`blockwise_attention`);
+* grid (B, H, num_q_blocks, num_k_blocks), ``parallel`` x 3 and
+  ``arbitrary`` on the innermost (sequential) axis, so f32 VMEM scratch
+  accumulators implement the streaming-softmax recurrence across k blocks
+  exactly like the lax fallback (`blockwise_attention`);
+* tiles come from the shape (``_choose_tiles``): one tile of
+  ``_round_up(T, 8)`` up to T = 128, else the largest rung of 1024 / 512 /
+  256 / 128 that divides ``_round_up(T, 128)`` and whose working set fits
+  the scoped VMEM the call asks for; ``block_q`` / ``block_k`` override;
+* under ``causal`` a tile wholly above the diagonal is neither fetched (its
+  ``index_map`` clamps to the last needed tile, so no DMA is issued) nor
+  computed (``pl.when``); the mask runs only on tiles the diagonal crosses
+  or that hold key padding;
 * forward saves per-row logsumexp; backward recomputes probabilities from
   (q, k, lse) in two Pallas kernels (dq over k blocks; dk/dv over q blocks)
   — no O(T^2) residuals;
-* f32 scores/accumulators regardless of input dtype (bf16 in, f32 out of the
-  MXU via ``preferred_element_type``);
+* operands reach the MXU in the input's dtype (``p`` and ``ds`` are cast to
+  it; float32 callers keep float32 products), accumulated in f32 via
+  ``preferred_element_type``; scores, softmax statistics, ``lse``,
+  ``delta`` and all accumulators are f32 regardless of input dtype;
+* at trace time the counter ``pallas.flash.tile.<kernel>.<bq>x<bk>`` and the
+  gauge ``pallas.flash.causal_tiles_run_share`` (tiles visited / tiles of
+  the grid, last traced kernel) record the schedule;
 * off-TPU the public entry point falls back to ``blockwise_attention`` (same
   math, pure lax) so the CPU oracle tests in `tests/` exercise identical
   semantics; ``interpret=True`` runs the real kernels through the Pallas
@@ -35,17 +48,150 @@ from .common import _NEG, _round_up, register_impl
 __all__ = ["flash_attention", "flash_self_attention"]
 
 
-def _causal_mask(s, qi, ki, block_q, block_k, kv_len):
-    bq, bk = s.shape
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where((q_pos >= k_pos) & (k_pos < kv_len), s, _NEG)
+# ---------------------------------------------------------------------------
+# the block schedule: how big a tile is, which tiles are visited
+# ---------------------------------------------------------------------------
+
+# tile sides a long sequence may take, largest first.  On the v5e at 4 x 16
+# heads x 2048 x 128, bfloat16, causal, the top rung won or tied every kernel
+# (PERF.md, PR 27): the per-step cost of carrying m, l and the accumulator
+# falls with the tile's width faster than the diagonal's waste grows.
+_LADDER = (1024, 512, 256, 128)
+# scoped VMEM every call asks for (the compiler's default is 16 MiB of the
+# v5e's 128 MiB); a tile's reckoned working set stays under it
+_VMEM_LIMIT = 32 * 1024 * 1024
 
 
-def _pad_mask(s, ki, block_k, kv_len):
-    bq, bk = s.shape
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(k_pos < kv_len, s, _NEG)
+def _padded(T):
+    """Length a sequence of ``T`` runs at: sub-tile sequences one tile of
+    ``_round_up(T, 8)``, longer ones the next multiple of 128."""
+    return _round_up(T, 8) if T <= 128 else _round_up(T, 128)
+
+
+def _working_set(kernel, block_q, block_k, D, itemsize):
+    """Reckoned VMEM bytes of one grid step: the operand and result tiles
+    twice (the pipeline double-buffers them), the float32 score-sized tiles
+    and their casts, the scratch accumulators.  A last dimension under 128
+    occupies 128 lanes."""
+    lanes = _round_up(D, 128)
+    q_tile = block_q * lanes * itemsize
+    k_tile = block_k * lanes * itemsize
+    row = block_q * 128 * 4                    # lse / delta / m / l: (bq, 1)
+    score = block_q * block_k * 4
+    cast = block_q * block_k * itemsize
+    if kernel == "fwd":                        # q k v -> o lse; acc m l; s p
+        return (2 * (2 * q_tile + 2 * k_tile + row)
+                + block_q * lanes * 4 + 2 * row + 2 * score + cast)
+    if kernel == "dq":                         # q k v do lse delta -> dq
+        return (2 * (3 * q_tile + 2 * k_tile + 2 * row)
+                + block_q * lanes * 4 + 3 * score + cast)
+    # dkv: q k v do lse delta -> dk dv; two accumulators; sT/pT dpT dsT
+    return (2 * (2 * q_tile + 4 * k_tile + 2 * row)
+            + 2 * block_k * lanes * 4 + 3 * score + 2 * cast)
+
+
+def _choose_tiles(Tq, Tk, D, itemsize):
+    """``((block_q, block_k) of fwd, of dq, of dkv)`` from what the call can
+    see.  Each side is the largest rung of the ladder that divides the
+    padded length (so nothing pads further than ``_padded``); while the
+    kernel's working set is over ``_VMEM_LIMIT`` the larger side steps down
+    a rung."""
+    def rungs(T_p):
+        if T_p <= 128:
+            return [T_p]
+        return [r for r in _LADDER if T_p % r == 0]
+
+    out = []
+    for kernel in ("fwd", "dq", "dkv"):
+        qs, ks = rungs(_padded(Tq)), rungs(_padded(Tk))
+        while (_working_set(kernel, qs[0], ks[0], D, itemsize) > _VMEM_LIMIT
+               and (len(qs) > 1 or len(ks) > 1)):
+            q_steps = len(qs) > 1 and (qs[0] >= ks[0] or len(ks) == 1)
+            (qs if q_steps else ks).pop(0)
+        out.append((qs[0], ks[0]))
+    return tuple(out)
+
+
+def _row_map(b, h, i, j):
+    """Block index of an operand tiled along the grid's outer axis."""
+    return (b, h, i, 0)
+
+
+def _inner_map(causal, block_q, block_k, n_inner, inner_is_k):
+    """Block index of an operand tiled along the grid's inner (reduction)
+    axis.  Under ``causal`` a step that ``_visit`` skips keeps the index of
+    the nearest tile that is needed, so the pipeline sees an unchanged block
+    and issues no DMA: with k inside, the last k-tile the q-tile needs (the
+    one holding its last row); with q inside, the first q-tile the k-tile
+    needs (the one holding its first column; a k-tile past every query
+    needs none and keeps the last)."""
+    def index_map(b, h, i, j):
+        if causal and inner_is_k:
+            j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+        elif causal:
+            j = jnp.maximum(j, jnp.minimum((i * block_k) // block_q,
+                                           n_inner - 1))
+        return (b, h, j, 0)
+    return index_map
+
+
+def _note_tiles(kernel, block_q, block_k, nq, nk, causal):
+    """Trace-time telemetry: which tile each kernel was built with, and the
+    share of the grid's tiles the last traced call visits."""
+    from ... import telemetry as _telemetry
+    reg = _telemetry.registry()
+    reg.counter("pallas.flash.tile.%s.%dx%d"
+                % (kernel, block_q, block_k)).inc()
+    run = nq * nk
+    if causal:                                 # _visit's own condition
+        run = sum(ki * block_k <= qi * block_q + block_q - 1
+                  for qi in range(nq) for ki in range(nk))
+    reg.gauge("pallas.flash.causal_tiles_run_share").set(run / (nq * nk))
+
+
+def _visit(step, q0, k0, block_q, block_k, causal, kv_len, Tk):
+    """Run ``step(masked)`` for the tile whose first row is ``q0`` and first
+    column ``k0``: not at all where it lies wholly above the causal
+    diagonal, masked where the diagonal crosses it or it holds key padding,
+    bare where it lies wholly below."""
+    pad = None if kv_len == Tk else k0 + block_k > kv_len
+    if causal:
+        run = k0 <= q0 + block_q - 1
+        need = k0 + block_k - 1 > q0
+        if pad is not None:
+            need = need | pad
+        pl.when(run & need)(lambda: step(True))
+        pl.when(run & jnp.logical_not(need))(lambda: step(False))
+    elif pad is None:
+        step(False)
+    else:
+        pl.when(pad)(lambda: step(True))
+        pl.when(jnp.logical_not(pad))(lambda: step(False))
+
+
+def _mask(s, q0, k0, causal, kv_len, k_axis):
+    """``_NEG`` where a key is padding or (``causal``) after its query;
+    ``k_axis`` is the axis of ``s`` that runs over keys."""
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
+    valid = k_pos < kv_len
+    if causal:
+        q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - k_axis)
+        valid = valid & (q_pos >= k_pos)
+    return jnp.where(valid, s, _NEG)
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))                                     # a . b^T
+_NN = ((1,), (0,))                                     # a . b
+
+
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=_VMEM_LIMIT)
 
 
 # ---------------------------------------------------------------------------
@@ -53,7 +199,8 @@ def _pad_mask(s, ki, block_k, kv_len):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                scale, causal, block_q, block_k, kv_len):
+                scale, causal, block_q, block_k, kv_len, Tk):
+    qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -63,30 +210,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                                    # (bq, D)
-    k = k_ref[0, 0]                                    # (bk, D)
-    v = v_ref[0, 0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    qi = pl.program_id(2)
-    if causal:
-        s = _causal_mask(s, qi, ki, block_q, block_k, kv_len)
-    else:
-        s = _pad_mask(s, ki, block_k, kv_len)
+    def step(masked):
+        q = q_ref[0, 0]                                # (bq, D)
+        k = k_ref[0, 0]                                # (bk, D)
+        v = v_ref[0, 0]
+        s = _dot(q, k, _NT) * scale                    # (bq, bk) f32
+        if masked:
+            s = _mask(s, qi * block_q, ki * block_k, causal, kv_len, 1)
+        m_prev = m_ref[:, :1]                          # (bq, 1)
+        l_prev = l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + _dot(p.astype(v.dtype), v, _NN)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    m_prev = m_ref[:, :1]                              # (bq, 1)
-    l_prev = l_ref[:, :1]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                             # (bq, bk) f32
-    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-    pv = jax.lax.dot_general(p, v.astype(jnp.float32),
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha + pv
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    _visit(step, qi * block_q, ki * block_k, block_q, block_k, causal,
+           kv_len, Tk)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -94,29 +236,33 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         # fully-masked rows (padding) have l == 0; emit 0 not nan
         safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_ref[:] / safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:, :1] + jnp.log(jnp.where(l == 0.0, 1.0, l))
+        lse_ref[0, 0] = m_ref[:, :1] + jnp.log(safe)
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k, kv_len, interpret):
+def _fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
+    block_q, block_k = tiles[0]
     nq, nk = Tq // block_q, Tk // block_k
+    _note_tiles("fwd", block_q, block_k, nq, nk, causal)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, kv_len=kv_len)
-    grid = (B, H, nq, nk)
+                               block_q=block_q, block_k=block_k,
+                               kv_len=kv_len, Tk=Tk)
+
+    q_map = _row_map
+    k_map = _inner_map(causal, block_q, block_k, nk, inner_is_k=True)
     call = pl.pallas_call(
         kernel,
         name="flash_fwd",
-        grid=grid,
+        grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, block_q, D), q_map),
+            pl.BlockSpec((1, 1, block_k, D), k_map),
+            pl.BlockSpec((1, 1, block_k, D), k_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, D), q_map),
+            pl.BlockSpec((1, 1, block_q, 1), q_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
@@ -132,6 +278,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, kv_len, interpret):
             bytes_accessed=2 * (B * H * (Tq + 2 * Tk) * D),
             transcendentals=B * H * Tq * Tk),
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )
     with jax.named_scope("flash_fwd"):
         o, lse = call(q, k, v)
@@ -143,7 +290,8 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, kv_len, interpret):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, scale, causal, block_q, block_k, kv_len):
+               acc_ref, *, scale, causal, block_q, block_k, kv_len, Tk):
+    qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -151,28 +299,21 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                                # (bq, 1)
-    delta = delta_ref[0, 0]
+    def step(masked):
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
+        s = _dot(q, k, _NT) * scale
+        if masked:
+            s = _mask(s, qi * block_q, ki * block_k, causal, kv_len, 1)
+        p = jnp.exp(s - lse_ref[0, 0])                 # lse: (bq, 1)
+        dp = _dot(do, v, _NT)
+        ds = p * (dp - delta_ref[0, 0]) * scale
+        acc_ref[:] += _dot(ds.astype(k.dtype), k, _NN)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    qi = pl.program_id(2)
-    if causal:
-        s = _causal_mask(s, qi, ki, block_q, block_k, kv_len)
-    else:
-        s = _pad_mask(s, ki, block_k, kv_len)
-    p = jnp.exp(s - lse)
-    dp = jax.lax.dot_general(do, v.astype(jnp.float32),
-                             (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta) * scale
-    acc_ref[:] += jax.lax.dot_general(ds, k.astype(jnp.float32),
-                                      (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+    _visit(step, qi * block_q, ki * block_k, block_q, block_k, causal,
+           kv_len, Tk)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -181,7 +322,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *,
-                scale, causal, block_q, block_k, kv_len):
+                scale, causal, block_q, block_k, kv_len, Tk):
+    ki = pl.program_id(2)
     qi = pl.program_id(3)
     nq = pl.num_programs(3)
 
@@ -190,34 +332,24 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q = q_ref[0, 0]
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = jnp.transpose(lse_ref[0, 0])                 # (1, bq)
-    delta = jnp.transpose(delta_ref[0, 0])
+    def step(masked):
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
+        lse = jnp.transpose(lse_ref[0, 0])             # (1, bq)
+        delta = jnp.transpose(delta_ref[0, 0])
+        sT = _dot(k, q, _NT) * scale                   # transposed: (bk, bq)
+        if masked:
+            sT = _mask(sT, qi * block_q, ki * block_k, causal, kv_len, 0)
+        pT = jnp.exp(sT - lse)
+        dv_acc[:] += _dot(pT.astype(do.dtype), do, _NN)
+        dpT = _dot(v, do, _NT)
+        dsT = pT * (dpT - delta) * scale
+        dk_acc[:] += _dot(dsT.astype(q.dtype), q, _NN)
 
-    ki = pl.program_id(2)
-    # transposed scores: (bk, bq)
-    sT = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32) * scale
-    bk, bq = sT.shape
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
-    valid = k_pos < kv_len
-    if causal:
-        valid = valid & (q_pos >= k_pos)
-    sT = jnp.where(valid, sT, _NEG)
-    pT = jnp.exp(sT - lse)                             # (bk, bq)
-    dv_acc[:] += jax.lax.dot_general(pT, do, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-    dpT = jax.lax.dot_general(v.astype(jnp.float32), do,
-                              (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    dsT = pT * (dpT - delta) * scale
-    dk_acc[:] += jax.lax.dot_general(dsT, q.astype(jnp.float32),
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
+    _visit(step, qi * block_q, ki * block_k, block_q, block_k, causal,
+           kv_len, Tk)
 
     @pl.when(qi == nq - 1)
     def _():
@@ -225,11 +357,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, kv_len,
-         interpret, dlse=None):
+def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
+         dlse=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    nq, nk = Tq // block_q, Tk // block_k
     # delta_i = rowsum(do_i * o_i) — cheap elementwise, XLA fuses it
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                     keepdims=True)
@@ -240,14 +371,19 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, kv_len,
         # which reuses both kernels unchanged.
         delta = delta - dlse.astype(jnp.float32)
 
-    qspec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0))
-    kspec = pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h, ki, 0))
-    rowq = pl.BlockSpec((1, 1, block_q, 1),
-                        lambda b, h, qi, ki: (b, h, qi, 0))
+    block_q, block_k = tiles[1]
+    nq, nk = Tq // block_q, Tk // block_k
+    _note_tiles("dq", block_q, block_k, nq, nk, causal)
 
+    q_map = _row_map
+    k_map = _inner_map(causal, block_q, block_k, nk, inner_is_k=True)
+    qspec = pl.BlockSpec((1, 1, block_q, D), q_map)
+    kspec = pl.BlockSpec((1, 1, block_k, D), k_map)
+    rowq = pl.BlockSpec((1, 1, block_q, 1), q_map)
     dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, kv_len=kv_len),
+                          block_q=block_q, block_k=block_k, kv_len=kv_len,
+                          Tk=Tk),
         name="flash_dq",
         grid=(B, H, nq, nk),
         in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
@@ -259,18 +395,25 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, kv_len,
             bytes_accessed=4 * B * H * (Tq + Tk) * D,
             transcendentals=B * H * Tq * Tk),
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )
     with jax.named_scope("flash_dq"):
         dq = dq_call(q, k, v, do, lse, delta)[0]
 
     # grid transposed: outer k blocks, inner (sequential) q blocks
-    qspec2 = pl.BlockSpec((1, 1, block_q, D), lambda b, h, ki, qi: (b, h, qi, 0))
-    kspec2 = pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, qi: (b, h, ki, 0))
-    rowq2 = pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b, h, ki, qi: (b, h, qi, 0))
+    block_q, block_k = tiles[2]
+    nq, nk = Tq // block_q, Tk // block_k
+    _note_tiles("dkv", block_q, block_k, nq, nk, causal)
+
+    q_map2 = _inner_map(causal, block_q, block_k, nq, inner_is_k=False)
+    k_map2 = _row_map
+    qspec2 = pl.BlockSpec((1, 1, block_q, D), q_map2)
+    kspec2 = pl.BlockSpec((1, 1, block_k, D), k_map2)
+    rowq2 = pl.BlockSpec((1, 1, block_q, 1), q_map2)
     dkv_call = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, kv_len=kv_len),
+                          block_q=block_q, block_k=block_k, kv_len=kv_len,
+                          Tk=Tk),
         name="flash_dkv",
         grid=(B, H, nk, nq),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2],
@@ -284,6 +427,7 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, kv_len,
             bytes_accessed=4 * B * H * (Tq + 2 * Tk) * D,
             transcendentals=B * H * Tq * Tk),
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )
     with jax.named_scope("flash_dkv"):
         dk, dv = dkv_call(q, k, v, do, lse, delta)
@@ -291,49 +435,81 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, kv_len,
 
 
 # ---------------------------------------------------------------------------
-# custom_vjp wrapper (operates on [B, H, T, D])
+# custom_vjp wrappers (operate on [B, H, T, D])
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, scale, block_q, block_k, kv_len, interpret):
-    o, _ = _fwd(q, k, v, causal, scale, block_q, block_k, kv_len, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, scale, tiles, kv_len, interpret):
+    o, _ = _fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
     return o
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, kv_len, interpret):
-    o, lse = _fwd(q, k, v, causal, scale, block_q, block_k, kv_len, interpret)
+def _flash_fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
+    o, lse = _fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, kv_len, interpret, res, do):
+def _flash_bwd(causal, scale, tiles, kv_len, interpret, res, do):
     q, k, v, o, lse = res
-    return _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, kv_len,
-                interpret)
+    return _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_lse(q, k, v, causal, scale, block_q, block_k, kv_len, interpret):
-    return _fwd(q, k, v, causal, scale, block_q, block_k, kv_len, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse(q, k, v, causal, scale, tiles, kv_len, interpret):
+    return _fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
 
 
-def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, kv_len,
-                   interpret):
-    o, lse = _fwd(q, k, v, causal, scale, block_q, block_k, kv_len, interpret)
+def _flash_lse_fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
+    o, lse = _fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_lse_bwd(causal, scale, block_q, block_k, kv_len, interpret, res,
-                   ct):
+def _flash_lse_bwd(causal, scale, tiles, kv_len, interpret, res, ct):
     q, k, v, o, lse = res
     do, dlse = ct
-    return _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, kv_len,
-                interpret, dlse=dlse)
+    return _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
+                dlse=dlse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+def _attend(q, k, v, causal, scale, block_q, block_k, interpret, with_lse):
+    """What both entry points share: the off-TPU fallback, the tiles, the
+    [B, H, T, D] layout and the padding to whole tiles.  Returns ``(o, lse)``
+    with ``lse`` [B, H, T] or None."""
+    B, T, H, D = q.shape
+    Tk = k.shape[1]
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
+    if interpret is None:
+        interpret = False
+        if jax.default_backend() != "tpu":
+            from ...parallel.ring_attention import blockwise_attention
+            out = blockwise_attention(q, k, v, causal=causal, scale=scale,
+                                      return_lse=with_lse)
+            return out if with_lse else (out, None)
+
+    tiles = tuple((block_q or bq, block_k or bk) for bq, bk in
+                  _choose_tiles(T, Tk, D, jnp.dtype(q.dtype).itemsize))
+    # every kernel's tile divides the largest (rungs of one ladder)
+    pq = _round_up(T, max(bq for bq, _ in tiles)) - T
+    pk = _round_up(Tk, max(bk for _, bk in tiles)) - Tk
+
+    def heads_first(x, pad):                           # -> [B, H, T + pad, D]
+        x = x.transpose(0, 2, 1, 3)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+
+    qt, kt, vt = heads_first(q, pq), heads_first(k, pk), heads_first(v, pk)
+    if with_lse:
+        o, lse = _flash_lse(qt, kt, vt, causal, scale, tiles, Tk, interpret)
+        lse = lse[:, :, :T, 0]
+    else:
+        o, lse = _flash(qt, kt, vt, causal, scale, tiles, Tk, interpret), None
+    return o[:, :, :T].transpose(0, 2, 1, 3), lse
 
 
 def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
@@ -343,36 +519,11 @@ def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
     On TPU runs the Pallas kernels above; elsewhere falls back to the
     numerically-identical lax ``blockwise_attention``.  ``interpret=True``
     forces the kernels through the Pallas interpreter (CPU parity tests).
-    Differentiable via custom VJP (Pallas backward kernels).
+    Differentiable via custom VJP (Pallas backward kernels).  ``block_q`` /
+    ``block_k`` override the tiles ``_choose_tiles`` takes from the shape.
     """
-    B, T, H, D = q.shape
-    Tk = k.shape[1]
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = False
-        if not on_tpu:
-            from ...parallel.ring_attention import blockwise_attention
-            return blockwise_attention(q, k, v, causal=causal, scale=scale)
-
-    block_q = block_q or min(128, _round_up(T, 8))
-    block_k = block_k or min(128, _round_up(Tk, 8))
-    qt = q.transpose(0, 2, 1, 3)                       # [B, H, T, D]
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    pq = _round_up(T, block_q) - T
-    pk = _round_up(Tk, block_k) - Tk
-    if pq:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pq), (0, 0)))
-    if pk:
-        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pk), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pk), (0, 0)))
-    o = _flash(qt, kt, vt, causal, scale, block_q, block_k, Tk,
-               interpret)
-    if pq:
-        o = o[:, :, :T]
-    return o.transpose(0, 2, 1, 3)
+    return _attend(q, k, v, causal, scale, block_q, block_k, interpret,
+                   with_lse=False)[0]
 
 
 def flash_attention_lse(q, k, v, causal=True, scale=None, block_q=None,
@@ -390,35 +541,8 @@ def flash_attention_lse(q, k, v, causal=True, scale=None, block_q=None,
     end-to-end.  Off-TPU falls back to the lax blockwise kernel unless
     ``interpret=True``.
     """
-    B, T, H, D = q.shape
-    Tk = k.shape[1]
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    if interpret is None:
-        interpret = False
-        if jax.default_backend() != "tpu":
-            from ...parallel.ring_attention import blockwise_attention
-            return blockwise_attention(q, k, v, causal=causal, scale=scale,
-                                       return_lse=True)
-
-    block_q = block_q or min(128, _round_up(T, 8))
-    block_k = block_k or min(128, _round_up(Tk, 8))
-    qt = q.transpose(0, 2, 1, 3)                       # [B, H, T, D]
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    pq = _round_up(T, block_q) - T
-    pk = _round_up(Tk, block_k) - Tk
-    if pq:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pq), (0, 0)))
-    if pk:
-        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pk), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pk), (0, 0)))
-    o, lse = _flash_lse(qt, kt, vt, causal, scale, block_q, block_k, Tk,
-                        interpret)
-    if pq:
-        o = o[:, :, :T]
-        lse = lse[:, :, :T]
-    return o.transpose(0, 2, 1, 3), lse[..., 0]
+    return _attend(q, k, v, causal, scale, block_q, block_k, interpret,
+                   with_lse=True)
 
 
 def flash_self_attention(q, k, v, causal=True, batch_axis="dp",

@@ -53,9 +53,10 @@ def test_leg_lm_train_and_multichip_toy(monkeypatch):
 
 def test_leg_kernel_parity_toy():
     out = chip_smoke.leg_kernel_parity(
-        interpret=True, seqs=(24,), head_dims=(8,), norm_shape=(2, 8, 32),
+        interpret=True, seqs=(24,), head_dims=(8,), cell_shape=(2, 48, 2, 8),
+        cross_seqs=(16, 40), norm_shape=(2, 8, 32),
         xent_rows=8, vocab=100, mm_shapes=((40, 24, 72),))
-    assert out["checks"] > 20
+    assert out["checks"] > 40
 
 
 def test_leg_server_toy():

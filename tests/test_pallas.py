@@ -14,6 +14,8 @@ from mxnet_tpu.ops.pallas import (flash_attention, flash_attention_lse,
                                   int8_matmul, int8_matmul_lax, kernel_unit,
                                   select_impl)
 from mxnet_tpu.ops.pallas.flash_attention import _flash  # noqa: F401
+from mxnet_tpu.ops.pallas.flash_attention import (_VMEM_LIMIT, _choose_tiles,
+                                                   _padded, _working_set)
 from mxnet_tpu.ops.pallas.int8_matmul import _int8_matmul_pallas
 from mxnet_tpu.ops.pallas.layers import _rmsnorm_lax, _xent_lax
 from mxnet_tpu.parallel.ring_attention import blockwise_attention
@@ -146,6 +148,155 @@ class TestFlashAttentionLSEGrad:
             q, k, v, causal=True, interpret=True)[0] ** 2).sum())(q)
         np.testing.assert_allclose(np.asarray(g2), np.asarray(g1),
                                    rtol=1e-5, atol=1e-5)
+
+
+def _dense_attention(q, k, v, causal):
+    """Dense float32 oracle that takes Tq != Tk (`blockwise_attention` takes
+    one length), with the kernels' top-left-aligned causal mask.  Returns
+    ``(o, lse)`` like ``flash_attention_lse``."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / q.shape[-1] ** 0.5
+    if causal:
+        keep = (jnp.arange(q.shape[1])[:, None]
+                >= jnp.arange(k.shape[1])[None, :])
+        s = jnp.where(keep, s, -1e30)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v)
+    return o, lse
+
+
+class TestFlashBlockSchedule:
+    """Explicit small tiles, so that several tiles, skipped ones and ragged
+    last ones exist at CPU sizes: forward, lse and gradient against the
+    oracles; the tile chooser as a pure function; the telemetry."""
+
+    # (Tq, Tk, block_q, block_k): block_q != block_k both ways; a ragged T
+    # (100 in 32-tiles: the last tile holds 28 padded keys); Tq != Tk
+    CASES = [(128, 128, 32, 16), (128, 128, 16, 32), (100, 100, 32, 32),
+             (64, 128, 32, 32), (128, 64, 16, 32)]
+
+    @staticmethod
+    def _both(Tq, Tk, bq, bk, causal, dtype=jnp.float32):
+        q = _rand(0, (2, Tq, 2, 32), dtype)
+        k = _rand(1, (2, Tk, 2, 32), dtype)
+        v = _rand(2, (2, Tk, 2, 32), dtype)
+        w = _rand(3, (2, Tq, 2, 32))
+
+        def loss(fn):
+            def f(q, k, v):
+                o, lse = fn(q, k, v)
+                return ((o.astype(jnp.float32) * w).sum()
+                        + jnp.tanh(lse).sum()), (o, lse)
+            return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+        (_, got), got_g = loss(lambda q, k, v: flash_attention_lse(
+            q, k, v, causal=causal, block_q=bq, block_k=bk,
+            interpret=True))(q, k, v)
+        (_, want), want_g = loss(lambda q, k, v: _dense_attention(
+            q, k, v, causal))(*(x.astype(jnp.float32) for x in (q, k, v)))
+        return got, got_g, want, want_g
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("Tq,Tk,bq,bk", CASES)
+    def test_parity_small_tiles(self, Tq, Tk, bq, bk, causal):
+        got, got_g, want, want_g = self._both(Tq, Tk, bq, bk, causal)
+        for a, b, name in zip(got, want, ("o", "lse")):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-5, atol=2e-5, err_msg=name)
+        for a, b, name in zip(got_g, want_g, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4,
+                                       err_msg="d%s mismatch" % name)
+
+    @pytest.mark.parametrize("T", [128, 100, 64])
+    def test_dense_oracle_agrees_with_blockwise(self, T):
+        # the oracle above against the repo's own, where both apply
+        q, k, v = (_rand(i, (2, T, 2, 32)) for i in range(3))
+        o, lse = _dense_attention(q, k, v, True)
+        ref_o, ref_lse = blockwise_attention(q, k, v, causal=True,
+                                             return_lse=True)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(ref_o),
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("Tq,Tk,bq,bk", [CASES[0], CASES[2], CASES[3]])
+    def test_bf16_small_tiles(self, Tq, Tk, bq, bk, causal):
+        # bfloat16 operands reach the MXU as bfloat16 (p and ds are rounded
+        # too): within bfloat16's resolution of the float32 oracle's largest
+        got, got_g, want, want_g = self._both(Tq, Tk, bq, bk, causal,
+                                              jnp.bfloat16)
+        assert got[0].dtype == jnp.bfloat16
+        assert all(g.dtype == jnp.bfloat16 for g in got_g)
+        for a, b in zip(list(got) + list(got_g), list(want) + list(want_g)):
+            b = np.asarray(b, np.float32)
+            np.testing.assert_allclose(np.asarray(a, np.float32), b,
+                                       rtol=0, atol=2e-2 * np.abs(b).max())
+
+    def test_float32_operands_stay_float32(self):
+        # the cast follows the input: no bfloat16 in a float32 call's kernels
+        q = _rand(0, (1, 64, 1, 32))
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q: flash_attention(
+            q, q, q, block_q=32, block_k=32, interpret=True).sum()))(q)
+        assert "bf16" not in str(jaxpr)
+
+    @pytest.mark.parametrize("itemsize", [2, 4])
+    @pytest.mark.parametrize("D", [64, 128])
+    @pytest.mark.parametrize("T", [8, 100, 128, 1000, 1024, 1100, 2048,
+                                   8192])
+    def test_choose_tiles(self, T, D, itemsize):
+        from mxnet_tpu.ops.pallas.common import _round_up
+        padded = _padded(T)
+        assert padded == (_round_up(T, 8) if T <= 128
+                          else _round_up(T, 128))
+        tiles = _choose_tiles(T, T, D, itemsize)
+        assert len(tiles) == 3
+        for kernel, (bq, bk) in zip(("fwd", "dq", "dkv"), tiles):
+            assert padded % bq == 0 and padded % bk == 0, (kernel, bq, bk)
+            assert bq % 8 == 0 and bk % 8 == 0
+            assert _working_set(kernel, bq, bk, D, itemsize) <= _VMEM_LIMIT
+        if T == 2048:
+            # long sequences leave the 128-tile: far fewer grid steps
+            assert all(bq >= 256 and bk >= 256 for bq, bk in tiles)
+
+    def test_choose_tiles_sides_apart(self):
+        # each side from its own length: a sub-tile query block against a
+        # long key sequence, and the other way round
+        for bq, bk in _choose_tiles(100, 2048, 128, 2):
+            assert bq == 104 and 2048 % bk == 0 and bk >= 256
+        for bq, bk in _choose_tiles(1100, 64, 128, 2):
+            assert bq == 128 and bk == 64
+
+    def test_choose_tiles_steps_down_to_fit(self):
+        # float32 at D = 256: the top rung's working set is over the ask for
+        # the kernels that hold three score-sized tiles, not for the forward
+        fwd, dq, dkv = _choose_tiles(2048, 2048, 256, 4)
+        assert fwd == (1024, 1024)
+        assert _working_set("dkv", 1024, 1024, 256, 4) > _VMEM_LIMIT
+        assert dkv != (1024, 1024)
+        for kernel, (bq, bk) in zip(("fwd", "dq", "dkv"), (fwd, dq, dkv)):
+            assert 2048 % bq == 0 and 2048 % bk == 0
+            assert _working_set(kernel, bq, bk, 256, 4) <= _VMEM_LIMIT
+
+    def test_tile_counter_and_run_share_gauge(self):
+        from mxnet_tpu import telemetry
+        reg = telemetry.registry()
+        names = ["pallas.flash.tile.%s.32x32" % k
+                 for k in ("fwd", "dq", "dkv")]
+        before = [reg.counter(n).value for n in names]
+        q = _rand(0, (1, 128, 1, 32))
+        gauge = reg.gauge("pallas.flash.causal_tiles_run_share")
+        # 4 x 4 tiles, causal: 10 of 16 visited, forward and backward alike
+        jax.grad(lambda q: flash_attention(
+            q, q, q, causal=True, block_q=32, block_k=32,
+            interpret=True).sum())(q)
+        assert gauge.value == 10 / 16
+        assert [reg.counter(n).value for n in names] == \
+            [b + 1 for b in before]
+        flash_attention(q, q, q, causal=False, block_q=32, block_k=32,
+                        interpret=True)
+        assert gauge.value == 1.0
 
 
 class TestInt8Matmul:

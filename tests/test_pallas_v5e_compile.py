@@ -1,0 +1,90 @@
+"""The flash kernels through libtpu's real compiler, for a v5e that is
+described and not attached (no chip, nothing runs): what the Pallas
+interpreter cannot refuse — a slice off the tiling, more scoped VMEM than a
+kernel may use — at every rung the tile chooser returns for the shapes the
+repo runs.  One file, and the topology only inside a fixture: one process
+at a time may load the TPU's library."""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # a TPU executable cannot be read back from the persistent cache
+    # without a chip: keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (B, Tq, Tk, H, D, dtype, causal, the tile the chooser must return)
+CASES = [
+    pytest.param(4, 2048, 2048, 16, 128, jnp.bfloat16, True, (1024, 1024),
+                 id="pythia14_train"),
+    pytest.param(4, 2048, 2048, 8, 128, jnp.bfloat16, True, (1024, 1024),
+                 id="pythia69_tp4_shard"),
+    pytest.param(1, 8192, 8192, 16, 64, jnp.bfloat16, True, (1024, 1024),
+                 id="chip_smoke_long_d64"),
+    pytest.param(2, 1024, 1024, 2, 128, jnp.float32, True, (1024, 1024),
+                 id="f32_top_rung"),
+    pytest.param(2, 1000, 1000, 2, 64, jnp.float32, False, (1024, 1024),
+                 id="ragged_1000_d64"),
+    pytest.param(2, 1100, 1100, 2, 128, jnp.float32, True, (128, 128),
+                 id="ragged_1100_tile_does_not_divide"),
+    pytest.param(2, 1536, 1536, 2, 128, jnp.bfloat16, True, (512, 512),
+                 id="rung_512"),
+    pytest.param(2, 768, 768, 2, 128, jnp.bfloat16, True, (256, 256),
+                 id="rung_256"),
+    pytest.param(2, 100, 100, 2, 64, jnp.float32, True, (104, 104),
+                 id="sub_tile_100"),
+    pytest.param(2, 512, 1024, 2, 128, jnp.bfloat16, True, (512, 1024),
+                 id="ring_step_tq_ne_tk"),
+]
+
+
+@pytest.mark.parametrize("B,Tq,Tk,H,D,dtype,causal,tile", CASES)
+def test_flash_kernels_compile_for_v5e(one_chip, B, Tq, Tk, H, D, dtype,
+                                       causal, tile):
+    assert fa._choose_tiles(Tq, Tk, D, jnp.dtype(dtype).itemsize) == \
+        (tile,) * 3
+
+    def fwd_and_grads(q, k, v, do, dlse):
+        # both outputs and both cotangents: the three kernels, and the lse
+        # cotangent's fold into delta
+        out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention_lse(
+            q, k, v, causal=causal, interpret=False), q, k, v)
+        return out, vjp((do, dlse))
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q = spec((B, Tq, H, D), dtype)
+    kv = spec((B, Tk, H, D), dtype)
+    # as chip_smoke.py's parity leg runs them: float32 at `highest` (the
+    # suite's own default), bfloat16 at the default precision the models
+    # use (Mosaic takes no multi-pass precision on bfloat16 operands)
+    with jax.default_matmul_precision(
+            "highest" if dtype == jnp.float32 else "default"):
+        compiled = jax.jit(fwd_and_grads).lower(
+            q, kv, kv, q, spec((B, H, Tq), jnp.float32)).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert name in text, name
