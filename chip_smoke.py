@@ -49,7 +49,19 @@ LM_WIDE = dict(vocab_size=32000, d_model=2048, n_heads=16, n_layers=4,
 LM_LONG = dict(vocab_size=32000, d_model=1024, n_heads=16, n_layers=4,
                d_ff=4096, max_len=8192, dtype="bfloat16", remat=True)
 
-KERNELS = ("flash_attention", "fused_rmsnorm", "fused_softmax_xent")
+# the hybrid state-space LM's layer pattern (one attention layer in fourteen,
+# at index 7) at a quarter of the benchmark cell's widths: the selective-scan
+# kernels forward and backward over 4,096 steps, one multi-query attention
+# layer through flash (1 x 16 x 4096^2 scores are over the dense gate), a
+# gated MLP, a tied head
+LM_HYBRID = dict(vocab_size=16384, d_model=1024, n_heads=16, n_kv_heads=1,
+                 n_layers=14, d_ff=2048, max_len=4096, dtype="bfloat16",
+                 remat=True, mlp="swiglu", tie_embeddings=True,
+                 layer_types=("mamba",) * 7 + ("attention",)
+                 + ("mamba",) * 6)
+
+KERNELS = ("flash_attention", "fused_rmsnorm", "fused_softmax_xent",
+           "selective_scan")
 IMPLS = ("pallas", "sharded", "interpret", "fallback")
 
 
@@ -192,9 +204,10 @@ def leg_trainer(ctx, model_name="resnet50_v1", batch=128, size=224,
 # ---------------------------------------------------------------------------
 # leg B — LM trainer and the kernels
 # ---------------------------------------------------------------------------
-def leg_lm_train(cfg_kw, batch, seq, steps=3, expect_impl="pallas"):
+def leg_lm_train(cfg_kw, batch, seq, steps=3, expect_impl="pallas", lr=1e-2):
     """``jax.jit(make_train_step(model))`` on a fixed batch; checks the loss
-    and which implementation ``select_impl`` gave each kernel."""
+    and which implementation ``select_impl`` gave each kernel (the
+    selective scan only where the model has state-space layers)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -207,7 +220,7 @@ def leg_lm_train(cfg_kw, batch, seq, steps=3, expect_impl="pallas"):
     model = TransformerLM(TransformerConfig(**cfg_kw))
     params = model.init(jax.random.PRNGKey(0))
     velocity = jax.tree_util.tree_map(jnp.zeros_like, params)
-    step = jax.jit(make_train_step(model))
+    step = jax.jit(make_train_step(model, lr=lr))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq + 1), 0,
                                 cfg_kw["vocab_size"])
     x, y = tokens[:, :-1], tokens[:, 1:]
@@ -226,6 +239,9 @@ def leg_lm_train(cfg_kw, batch, seq, steps=3, expect_impl="pallas"):
     _check(losses[-1] < losses[0],
            "LM loss did not fall on the fixed batch: %r" % losses)
     selected = _select_delta(before)
+    if "mamba" not in cfg_kw.get("layer_types", ()):
+        _check(not any(selected.pop("selective_scan").values()),
+               "selective_scan selected by a model with no state-space layer")
     for kernel, by_impl in selected.items():
         others = {i: n for i, n in by_impl.items()
                   if i != expect_impl and n}
@@ -244,7 +260,9 @@ def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100, 1100),
                       head_dims=(64, 128), cell_shape=(4, 2048, 16, 128),
                       cross_seqs=(512, 1024), norm_shape=(4, 256, 2048),
                       xent_rows=1024, vocab=32000,
-                      mm_shapes=((512, 768, 1024), (100, 70, 200))):
+                      mm_shapes=((512, 768, 1024), (100, 70, 200)),
+                      scan_shapes=((2, 300, 256, 16), (1, 1100, 640, 16)),
+                      scan_cell_shape=(1, 4096, 5120, 16)):
     """Every Pallas kernel (compiled unless ``interpret``) against its lax
     reference on the same device, forward and gradient.
 
@@ -269,6 +287,7 @@ def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100, 1100),
                                                    int8_matmul_lax)
     from mxnet_tpu.ops.pallas.layers import (fused_rmsnorm,
                                               fused_softmax_xent)
+    from mxnet_tpu.ops.pallas.selective_scan import selective_scan
     from mxnet_tpu.parallel.ring_attention import blockwise_attention
     from mxnet_tpu.test_utils import _device_tolerance_floor
 
@@ -429,6 +448,41 @@ def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100, 1100),
                          jnp.float32),
              jnp.asarray(rng.randint(0, vocab, (xent_rows,)), jnp.int32)],
             lambda out: (out * g).sum(), (0,), (1e-5, 1e-5), (1e-4, 1e-5))
+
+    # -- selective_scan: against a step-by-step lax.scan from a zero state,
+    # every gradient (u, delta, A, B, C, D, z); lengths the chunk does not
+    # divide, several chunks (the carried state and the reverse pass), and
+    # the benchmark cell's own shape in the types the model feeds it --------
+    def scan_reference(u, delta, A, B, C, D, z):
+        def step(h, xs):
+            ut, dt, bt, ct = xs
+            h = (jnp.exp(dt[..., None] * A) * h
+                 + (dt * ut)[..., None] * bt[:, None, :])
+            return h, jnp.einsum("bdn,bn->bd", h, ct) + D * ut
+        h0 = jnp.zeros((u.shape[0],) + A.shape, jnp.float32)
+        y = jax.lax.scan(step, h0, tuple(
+            x.transpose(1, 0, 2) for x in (u, delta, B, C)))[1]
+        return y.transpose(1, 0, 2) * z * jax.nn.sigmoid(z)
+
+    def scan_args(Bt, T, Di, N, dtype):
+        wide, narrow = (Bt, T, Di), (Bt, T, N)
+        return [rand(11, wide, dtype),
+                jax.nn.softplus(rand(12, wide) - 2.0),
+                -jnp.exp(0.5 * rand(13, (Di, N))),
+                rand(14, narrow, dtype), rand(15, narrow, dtype),
+                rand(16, (Di,)), rand(17, wide, dtype)]
+
+    for shape in scan_shapes:
+        compare("selective_scan %s" % "x".join(map(str, shape)),
+                lambda *a: selective_scan(*a, interpret=interpret),
+                scan_reference, scan_args(*shape, jnp.float32),
+                weighted(rand(18, shape[:3])), tuple(range(7)),
+                (2e-5, 2e-5), (2e-4, 2e-4))
+    compare("selective_scan bfloat16 %s"
+            % "x".join(map(str, scan_cell_shape)),
+            lambda *a: selective_scan(*a, interpret=interpret),
+            scan_reference, scan_args(*scan_cell_shape, jnp.bfloat16),
+            weighted(rand(18, scan_cell_shape[:3])), tuple(range(7)))
 
     # -- int8_matmul: int32 path bit-identical, fused dequant close --------
     for M, N, K in mm_shapes:
@@ -670,6 +724,11 @@ def main():
         run("A_trainer", leg_trainer, mx.tpu())
         wide = run("B_lm_wide", leg_lm_train, LM_WIDE, batch=8, seq=1024)
         run("B_lm_long", leg_lm_train, LM_LONG, batch=1, seq=8192)
+        # a fifth of the other legs' step: from a random start the mixers'
+        # x_proj gradient is some forty times the other leaves', and at
+        # 1e-2 with momentum the second step already overshoots
+        run("B_lm_hybrid", leg_lm_train, LM_HYBRID, batch=1, seq=4096,
+            lr=2e-3)
         run("B_kernel_parity", leg_kernel_parity)
         run("C_server", leg_server, LM_WIDE)
         run("C_server_reference", leg_server_reference, LM_WIDE)

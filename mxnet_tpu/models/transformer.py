@@ -61,10 +61,63 @@ class TransformerConfig:
     # O(T).  The gate is the f32 score-tensor size B*H*T^2*4 bytes —
     # gating on T alone would let large batches OOM.
     dense_attn_max_score_mb: int = 768
+    # Key/value heads shared by groups of query heads (multi-query: 1).
+    # 0 means as many as ``n_heads``.  Training path only: the paged pool
+    # and the MXKV blob still take equal head counts (ROADMAP R-m2).
+    n_kv_heads: int = 0
+    # "gelu": down(gelu(up(h))); "swiglu": down(silu(gate(h)) * up(h))
+    mlp: str = "gelu"
+    # logits = x . embed^T, no ``unembed`` leaf
+    tie_embeddings: bool = False
+    # One entry a layer, "attention" or "mamba" (a Mamba-1 mixer,
+    # `models/mamba.py`, in the attention half's place); () is attention
+    # everywhere.  Leaves every layer has stay under ``blocks.``; the
+    # mixers' own stack under ``attn.`` and ``ssm.``.
+    layer_types: tuple = ()
+    ssm_expand: int = 2
+    ssm_state: int = 16
+    ssm_dt_rank: int = 0                  # 0: ceil(d_model / 16)
+    ssm_conv: int = 4
+
+    def __post_init__(self):
+        # a configuration read from JSON brings a list
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if not self.ssm_dt_rank:
+            object.__setattr__(self, "ssm_dt_rank", -(-self.d_model // 16))
+        if self.layer_types:
+            assert len(self.layer_types) == self.n_layers, \
+                "layer_types names %d layers, n_layers is %d" % (
+                    len(self.layer_types), self.n_layers)
+            assert set(self.layer_types) <= {"attention", "mamba"}
+            assert not self.use_moe, "layer_types with MoE: not built"
+        assert self.mlp in ("gelu", "swiglu")
+        assert self.n_heads % self.kv_heads == 0
 
     @property
     def head_dim(self):
         return self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self):
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def d_inner(self):
+        return self.ssm_expand * self.d_model
+
+    def layer_runs(self):
+        """``[(kind, lo, hi, kind_lo, kind_hi)]``: maximal runs of layers of
+        one kind, as ranges over all layers and over the layers of that
+        kind (the index into the ``attn.`` / ``ssm.`` stacks)."""
+        runs, seen = [], {"attention": 0, "mamba": 0}
+        for i, kind in enumerate(self.layer_types):
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] = i + 1
+                runs[-1][4] = seen[kind] + 1
+            else:
+                runs.append([kind, i, i + 1, seen[kind], seen[kind] + 1])
+            seen[kind] += 1
+        return [tuple(r) for r in runs]
 
 
 def default_rules() -> ShardingRules:
@@ -76,6 +129,7 @@ def default_rules() -> ShardingRules:
         (r".*wqkv",       P(None, "fsdp", "tp")),
         (r".*wo",         P(None, "tp", "fsdp")),
         (r".*w_up",       P(None, "fsdp", "tp")),
+        (r".*w_gate",     P(None, "fsdp", "tp")),
         (r".*w_down",     P(None, "tp", "fsdp")),
         (r".*moe_up",     P(None, "ep", "fsdp", None)),
         (r".*moe_down",   P(None, "ep", None, "fsdp")),
@@ -116,8 +170,12 @@ class TransformerLM:
         cfg = self.cfg
         L, E, F = cfg.n_layers, cfg.d_model, cfg.d_ff
         HD = cfg.n_heads * cfg.head_dim
+        QKV = HD + 2 * cfg.kv_heads * cfg.head_dim
         dt = jnp.dtype(cfg.dtype)
         keys = jax.random.split(rng, 8)
+        # with layer_types the mixers' leaves stack over their own layers
+        n_attn = cfg.layer_types.count("attention") if cfg.layer_types else L
+        attn = "attn." if cfg.layer_types else "blocks."
 
         def norm(key, shape, fan_in):
             return (jax.random.normal(key, shape, jnp.float32)
@@ -127,11 +185,19 @@ class TransformerLM:
             "embed": norm(keys[0], (cfg.vocab_size, E), E),
             "blocks.ln1_scale": jnp.ones((L, E), dt),
             "blocks.ln2_scale": jnp.ones((L, E), dt),
-            "blocks.wqkv": norm(keys[1], (L, E, 3 * HD), E),
-            "blocks.wo": norm(keys[2], (L, HD, E), HD),
+            attn + "wqkv": norm(keys[1], (n_attn, E, QKV), E),
+            attn + "wo": norm(keys[2], (n_attn, HD, E), HD),
             "final_ln_scale": jnp.ones((E,), dt),
-            "unembed": norm(keys[3], (E, cfg.vocab_size), E),
         }
+        if not cfg.tie_embeddings:
+            p["unembed"] = norm(keys[3], (E, cfg.vocab_size), E)
+        if cfg.layer_types:
+            from .mamba import mamba_init
+            for k, v in mamba_init(cfg, keys[7],
+                                   L - n_attn).items():
+                p["ssm." + k] = v
+        if cfg.mlp == "swiglu":
+            p["blocks.w_gate"] = norm(keys[4], (L, E, F), E)
         if cfg.use_moe:
             p["blocks.gate"] = norm(keys[4], (L, E, cfg.n_experts), E)
             p["blocks.moe_up"] = norm(keys[5], (L, cfg.n_experts, E, F), E)
@@ -164,18 +230,40 @@ class TransformerLM:
             return x, aux, k, v
         return x, aux
 
+    def _ssm_block(self, bp, x):
+        """A layer whose mixer is a Mamba-1 state-space mixer."""
+        from .mamba import mamba_mixer
+        with jax.named_scope("ssm"):
+            h = self._rmsnorm(x, bp["ln1_scale"])
+            x = x + constraint(mamba_mixer(bp, h, self.cfg),
+                               "dp", "sp", None)
+        with jax.named_scope("mlp"):
+            return self._mlp_half(bp, x)
+
     def _attn_half(self, bp, x, use_ring):
         cfg = self.cfg
         B, T, E = x.shape
-        H, D = cfg.n_heads, cfg.head_dim
+        H, D, KV = cfg.n_heads, cfg.head_dim, cfg.kv_heads
         h = self._rmsnorm(x, bp["ln1_scale"])
         qkv = jnp.einsum("bte,ef->btf", h, bp["wqkv"],
                          preferred_element_type=jnp.float32).astype(x.dtype)
         qkv = constraint(qkv, "dp", "sp", "tp")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        if KV == H:
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+        else:
+            q, k, v = jnp.split(qkv, [H * D, (H + KV) * D], axis=-1)
         q = q.reshape(B, T, H, D)
-        k = k.reshape(B, T, H, D)
-        v = v.reshape(B, T, H, D)
+        k = k.reshape(B, T, KV, D)
+        v = v.reshape(B, T, KV, D)
+        if KV != H:
+            # Shared key/value heads are broadcast to their query heads
+            # before attention (the backward sums dk, dv over the group by
+            # autodiff), not indexed ``h // group`` inside the kernels: the
+            # three flash kernels stay as the equal-head models run them,
+            # at the cost of K and V read once a query head, which the
+            # kernels' grid does anyway.
+            k = jnp.repeat(k, H // KV, axis=2)
+            v = jnp.repeat(v, H // KV, axis=2)
         score_mb = B * H * T * T * 4 / 1e6
         if use_ring:
             attn = ring_self_attention(q, k, v, causal=True)
@@ -209,7 +297,13 @@ class TransformerLM:
         else:
             up = jnp.einsum("bte,ef->btf", h, bp["w_up"],
                             preferred_element_type=jnp.float32)
-            up = constraint(jax.nn.gelu(up).astype(x.dtype), "dp", "sp", "tp")
+            if cfg.mlp == "swiglu":
+                gate = jnp.einsum("bte,ef->btf", h, bp["w_gate"],
+                                  preferred_element_type=jnp.float32)
+                up = jax.nn.silu(gate) * up
+            else:
+                up = jax.nn.gelu(up)
+            up = constraint(up.astype(x.dtype), "dp", "sp", "tp")
             ff = jnp.einsum("btf,fe->bte", up, bp["w_down"],
                             preferred_element_type=jnp.float32).astype(x.dtype)
         return x + constraint(ff, "dp", "sp", None), aux
@@ -227,9 +321,28 @@ class TransformerLM:
     # The allocator/scheduler around these functions lives in
     # mxnet_tpu/generation.py (docs/GENERATIVE.md).
 
+    def _refuse_serving(self):
+        """The paged decode path knows one block: equal head counts, a GELU
+        MLP, an untied head, attention in every layer, no experts."""
+        cfg = self.cfg
+        if cfg.use_moe:
+            raise NotImplementedError("paged decode does not support MoE yet")
+        if cfg.layer_types:
+            raise NotImplementedError(
+                "paged decode does not support layer_types yet: a "
+                "state-space layer needs its state kept beside the KV "
+                "pages (ROADMAP R-m5)")
+        if (cfg.kv_heads != cfg.n_heads or cfg.mlp != "gelu"
+                or cfg.tie_embeddings):
+            raise NotImplementedError(
+                "paged decode does not support n_kv_heads < n_heads, a "
+                "gated MLP or a tied head yet (ROADMAP R-m2)")
+
     def init_kv_pages(self, num_pages, page_size):
         """Allocate zeroed paged KV storage: ([L,P,ps,H,D], same) pair."""
         cfg = self.cfg
+        if cfg.layer_types or cfg.kv_heads != cfg.n_heads:
+            self._refuse_serving()
         shape = (cfg.n_layers, num_pages, page_size, cfg.n_heads,
                  cfg.head_dim)
         dt = jnp.dtype(cfg.dtype)
@@ -248,8 +361,7 @@ class TransformerLM:
         argmax of this, no decode step needed for the first token).
         """
         cfg = self.cfg
-        if cfg.use_moe:
-            raise NotImplementedError("paged decode does not support MoE yet")
+        self._refuse_serving()
         ps = k_pages.shape[2]
         Tpad = tokens.shape[1]
         x = params["embed"][tokens]
@@ -290,8 +402,7 @@ class TransformerLM:
         slot-count bucket, so join/leave churn never recompiles.
         """
         cfg = self.cfg
-        if cfg.use_moe:
-            raise NotImplementedError("paged decode does not support MoE yet")
+        self._refuse_serving()
         H, D = cfg.n_heads, cfg.head_dim
         S = tokens.shape[0]
         ps = k_pages.shape[2]
@@ -371,13 +482,47 @@ class TransformerLM:
             x, a = self._block(bp, x, use_ring)
             return (x, aux + a), None
 
-        body_fn = jax.checkpoint(body) if cfg.remat else body
-        (x, aux), _ = lax.scan(body_fn, (x, jnp.float32(0.0)), stacked,
-                               unroll=bool(cfg.scan_unroll))
+        def ssm_body(carry, bp):
+            x, aux = carry
+            x, a = self._ssm_block(bp, x)
+            return (x, aux + a), None
+
+        carry = (x, jnp.float32(0.0))
+        if not cfg.layer_types:
+            body_fn = jax.checkpoint(body) if cfg.remat else body
+            carry, _ = lax.scan(body_fn, carry, stacked,
+                                unroll=bool(cfg.scan_unroll))
+        else:
+            # one scan a run of layers of one kind, over that run's slice of
+            # the common stack and of the kind's own
+            own = {kind: {k.split(".", 1)[1]: v for k, v in params.items()
+                          if k.startswith(prefix)}
+                   for kind, prefix in (("attention", "attn."),
+                                        ("mamba", "ssm."))}
+            for kind, lo, hi, klo, khi in cfg.layer_runs():
+                run = {k: v[lo:hi] for k, v in stacked.items()}
+                run.update({k: v[klo:khi] for k, v in own[kind].items()})
+                run_body = body if kind == "attention" else ssm_body
+                if cfg.remat:
+                    # a Mamba layer keeps the scan's output and chunk
+                    # boundaries (52 MB a layer at 4096 x 5120): the
+                    # backward re-makes everything else but does not run
+                    # the scan forward twice
+                    from ..ops.pallas.selective_scan import SAVED_NAMES
+                    run_body = jax.checkpoint(
+                        run_body, policy=jax.checkpoint_policies
+                        .save_only_these_names(*SAVED_NAMES))
+                carry, _ = lax.scan(run_body, carry, run,
+                                    unroll=bool(cfg.scan_unroll))
+        x, aux = carry
 
         x = self._rmsnorm(x, params["final_ln_scale"])
-        logits = jnp.einsum("bte,ev->btv", x, params["unembed"],
-                            preferred_element_type=jnp.float32)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bte,ve->btv", x, params["embed"],
+                                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.einsum("bte,ev->btv", x, params["unembed"],
+                                preferred_element_type=jnp.float32)
         return logits, aux
 
     def loss(self, params, tokens, targets):

@@ -16,8 +16,10 @@ from .flash_attention import (flash_attention, flash_attention_lse,  # noqa: F40
                               flash_self_attention)
 from .int8_matmul import int8_matmul, int8_matmul_lax  # noqa: F401
 from .layers import fused_rmsnorm, fused_softmax_xent  # noqa: F401
+from .selective_scan import selective_scan, selective_scan_lax  # noqa: F401
 
 __all__ = ["flash_attention", "flash_attention_lse", "flash_self_attention",
            "fused_rmsnorm", "fused_softmax_xent",
            "int8_matmul", "int8_matmul_lax",
+           "selective_scan", "selective_scan_lax",
            "select_impl", "register_impl", "kernel_unit", "kernel_units"]

@@ -53,7 +53,8 @@ def register_impl(name, *, pallas, fallback, sharded=None):
 def _ensure_registered():
     # Kernel modules register at import; pull them in on first lookup so
     # importing only `common` (e.g. from models.transformer) still works.
-    from . import flash_attention, int8_matmul, layers  # noqa: F401
+    from . import (flash_attention, int8_matmul, layers,  # noqa: F401
+                   selective_scan)
 
 
 def select_impl(name):

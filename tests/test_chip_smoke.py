@@ -22,6 +22,9 @@ finally:
 
 TOY_LM = dict(vocab_size=96, d_model=32, n_heads=2, n_layers=1, d_ff=64,
               max_len=64, dtype="float32", remat=False)
+TOY_HYBRID = dict(TOY_LM, n_layers=2, n_kv_heads=1, mlp="swiglu",
+                  tie_embeddings=True, layer_types=("mamba", "attention"),
+                  ssm_state=8, ssm_dt_rank=4)
 
 
 def test_leg_trainer_toy():
@@ -42,6 +45,12 @@ def test_leg_lm_train_and_multichip_toy(monkeypatch):
     assert out["selected"] == {"flash_attention": {"interpret": 1},
                                "fused_rmsnorm": {"interpret": 3},
                                "fused_softmax_xent": {"interpret": 1}}
+    hybrid = chip_smoke.leg_lm_train(TOY_HYBRID, batch=2, seq=32, steps=2,
+                                     expect_impl="interpret")
+    assert hybrid["selected"] == {"flash_attention": {"interpret": 1},
+                                  "fused_rmsnorm": {"interpret": 5},
+                                  "fused_softmax_xent": {"interpret": 1},
+                                  "selective_scan": {"interpret": 1}}
     mesh = chip_smoke.leg_multichip(TOY_LM, batch=2, seq=32,
                                     ref_loss=out["losses"][0],
                                     devices=jax.devices()[:4])
@@ -55,8 +64,9 @@ def test_leg_kernel_parity_toy():
     out = chip_smoke.leg_kernel_parity(
         interpret=True, seqs=(24,), head_dims=(8,), cell_shape=(2, 48, 2, 8),
         cross_seqs=(16, 40), norm_shape=(2, 8, 32),
-        xent_rows=8, vocab=100, mm_shapes=((40, 24, 72),))
-    assert out["checks"] > 40
+        xent_rows=8, vocab=100, mm_shapes=((40, 24, 72),),
+        scan_shapes=((2, 40, 128, 8),), scan_cell_shape=(1, 48, 256, 16))
+    assert out["checks"] > 56
 
 
 def test_leg_server_toy():

@@ -1,9 +1,10 @@
-"""The flash kernels through libtpu's real compiler, for a v5e that is
-described and not attached (no chip, nothing runs): what the Pallas
-interpreter cannot refuse — a slice off the tiling, more scoped VMEM than a
-kernel may use — at every rung the tile chooser returns for the shapes the
-repo runs.  One file, and the topology only inside a fixture: one process
-at a time may load the TPU's library."""
+"""The flash and selective-scan kernels through libtpu's real compiler, for
+a v5e that is described and not attached (no chip, nothing runs): what the
+Pallas interpreter cannot refuse — a slice off the tiling, a strided access
+to a buffer that is not 128 wide, more scoped VMEM than a kernel may use —
+at every rung the tile chooser returns for the shapes the repo runs.  One
+file, and the topology only inside a fixture: one process at a time may load
+the TPU's library."""
 import importlib
 
 import jax
@@ -11,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+ss = importlib.import_module("mxnet_tpu.ops.pallas.selective_scan")
 
 
 @pytest.fixture(scope="module")
@@ -87,4 +89,40 @@ def test_flash_kernels_compile_for_v5e(one_chip, B, Tq, Tk, H, D, dtype,
             q, kv, kv, q, spec((B, H, Tq), jnp.float32)).compile()
     text = compiled.as_text()
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert name in text, name
+
+
+# (batch, T, Di, N, dtype of u / z / B / C, the blocks the chooser must return)
+SCAN_CASES = [
+    pytest.param(1, 4096, 5120, 16, jnp.bfloat16, (128, 1024),
+                 id="jamba2_3b_train_one_of_14_layers"),
+    pytest.param(1, 4096, 2048, 16, jnp.bfloat16, (128, 1024),
+                 id="chip_smoke_hybrid"),
+    pytest.param(1, 2048, 1536, 16, jnp.bfloat16, (256, 512),
+                 id="rung_512"),
+    pytest.param(2, 1100, 640, 16, jnp.float32, (256, 128),
+                 id="f32_ragged_1100_di640"),
+    pytest.param(2, 300, 256, 4, jnp.float32, (256, 256),
+                 id="f32_n4_padded_to_a_sublane_tile"),
+]
+
+
+@pytest.mark.parametrize("Bt,T,Di,N,dtype,blocks", SCAN_CASES)
+def test_scan_kernels_compile_for_v5e(one_chip, Bt, T, Di, N, dtype, blocks):
+    assert ss._choose_blocks(T, Di, N) == blocks
+
+    def fwd_and_grads(u, delta, A, B, C, D, z, do):
+        out, vjp = jax.vjp(lambda *a: ss.selective_scan(
+            *a, interpret=False), u, delta, A, B, C, D, z)
+        return out, vjp(do)
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    wide, narrow = spec((Bt, T, Di), dtype), spec((Bt, T, N), dtype)
+    compiled = jax.jit(fwd_and_grads).lower(
+        wide, spec((Bt, T, Di), jnp.float32), spec((Di, N), jnp.float32),
+        narrow, narrow, spec((Di,), jnp.float32), wide, wide).compile()
+    text = compiled.as_text()
+    for name in ("ssm_scan_fwd", "ssm_scan_bwd"):
         assert name in text, name
