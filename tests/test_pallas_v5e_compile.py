@@ -5,6 +5,7 @@ to a buffer that is not 128 wide, more scoped VMEM than a kernel may use —
 at every rung the tile chooser returns for the shapes the repo runs.  One
 file, and the topology only inside a fixture: one process at a time may load
 the TPU's library."""
+import functools
 import importlib
 
 import jax
@@ -99,30 +100,99 @@ SCAN_CASES = [
     pytest.param(1, 4096, 2048, 16, jnp.bfloat16, (128, 1024),
                  id="chip_smoke_hybrid"),
     pytest.param(1, 2048, 1536, 16, jnp.bfloat16, (256, 512),
-                 id="rung_512"),
+                 id="rung_512_two_tiles_of_columns_a_chunk"),
     pytest.param(2, 1100, 640, 16, jnp.float32, (256, 128),
                  id="f32_ragged_1100_di640"),
     pytest.param(2, 300, 256, 4, jnp.float32, (256, 256),
                  id="f32_n4_padded_to_a_sublane_tile"),
+    pytest.param(1, 2048, 2048, 8, jnp.bfloat16, (256, 1024),
+                 id="n8_one_sublane_tile"),
+    pytest.param(1, 1024, 1024, 24, jnp.bfloat16, (64, 1024),
+                 id="n24_three_sublane_tiles"),
 ]
 
 
-@pytest.mark.parametrize("Bt,T,Di,N,dtype,blocks", SCAN_CASES)
-def test_scan_kernels_compile_for_v5e(one_chip, Bt, T, Di, N, dtype, blocks):
-    assert ss._choose_blocks(T, Di, N) == blocks
-
+def _compile_scan(one_chip, Bt, T, Di, N, dtype, **blocks):
     def fwd_and_grads(u, delta, A, B, C, D, z, do):
         out, vjp = jax.vjp(lambda *a: ss.selective_scan(
-            *a, interpret=False), u, delta, A, B, C, D, z)
+            *a, interpret=False, **blocks), u, delta, A, B, C, D, z)
         return out, vjp(do)
 
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     wide, narrow = spec((Bt, T, Di), dtype), spec((Bt, T, N), dtype)
-    compiled = jax.jit(fwd_and_grads).lower(
+    return jax.jit(fwd_and_grads).lower(
         wide, spec((Bt, T, Di), jnp.float32), spec((Di, N), jnp.float32),
-        narrow, narrow, spec((Di,), jnp.float32), wide, wide).compile()
-    text = compiled.as_text()
-    for name in ("ssm_scan_fwd", "ssm_scan_bwd"):
-        assert name in text, name
+        narrow, narrow, spec((Di,), jnp.float32), wide, wide
+    ).compile().as_text()
+
+
+def _scan_calls(text):
+    """The two kernels' custom calls in the compiled program, by name."""
+    calls = {}
+    for line in text.splitlines():
+        for name in ("ssm_scan_fwd", "ssm_scan_bwd"):
+            if "custom-call(" in line and "%" + name in line.split("=")[0]:
+                calls[name] = line
+    return calls
+
+
+@pytest.mark.parametrize("Bt,T,Di,N,dtype,blocks", SCAN_CASES)
+def test_scan_kernels_compile_for_v5e(one_chip, Bt, T, Di, N, dtype, blocks):
+    assert ss._choose_blocks(T, Di, N) == blocks
+    calls = _scan_calls(_compile_scan(one_chip, Bt, T, Di, N, dtype))
+    assert sorted(calls) == ["ssm_scan_bwd", "ssm_scan_fwd"]
+    # what the benchmark's reader finds the scan's calls by
+    # (``family_hybrid_ssm_lm.scan_call_seconds``): the decay matrix
+    # states-first, a float32 [N, Di], an operand of both kernels
+    n_p, di_p = -(-N // 8) * 8, -(-Di // blocks[1]) * blocks[1]
+    for name, line in calls.items():
+        operands = line.split("custom-call(", 1)[1]
+        assert "f32[%d,%d]" % (n_p, di_p) in operands, (name, operands[:400])
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def test_scan_kernels_spread_a_row_by_a_load_of_stride_0():
+    """``_row`` is the one place where ``interpret`` forks the kernels: the
+    tests off the chip take its ``broadcast_to`` branch.  What is compiled
+    above reads a step's row of ``delta`` and ``delta u`` (and, backwards,
+    ``dy``) by a load of stride 0, on every step and lane group: two a step
+    in the forward's loop, three in each of the backward's two."""
+    Bt, T, Di, N = 1, 256, 512, 16
+    chunk, d_block = ss._choose_blocks(T, Di, N)
+    groups = d_block // 128
+
+    def fwd_and_grads(u, delta, A, B, C, D, z, do):
+        out, vjp = jax.vjp(lambda *a: ss.selective_scan(
+            *a, interpret=False), u, delta, A, B, C, D, z)
+        return out, vjp(do)
+
+    wide = jax.ShapeDtypeStruct((Bt, T, Di), jnp.bfloat16)
+    narrow = jax.ShapeDtypeStruct((Bt, T, N), jnp.bfloat16)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    traced = jax.make_jaxpr(fwd_and_grads)(
+        wide, f32((Bt, T, Di)), f32((Di, N)), narrow, narrow, f32((Di,)),
+        wide, wide)
+    found = {}
+    for call in _equations(traced.jaxpr):
+        if call.primitive.name != "pallas_call":
+            continue
+        loads = 0
+        for eqn in _equations(call.params["jaxpr"]):
+            if eqn.primitive.name == "get":
+                index = jax.tree_util.tree_unflatten(eqn.params["tree"],
+                                                     eqn.invars[1:])
+                loads += repr(index).count("stride=0")
+        found[call.params["name"]] = loads
+    assert found == {"ssm_scan_fwd": 2 * ss._ROWS * groups,
+                     "ssm_scan_bwd": 6 * ss._ROWS * groups}
