@@ -1239,9 +1239,9 @@ def tenant_bench(quick=False):
 
 def kernels_bench(quick=False):
     """Pallas kernel-program leg (docs/KERNELS.md): measures the two
-    tentpole kernels through the SAME ``select_impl`` registry the model
-    paths use, so the number tracks whatever implementation the backend
-    actually gets (Pallas on TPU, lax fallbacks elsewhere — the quick/CPU
+    tentpole kernels through the SAME entry points the model paths call,
+    so the number tracks whatever implementation ``kernel_impl`` gives
+    the backend (Pallas on TPU, lax fallbacks elsewhere — the quick/CPU
     reading gates plumbing regressions, the TPU reading gates the
     kernels).  Both are wrapped as ``kernel_unit`` TrackedJits, so the
     flight recorder and MFU attribution see them as ``kernel.*`` units.
@@ -1256,7 +1256,8 @@ def kernels_bench(quick=False):
     import jax.numpy as jnp
     import numpy as np
 
-    from mxnet_tpu.ops.pallas import kernel_unit, select_impl
+    from mxnet_tpu.ops.pallas import (flash_attention, int8_matmul,
+                                      kernel_impl, kernel_unit)
 
     on_tpu = jax.default_backend() == "tpu"
     B, H, D = 1, 4, 64
@@ -1269,10 +1270,10 @@ def kernels_bench(quick=False):
     k = jax.random.normal(kk, (B, T, H, D), dt)
     v = jax.random.normal(kv, (B, T, H, D), dt)
 
-    attn_fn, attn_impl = select_impl("flash_attention")
+    attn_impl = kernel_impl("flash_attention")      # no mesh in this leg
 
     def flash_loss(q, k, v):
-        o = attn_fn(q, k, v, causal=True)
+        o = flash_attention(q, k, v, causal=True)
         return (o.astype(jnp.float32) ** 2).sum()
 
     def naive_loss(q, k, v):
@@ -1314,10 +1315,8 @@ def kernels_bench(quick=False):
     w8 = jnp.asarray(rng.randint(-127, 128, (N, K)), jnp.int8)
     sa = jnp.float32(0.05)
     sw = jnp.asarray(rng.rand(N).astype(np.float32) * 0.1 + 0.01)
-    int8_fn, int8_impl = select_impl("int8_matmul")
-    int8_step = kernel_unit(
-        "bench_int8_matmul",
-        lambda a, b, s_a, s_b: int8_fn(a, b, s_a, s_b))
+    int8_impl = kernel_impl("int8_matmul")
+    int8_step = kernel_unit("bench_int8_matmul", int8_matmul)
     bdt = jnp.bfloat16 if on_tpu else jnp.float32
     a16 = (a8.astype(jnp.float32) * sa).astype(bdt)
     w16 = (w8.astype(jnp.float32) * sw[:, None]).astype(bdt)

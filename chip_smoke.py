@@ -204,10 +204,13 @@ def leg_trainer(ctx, model_name="resnet50_v1", batch=128, size=224,
 # ---------------------------------------------------------------------------
 # leg B — LM trainer and the kernels
 # ---------------------------------------------------------------------------
-def leg_lm_train(cfg_kw, batch, seq, steps=3, expect_impl="pallas", lr=1e-2):
+def leg_lm_train(cfg_kw, batch, seq, steps=3, expect_impl="pallas", lr=1e-2,
+                 flash=True):
     """``jax.jit(make_train_step(model))`` on a fixed batch; checks the loss
-    and which implementation ``select_impl`` gave each kernel (the
-    selective scan only where the model has state-space layers)."""
+    and which implementation ``kernel_impl`` gave each kernel the model
+    called (the selective scan only where it has state-space layers; not
+    ``flash`` where the caller says the model's own gate keeps attention
+    dense at this size)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -242,6 +245,9 @@ def leg_lm_train(cfg_kw, batch, seq, steps=3, expect_impl="pallas", lr=1e-2):
     if "mamba" not in cfg_kw.get("layer_types", ()):
         _check(not any(selected.pop("selective_scan").values()),
                "selective_scan selected by a model with no state-space layer")
+    if not flash:
+        _check(not any(selected.pop("flash_attention").values()),
+               "flash_attention selected where the leg expects dense")
     for kernel, by_impl in selected.items():
         others = {i: n for i, n in by_impl.items()
                   if i != expect_impl and n}
@@ -722,7 +728,8 @@ def main():
 
     try:
         run("A_trainer", leg_trainer, mx.tpu())
-        wide = run("B_lm_wide", leg_lm_train, LM_WIDE, batch=8, seq=1024)
+        wide = run("B_lm_wide", leg_lm_train, LM_WIDE, batch=8, seq=1024,
+                   flash=False)
         run("B_lm_long", leg_lm_train, LM_LONG, batch=1, seq=8192)
         # a fifth of the other legs' step: from a random start the mixers'
         # x_proj gradient is some forty times the other leaves', and at

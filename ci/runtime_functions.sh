@@ -215,11 +215,11 @@ gen_check() {
 }
 
 kernel_check() {
-    # Pallas kernel program (docs/KERNELS.md): select_impl registry mode
+    # Pallas kernel program (docs/KERNELS.md): kernel_impl's mode
     # semantics, flash-attention fwd+bwd parity (incl. the lse-cotangent
     # custom VJP), int8 matmul int32 exactness + fused per-channel
     # dequant oracle, and the quantized_dense wiring.  The second run
-    # routes every registry call site through the Pallas interpreter —
+    # routes every entry point through the Pallas interpreter —
     # the CPU stand-in for the real kernels.
     python -m pytest tests/test_pallas.py tests/test_quantization.py -q
     MXTPU_PALLAS=interpret python -m pytest tests/test_pallas.py -q

@@ -181,16 +181,18 @@ class _Config:
              "kv_pages, replica slices) published as mem.* gauges on "
              "every memory.update(). Set 0 to make update() a no-op."),
         Knob("MXTPU_PALLAS", str, "auto",
-             "Kernel-selection mode for the Pallas kernel library "
-             "(docs/KERNELS.md; ops.pallas.common.select_impl): 'auto' "
-             "runs the hand-tiled kernels (flash attention fwd+bwd, int8 "
+             "Which implementation a Pallas kernel's entry point takes "
+             "(docs/KERNELS.md; one rule, "
+             "ops.pallas.common.kernel_impl): 'auto' runs the hand-tiled "
+             "kernels (flash attention fwd+bwd, the selective scan, int8 "
              "matmul with fused dequant, fused rmsnorm/xent) on "
-             "single-device TPU and the identical-math lax fallbacks "
-             "elsewhere; 'off' forces the fallbacks everywhere; "
-             "'interpret' runs the real kernels through the Pallas "
-             "interpreter on any backend — the CPU parity-testing mode. "
-             "Each resolution bumps a pallas.select.<kernel>.<impl> "
-             "telemetry counter."),
+             "single-device TPU, flash inside its shard_map wrapper "
+             "under a mesh, and the identical-math lax forms elsewhere; "
+             "'off' forces the lax forms everywhere; 'interpret' runs "
+             "the real kernels through the Pallas interpreter on any "
+             "backend (the lax forms under a mesh) — the CPU "
+             "parity-testing mode. Each answer bumps a "
+             "pallas.select.<kernel>.<impl> telemetry counter."),
         Knob("MXTPU_LOCKDEP", str, "off",
              "Runtime lock-order sanitizer (mxnet_tpu.lockdep; "
              "docs/STATIC_ANALYSIS.md 'Runtime lockdep'): wraps every "

@@ -93,10 +93,10 @@ def trace_guard_mode():
 def pallas_mode():
     """'auto', 'off', or 'interpret' — the MXTPU_PALLAS knob, validated.
 
-    Consumed by ``ops.pallas.common.select_impl`` (docs/KERNELS.md): 'auto'
-    picks the Pallas kernel on single-device TPU and the lax fallback
-    elsewhere; 'off' forces the fallback everywhere; 'interpret' runs the
-    real kernels through the Pallas interpreter on any backend (the CPU
+    Read by ``ops.pallas.common.kernel_impl`` (docs/KERNELS.md), which has
+    the table: 'auto' is the Pallas kernel on single-device TPU and the lax
+    form elsewhere; 'off' the lax form everywhere; 'interpret' the real
+    kernels through the Pallas interpreter on any backend (the CPU
     parity-testing mode)."""
     from .config import config
 

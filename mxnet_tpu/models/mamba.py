@@ -8,8 +8,8 @@
     delta     = softplus(dt_proj(d) + b_dt)                 R -> Di
     out       = out_proj(selective_scan(u, delta, -exp(A_log), B, C, D, z))
 
-The recurrence itself is `ops/pallas/selective_scan.py` (kernel registry:
-Pallas on a single TPU chip, the same chunked mathematics in lax elsewhere).
+The recurrence itself is `ops/pallas/selective_scan.py` (the Pallas kernel
+on a single TPU chip, the same chunked mathematics in lax elsewhere).
 ``delta``, ``A``, the state and every ``exp`` are float32 whatever the
 model's dtype; ``A_log``, ``D`` and ``dt_bias`` are float32 leaves.
 """
@@ -19,6 +19,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+# the three inner norms are 160 and 16 wide: the plain lax form, not the
+# kernel the layer norms take
+from ..ops.pallas.layers import _rmsnorm_lax as rms
+from ..ops.pallas.selective_scan import selective_scan
 
 __all__ = ["mamba_mixer", "mamba_leaf_shapes", "mamba_init"]
 
@@ -89,10 +94,6 @@ def _causal_conv(u, w, b):
 
 def mamba_mixer(bp, h, cfg):
     """One mixer on the normed input ``h`` [B, T, E] -> [B, T, E]."""
-    from ..ops.pallas.common import select_impl
-    # the three inner norms are 160 and 16 wide: the plain lax form, not the
-    # kernel the layer norms take
-    from ..ops.pallas.layers import _rmsnorm_lax as rms
     dt = h.dtype
     N, R = cfg.ssm_state, cfg.ssm_dt_rank
 
@@ -113,6 +114,5 @@ def mamba_mixer(bp, h, cfg):
                             + bp["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(bp["A_log"].astype(jnp.float32))
     with jax.named_scope("scan"):
-        scan_fn, _impl = select_impl("selective_scan")
-        y = scan_fn(u, delta, A, B, C, bp["D"], z)
+        y = selective_scan(u, delta, A, B, C, bp["D"], z)
     return proj(y, bp["out_proj"]).astype(dt)

@@ -12,9 +12,10 @@ Design notes (TPU-first):
 * parameters are a flat ``{name: jax.Array}`` dict; layer stacks use a leading
   ``L`` dim + ``lax.scan`` over blocks (ONE traced block body, remat-friendly)
   — not L separately-traced python layers.  By default the scan is UNROLLED
-  at compile time (``scan_unroll=True``: XLA overlaps across layers, measured
-  47.4%→53.7% MFU) at a compile-time cost ~ n_layers; deep configs can set
-  ``scan_unroll=False`` to regain one-body compiles;
+  at compile time (``scan_unroll=True``: XLA may overlap and fuse across
+  layers; not re-measured since PR 27; ROADMAP S1 (c)) at a compile-time
+  cost ~ n_layers; deep configs can set ``scan_unroll=False`` to regain
+  one-body compiles;
 * compute dtype bf16, accumulation f32 (MXU-native);
 * causal LM loss is computed from sharded logits; everything is static-shaped.
 """
@@ -28,9 +29,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.pallas import (flash_attention, fused_rmsnorm,
+                          fused_softmax_xent)
 from ..parallel.sharding import ShardingRules, constraint, PartitionSpec as P
-from ..parallel.ring_attention import ring_self_attention, blockwise_attention
+from ..parallel.ring_attention import ring_self_attention
 from ..parallel.moe import moe_layer
+from .mamba import mamba_mixer
 
 __all__ = ["TransformerConfig", "TransformerLM", "make_train_step",
            "default_rules"]
@@ -49,17 +53,15 @@ class TransformerConfig:
     n_experts: int = 8
     moe_aux_weight: float = 0.01
     remat: bool = True
-    # Unroll the layer scan: one traced body, unrolled execution — XLA
-    # overlaps/fuses across layers (measured on v5e: 47.4% -> 53.7% MFU
-    # for the d2048x4 flagship; scan bodies ran at ~22 TF/s vs 120-190
-    # for the same kernels unrolled).  Costs compile time ~ n_layers.
+    # Unroll the layer scan: one traced body, unrolled execution, so XLA
+    # may overlap and fuse across layers.  Costs compile time ~ n_layers.
+    # Not re-measured since PR 27; ROADMAP S1 (c).
     scan_unroll: bool = True
-    # Small attention problems use plain dense attention (scores
-    # materialize, but the fused matmul+softmax runs at full MXU rate:
-    # measured 60.0% vs 53.7% MFU with the Pallas flash kernel at
-    # B=8/H=16/T=1024); bigger ones switch to flash so memory stays
-    # O(T).  The gate is the f32 score-tensor size B*H*T^2*4 bytes —
-    # gating on T alone would let large batches OOM.
+    # Small attention problems use plain dense attention (the scores
+    # materialize); bigger ones take flash so memory stays O(T).  The
+    # gate is the f32 score-tensor size B*H*T^2*4 bytes — gating on T
+    # alone would let large batches OOM; 0 sends every shape to flash.
+    # Not re-measured since PR 27; ROADMAP S1 (c).
     dense_attn_max_score_mb: int = 768
     # Key/value heads shared by groups of query heads (multi-query: 1).
     # 0 means as many as ``n_heads``.  Training path only: the paged pool
@@ -140,10 +142,10 @@ def default_rules() -> ShardingRules:
 
 
 def _dense_self_attention(q, k, v, causal=True):
-    """Plain materialized attention for short sequences: on TPU the fused
-    QK^T -> softmax -> PV chain runs at full MXU rate (measured 60% MFU
-    for the flagship at T=1024 vs 53.7% with the flash kernel); memory is
-    O(T^2) so the caller gates it by ``dense_attn_max_score_mb``."""
+    """Plain materialized attention for short sequences, one fused QK^T ->
+    softmax -> PV chain; memory is O(T^2) so the caller gates it by
+    ``dense_attn_max_score_mb`` (not re-measured against the flash kernel
+    since PR 27; ROADMAP S1 (c))."""
     B, T, H, D = q.shape
     qh = q.transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
@@ -209,52 +211,48 @@ class TransformerLM:
 
     # -- forward -------------------------------------------------------
     def _rmsnorm(self, x, scale):
-        # kernel registry (docs/KERNELS.md): fused Pallas kernel (one VMEM
-        # pass) on single-chip TPU or under MXTPU_PALLAS=interpret; under a
-        # mesh GSPMD can't partition the custom call, and the lax form
-        # below fuses fine anyway
-        from ..ops.pallas.common import select_impl
-        fn, impl = select_impl("fused_rmsnorm")
-        if impl in ("pallas", "interpret"):
-            return fn(x, scale.astype(x.dtype))
-        var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
-        return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)
-                ).astype(x.dtype) * scale
+        return fused_rmsnorm(x, scale.astype(x.dtype))
 
-    def _block(self, bp, x, use_ring, with_kv=False):
-        with jax.named_scope("attn"):
-            x, k, v = self._attn_half(bp, x, use_ring)
+    def _block(self, bp, x, mixer, scope="attn"):
+        """The one layer body of training, prefill and decode: norm ->
+        ``mixer(bp, h)`` -> residual -> MLP.  Returns ``(x, aux, state)``,
+        ``state`` being whatever the mixer hands back beside its output."""
+        with jax.named_scope(scope):
+            h = self._rmsnorm(x, bp["ln1_scale"])
+            o, state = mixer(bp, h)
+            x = x + constraint(o, "dp", "sp", None)
         with jax.named_scope("mlp"):
             x, aux = self._mlp_half(bp, x)
-        if with_kv:
-            return x, aux, k, v
-        return x, aux
+        return x, aux, state
 
-    def _ssm_block(self, bp, x):
-        """A layer whose mixer is a Mamba-1 state-space mixer."""
-        from .mamba import mamba_mixer
-        with jax.named_scope("ssm"):
-            h = self._rmsnorm(x, bp["ln1_scale"])
-            x = x + constraint(mamba_mixer(bp, h, self.cfg),
-                               "dp", "sp", None)
-        with jax.named_scope("mlp"):
-            return self._mlp_half(bp, x)
-
-    def _attn_half(self, bp, x, use_ring):
+    def _qkv(self, bp, h):
+        """The fused projection of ``h`` [B, T, E], split into heads:
+        q [B, T, H, D] and k, v [B, T, KV, D]."""
         cfg = self.cfg
-        B, T, E = x.shape
+        B, T, _ = h.shape
         H, D, KV = cfg.n_heads, cfg.head_dim, cfg.kv_heads
-        h = self._rmsnorm(x, bp["ln1_scale"])
         qkv = jnp.einsum("bte,ef->btf", h, bp["wqkv"],
-                         preferred_element_type=jnp.float32).astype(x.dtype)
+                         preferred_element_type=jnp.float32).astype(h.dtype)
         qkv = constraint(qkv, "dp", "sp", "tp")
-        if KV == H:
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-        else:
-            q, k, v = jnp.split(qkv, [H * D, (H + KV) * D], axis=-1)
-        q = q.reshape(B, T, H, D)
-        k = k.reshape(B, T, KV, D)
-        v = v.reshape(B, T, KV, D)
+        q, k, v = jnp.split(qkv, [H * D, (H + KV) * D], axis=-1)
+        return (q.reshape(B, T, H, D), k.reshape(B, T, KV, D),
+                v.reshape(B, T, KV, D))
+
+    def _attn_out(self, bp, attn):
+        """The output projection of ``attn`` [B, T, H, D]."""
+        B, T = attn.shape[:2]
+        return jnp.einsum("btf,fe->bte", attn.reshape(B, T, -1), bp["wo"],
+                          preferred_element_type=jnp.float32
+                          ).astype(attn.dtype)
+
+    def _self_attention(self, bp, h, use_ring=False):
+        """The mixer of training and prefill: causal self-attention over
+        ``h``; its state is the layer's ``(k, v)``, for the page write."""
+        cfg = self.cfg
+        B, T, _ = h.shape
+        H, KV = cfg.n_heads, cfg.kv_heads
+        q, k, v = self._qkv(bp, h)
+        kh, vh = k, v
         if KV != H:
             # Shared key/value heads are broadcast to their query heads
             # before attention (the backward sums dk, dv over the group by
@@ -262,31 +260,19 @@ class TransformerLM:
             # three flash kernels stay as the equal-head models run them,
             # at the cost of K and V read once a query head, which the
             # kernels' grid does anyway.
-            k = jnp.repeat(k, H // KV, axis=2)
-            v = jnp.repeat(v, H // KV, axis=2)
-        score_mb = B * H * T * T * 4 / 1e6
+            kh = jnp.repeat(k, H // KV, axis=2)
+            vh = jnp.repeat(v, H // KV, axis=2)
         if use_ring:
-            attn = ring_self_attention(q, k, v, causal=True)
+            attn = ring_self_attention(q, kh, vh, causal=True)
+        elif B * H * T * T * 4 / 1e6 <= cfg.dense_attn_max_score_mb:
+            attn = _dense_self_attention(q, kh, vh, causal=True)
         else:
-            # kernel registry (docs/KERNELS.md): 'pallas'/'sharded' is the
-            # flash kernel (dense-gated below — small problems run the
-            # materialized form at full MXU rate), 'interpret' forces the
-            # real kernels through the interpreter regardless of size (the
-            # parity-testing mode), 'fallback' is the lax blockwise path.
-            from ..ops.pallas.common import select_impl
-            attn_fn, attn_impl = select_impl("flash_attention")
-            if attn_impl == "interpret":
-                attn = attn_fn(q, k, v, causal=True)
-            elif score_mb <= cfg.dense_attn_max_score_mb:
-                attn = _dense_self_attention(q, k, v, causal=True)
-            elif attn_impl in ("pallas", "sharded"):
-                attn = attn_fn(q, k, v, causal=True)
-            else:
-                attn = blockwise_attention(q, k, v, causal=True)
-        attn = attn.reshape(B, T, H * D)
-        o = jnp.einsum("btf,fe->bte", attn, bp["wo"],
-                       preferred_element_type=jnp.float32).astype(x.dtype)
-        return x + constraint(o, "dp", "sp", None), k, v
+            attn = flash_attention(q, kh, vh, causal=True)
+        return self._attn_out(bp, attn), (k, v)
+
+    def _ssm(self, bp, h):
+        """The mixer of a state-space layer (`models/mamba.py`)."""
+        return mamba_mixer(bp, h, self.cfg), None
 
     def _mlp_half(self, bp, x):
         cfg = self.cfg
@@ -378,7 +364,7 @@ class TransformerLM:
 
         def body(x, xs):
             bp, kp, vp = xs
-            x, _aux, k, v = self._block(bp, x, use_ring=False, with_kv=True)
+            x, _aux, (k, v) = self._block(bp, x, self._self_attention)
             return x, (write(kp, k[0]), write(vp, v[0]))
 
         x, (k_pages, v_pages) = lax.scan(body, x, (stacked, k_pages, v_pages))
@@ -416,47 +402,28 @@ class TransformerLM:
         span = page_tables.shape[1] * ps
         attn_mask = jnp.arange(span)[None, :] <= lens[:, None]  # [S, span]
 
-        def attn_half(x, bp, kp, vp):
-            h = self._rmsnorm(x, bp["ln1_scale"])
-            qkv = jnp.einsum("ste,ef->stf", h, bp["wqkv"],
-                             preferred_element_type=jnp.float32
-                             ).astype(x.dtype)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(S, H, D)
-            kp = kp.reshape(-1, H, D).at[dest].set(
-                k.reshape(S, H, D)).reshape(kp.shape)
-            vp = vp.reshape(-1, H, D).at[dest].set(
-                v.reshape(S, H, D)).reshape(vp.shape)
+        def paged_attention(kp, vp, bp, h):
+            """The mixer of decode: the token's k, v written at ``dest``,
+            then attention over the slot's pages up to its length."""
+            q, k, v = self._qkv(bp, h)                         # [S, 1, H, D]
+            kp = kp.reshape(-1, H, D).at[dest].set(k[:, 0]).reshape(kp.shape)
+            vp = vp.reshape(-1, H, D).at[dest].set(v[:, 0]).reshape(vp.shape)
             kg = kp[page_tables].reshape(S, span, H, D)
             vg = vp[page_tables].reshape(S, span, H, D)
-            s = jnp.einsum("shd,skhd->shk", q, kg,
+            s = jnp.einsum("shd,skhd->shk", q[:, 0], kg,
                            preferred_element_type=jnp.float32) / math.sqrt(D)
             s = jnp.where(attn_mask[:, None, :], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+            p = jax.nn.softmax(s, axis=-1).astype(h.dtype)
             attn = jnp.einsum("shk,skhd->shd", p, vg,
                               preferred_element_type=jnp.float32
-                              ).astype(x.dtype)
-            o = jnp.einsum("stf,fe->ste", attn.reshape(S, 1, H * D),
-                           bp["wo"], preferred_element_type=jnp.float32
-                           ).astype(x.dtype)
-            return x + o, kp, vp
-
-        def mlp_half(x, bp):
-            h = self._rmsnorm(x, bp["ln2_scale"])
-            up = jnp.einsum("ste,ef->stf", h, bp["w_up"],
-                            preferred_element_type=jnp.float32)
-            ff = jnp.einsum("stf,fe->ste", jax.nn.gelu(up).astype(x.dtype),
-                            bp["w_down"], preferred_element_type=jnp.float32
-                            ).astype(x.dtype)
-            return x + ff
+                              ).astype(h.dtype)
+            return self._attn_out(bp, attn[:, None]), (kp, vp)
 
         def body(x, xs):
             bp, kp, vp = xs
-            with jax.named_scope("attn"):
-                x, kp, vp = attn_half(x, bp, kp, vp)
-            with jax.named_scope("mlp"):
-                x = mlp_half(x, bp)
-            return x, (kp, vp)
+            x, _aux, pages = self._block(
+                bp, x, functools.partial(paged_attention, kp, vp))
+            return x, pages
 
         x, (k_pages, v_pages) = lax.scan(body, x, (stacked, k_pages, v_pages))
         x = self._rmsnorm(x, params["final_ln_scale"])
@@ -479,12 +446,13 @@ class TransformerLM:
 
         def body(carry, bp):
             x, aux = carry
-            x, a = self._block(bp, x, use_ring)
+            x, a, _kv = self._block(bp, x, functools.partial(
+                self._self_attention, use_ring=use_ring))
             return (x, aux + a), None
 
         def ssm_body(carry, bp):
             x, aux = carry
-            x, a = self._ssm_block(bp, x)
+            x, a, _ = self._block(bp, x, self._ssm, scope="ssm")
             return (x, aux + a), None
 
         carry = (x, jnp.float32(0.0))
@@ -528,15 +496,7 @@ class TransformerLM:
     def loss(self, params, tokens, targets):
         """Causal LM loss: mean token cross-entropy (+ MoE aux loss)."""
         logits, aux = self.apply(params, tokens)
-        from ..ops.pallas.common import select_impl
-        xent_fn, xent_impl = select_impl("fused_softmax_xent")
-        if xent_impl in ("pallas", "interpret"):
-            nll = xent_fn(logits, targets).mean()
-        else:
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(logits, targets[..., None],
-                                       axis=-1)[..., 0]
-            nll = (logz - gold).mean()
+        nll = fused_softmax_xent(logits, targets).mean()
         return nll + self.cfg.moe_aux_weight * aux
 
 

@@ -7,19 +7,18 @@ where explicit VMEM blocking beats XLA's default schedule — attention above
 all (the reference predates flash attention entirely; SURVEY.md §5
 "Long-context: absent").
 
-Kernels fall back to pure-lax implementations off-TPU (CPU oracle testing —
+Each entry point takes its kernel or the same mathematics in lax by one
+rule, ``common.kernel_impl`` (docs/KERNELS.md; CPU oracle testing —
 SURVEY.md §4 test strategy).
 """
-from .common import (kernel_unit, kernel_units, register_impl,  # noqa: F401
-                     select_impl)
-from .flash_attention import (flash_attention, flash_attention_lse,  # noqa: F401
-                              flash_self_attention)
+from .common import kernel_impl, kernel_unit, kernel_units  # noqa: F401
+from .flash_attention import flash_attention, flash_attention_lse  # noqa: F401
 from .int8_matmul import int8_matmul, int8_matmul_lax  # noqa: F401
 from .layers import fused_rmsnorm, fused_softmax_xent  # noqa: F401
 from .selective_scan import selective_scan, selective_scan_lax  # noqa: F401
 
-__all__ = ["flash_attention", "flash_attention_lse", "flash_self_attention",
+__all__ = ["flash_attention", "flash_attention_lse",
            "fused_rmsnorm", "fused_softmax_xent",
            "int8_matmul", "int8_matmul_lax",
            "selective_scan", "selective_scan_lax",
-           "select_impl", "register_impl", "kernel_unit", "kernel_units"]
+           "kernel_impl", "kernel_unit", "kernel_units"]

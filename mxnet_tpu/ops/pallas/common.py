@@ -1,17 +1,18 @@
 """Shared helpers for the Pallas kernel layer.
 
-Besides the numeric helpers this module is the kernel library's front
-door (docs/KERNELS.md): every kernel registers its implementations with
-:func:`register_impl` and callers resolve them with :func:`select_impl`,
-which honors the validated ``MXTPU_PALLAS=auto|off|interpret`` knob
-(``dispatch.pallas_mode``).  :func:`kernel_unit` wraps a kernel entry in a
-memoized, labeled ``TrackedJit`` so the recompile flight recorder and the
-per-leg cost/MFU attribution see each kernel as its own unit.
+Besides the numeric helpers this module holds the one rule for which
+implementation of a kernel runs (docs/KERNELS.md): every kernel's public
+entry point asks :func:`kernel_impl` when its caller forces nothing, and
+dispatches among its own private implementations.  :func:`kernel_unit`
+wraps a kernel entry in a memoized, labeled ``TrackedJit`` so the recompile
+flight recorder and the per-leg cost/MFU attribution see each kernel as its
+own unit.
 """
 from __future__ import annotations
 
-import functools
 import threading
+
+import jax
 
 _NEG = -1e30  # masked-logit filler: finite (NaN-safe) but exp() == 0 in f32
 
@@ -20,87 +21,55 @@ def _round_up(x, m):
     return -(-x // m) * m
 
 
-def _mesh_active():
-    """True when a device mesh is active — GSPMD cannot partition a Pallas
-    custom call, so kernels must route to their lax fallbacks (or shard_map
-    wrappers) in that case."""
+def kernel_impl(name, sharded=False, per_device=False):
+    """Which implementation of kernel ``name`` runs here: ``'pallas'`` (the
+    kernel, on a single-device TPU), ``'interpret'`` (the kernel through the
+    Pallas interpreter: any backend, parity testing), ``'sharded'`` (the
+    kernel's own ``shard_map`` wrapper; only for a kernel that says it has
+    one) or ``'fallback'`` (the same mathematics in lax, which GSPMD shards
+    freely).  By the validated ``MXTPU_PALLAS`` knob
+    (``dispatch.pallas_mode``):
+
+    ==========  ==================  ===============================
+    mode        no mesh             a mesh is active
+    ==========  ==================  ===============================
+    auto, TPU   pallas              sharded if ``sharded`` else fallback
+    auto, else  fallback            fallback
+    off         fallback            fallback
+    interpret   interpret           fallback
+    ==========  ==================  ===============================
+
+    GSPMD cannot partition a Pallas custom call, hence the right column.
+    A caller already inside a ``shard_map`` body holds its own device's
+    shard: it passes ``per_device`` and is answered by the left column.
+
+    Runs at trace time.  Each answer bumps the ``pallas.select.<name>.<impl>``
+    telemetry counter, except a ``per_device`` one: whoever made the body
+    made the selection.
+    """
+    from ...dispatch import pallas_mode
     from ...parallel.mesh import current_mesh
-    return current_mesh() is not None
+    mode = pallas_mode()
+    meshed = not per_device and current_mesh() is not None
+    if mode == "off":
+        impl = "fallback"
+    elif mode == "interpret":
+        impl = "fallback" if meshed else "interpret"
+    elif jax.default_backend() != "tpu":
+        impl = "fallback"
+    elif meshed:
+        impl = "sharded" if sharded else "fallback"
+    else:
+        impl = "pallas"
+    if not per_device:
+        from ... import telemetry as _telemetry
+        _telemetry.registry().counter(
+            "pallas.select.%s.%s" % (name, impl)).inc()
+    return impl
 
 
-# ---------------------------------------------------------------------------
-# kernel-selection registry
-# ---------------------------------------------------------------------------
-
-_REGISTRY = {}
 _UNITS = {}
 _UNITS_LOCK = threading.Lock()
-
-
-def register_impl(name, *, pallas, fallback, sharded=None):
-    """Register kernel ``name``'s implementations.
-
-    ``pallas`` is the single-device Pallas entry point and must accept an
-    ``interpret=`` keyword (interpret mode partials it in); ``fallback`` is
-    the pure-lax path (identical math, GSPMD-shardable); ``sharded`` is an
-    optional mesh-aware wrapper (e.g. a shard_map entry) used under 'auto'
-    on TPU when a mesh is active.
-    """
-    _REGISTRY[name] = {"pallas": pallas, "fallback": fallback,
-                       "sharded": sharded}
-
-
-def _ensure_registered():
-    # Kernel modules register at import; pull them in on first lookup so
-    # importing only `common` (e.g. from models.transformer) still works.
-    from . import (flash_attention, int8_matmul, layers,  # noqa: F401
-                   selective_scan)
-
-
-def select_impl(name):
-    """Resolve kernel ``name`` to ``(callable, impl)``.
-
-    ``impl`` is one of ``'pallas'`` (real kernel, single-device TPU),
-    ``'sharded'`` (mesh-aware wrapper), ``'interpret'`` (real kernel through
-    the Pallas interpreter — any backend, parity testing), or ``'fallback'``
-    (pure-lax path).  Selection honors ``MXTPU_PALLAS``:
-
-    * ``auto`` (default): pallas on TPU without a mesh; the sharded wrapper
-      (when registered) on TPU under a mesh; lax fallback elsewhere.
-    * ``off``: always the lax fallback.
-    * ``interpret``: the real kernels via the interpreter, except under an
-      active mesh (GSPMD cannot partition the custom call) where the
-      fallback keeps semantics identical.
-
-    Runs at trace time; each resolution bumps the
-    ``pallas.select.<name>.<impl>`` telemetry counter so kernel routing is
-    visible in the registry snapshot.
-    """
-    if name not in _REGISTRY:
-        _ensure_registered()
-    entry = _REGISTRY[name]
-    from ...dispatch import pallas_mode
-    mode = pallas_mode()
-    if mode == "interpret" and not _mesh_active():
-        fn, impl = functools.partial(entry["pallas"], interpret=True), \
-            "interpret"
-    elif mode == "off":
-        fn, impl = entry["fallback"], "fallback"
-    else:
-        import jax
-        if jax.default_backend() != "tpu":
-            fn, impl = entry["fallback"], "fallback"
-        elif _mesh_active():
-            if entry["sharded"] is not None:
-                fn, impl = entry["sharded"], "sharded"
-            else:
-                fn, impl = entry["fallback"], "fallback"
-        else:
-            fn, impl = entry["pallas"], "pallas"
-    from ... import telemetry as _telemetry
-    _telemetry.registry().counter(
-        "pallas.select.%s.%s" % (name, impl)).inc()
-    return fn, impl
 
 
 def kernel_unit(name, fn=None, static_argnums=()):

@@ -29,10 +29,13 @@ flash attention entirely; its kernel corpus lives in
 * at trace time the counter ``pallas.flash.tile.<kernel>.<bq>x<bk>`` and the
   gauge ``pallas.flash.causal_tiles_run_share`` (tiles visited / tiles of
   the grid, last traced kernel) record the schedule;
-* off-TPU the public entry point falls back to ``blockwise_attention`` (same
-  math, pure lax) so the CPU oracle tests in `tests/` exercise identical
-  semantics; ``interpret=True`` runs the real kernels through the Pallas
-  interpreter for parity testing without TPU hardware.
+* called with ``interpret=None`` the public entry points ask
+  ``common.kernel_impl``: the kernels, the kernels inside a ``shard_map``
+  over the mesh's batch and head axes (``_over_mesh``), or
+  ``blockwise_attention`` (same math, pure lax) so the CPU oracle tests in
+  `tests/` exercise identical semantics; ``interpret=True`` runs the real
+  kernels through the Pallas interpreter for parity testing without TPU
+  hardware.
 """
 from __future__ import annotations
 
@@ -43,9 +46,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import _NEG, _round_up, register_impl
+from .common import _NEG, _round_up, kernel_impl
 
-__all__ = ["flash_attention", "flash_self_attention"]
+__all__ = ["flash_attention", "flash_attention_lse"]
 
 
 # ---------------------------------------------------------------------------
@@ -477,21 +480,27 @@ def _flash_lse_bwd(causal, scale, tiles, kv_len, interpret, res, ct):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def _attend(q, k, v, causal, scale, block_q, block_k, interpret, with_lse):
-    """What both entry points share: the off-TPU fallback, the tiles, the
-    [B, H, T, D] layout and the padding to whole tiles.  Returns ``(o, lse)``
-    with ``lse`` [B, H, T] or None."""
+def _attend(q, k, v, causal, scale, block_q, block_k, interpret, per_device,
+            with_lse):
+    """What both entry points share: the choice of implementation, the
+    tiles, the [B, H, T, D] layout and the padding to whole tiles.  Returns
+    ``(o, lse)`` with ``lse`` [B, H, T] or None."""
     B, T, H, D = q.shape
     Tk = k.shape[1]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
-        interpret = False
-        if jax.default_backend() != "tpu":
+        # only the form without ``lse`` has a wrapper for a mesh
+        impl = kernel_impl("flash_attention", sharded=not with_lse,
+                           per_device=per_device)
+        if impl == "sharded":
+            return _over_mesh(q, k, v, causal, scale, block_q, block_k), None
+        if impl == "fallback":
             from ...parallel.ring_attention import blockwise_attention
             out = blockwise_attention(q, k, v, causal=causal, scale=scale,
                                       return_lse=with_lse)
             return out if with_lse else (out, None)
+        interpret = impl == "interpret"
 
     tiles = tuple((block_q or bq, block_k or bk) for bq, bk in
                   _choose_tiles(T, Tk, D, jnp.dtype(q.dtype).itemsize))
@@ -512,22 +521,56 @@ def _attend(q, k, v, causal, scale, block_q, block_k, interpret, with_lse):
     return o[:, :, :T].transpose(0, 2, 1, 3), lse
 
 
+def _over_mesh(q, k, v, causal, scale, block_q, block_k):
+    """The kernels under an active mesh, q/k/v [B, T, H, D] with the batch
+    possibly sharded on ``dp`` and the heads on ``tp``.
+
+    GSPMD cannot partition a custom call, so the kernel is wrapped in
+    ``shard_map`` over the batch/head axes (attention is independent per
+    batch element and head; sequence stays local — the sequence-sharded
+    case is `parallel.ring_attention`)."""
+    from ...parallel.mesh import current_mesh
+    mesh = current_mesh()
+    # inside the body each device holds its own shard: the kernel, forced
+    local = functools.partial(flash_attention, causal=causal, scale=scale,
+                              block_q=block_q, block_k=block_k,
+                              interpret=False)
+    b = "dp" if mesh.size("dp") > 1 else None
+    h = "tp" if mesh.size("tp") > 1 else None
+    if b is None and h is None:
+        return local(q, k, v)
+    if (b is not None and q.shape[0] % mesh.size("dp")) or \
+            (h is not None and q.shape[2] % mesh.size("tp")):
+        # shard_map needs exact divisibility; under a mesh the raw pallas
+        # call is unpartitionable by GSPMD, so fall back to the blockwise
+        # lax path (which GSPMD shards/replicates freely)
+        from ...parallel.ring_attention import blockwise_attention
+        return blockwise_attention(q, k, v, causal=causal, scale=scale)
+    from ...parallel.collectives import shard_map
+    from jax.sharding import PartitionSpec as P
+    spec = P(b, None, h, None)
+    return shard_map(local, mesh=mesh.mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)(q, k, v)
+
+
 def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
                     block_k=None, interpret=None):
     """Flash attention over [B, T, H, D] tensors.
 
-    On TPU runs the Pallas kernels above; elsewhere falls back to the
-    numerically-identical lax ``blockwise_attention``.  ``interpret=True``
-    forces the kernels through the Pallas interpreter (CPU parity tests).
-    Differentiable via custom VJP (Pallas backward kernels).  ``block_q`` /
-    ``block_k`` override the tiles ``_choose_tiles`` takes from the shape.
+    With ``interpret=None`` the implementation is ``common.kernel_impl``'s
+    answer: the Pallas kernels above, the same inside a ``shard_map`` under
+    a mesh, or the numerically-identical lax ``blockwise_attention``.
+    ``interpret=True`` forces the kernels through the Pallas interpreter
+    (CPU parity tests).  Differentiable via custom VJP (Pallas backward
+    kernels).  ``block_q`` / ``block_k`` override the tiles
+    ``_choose_tiles`` takes from the shape.
     """
     return _attend(q, k, v, causal, scale, block_q, block_k, interpret,
-                   with_lse=False)[0]
+                   per_device=False, with_lse=False)[0]
 
 
 def flash_attention_lse(q, k, v, causal=True, scale=None, block_q=None,
-                        block_k=None, interpret=None):
+                        block_k=None, interpret=None, per_device=False):
     """Flash attention returning ``(o, lse)``.
 
     Same [B, T, H, D] API as :func:`flash_attention`, plus the per-row
@@ -538,51 +581,8 @@ def flash_attention_lse(q, k, v, causal=True, scale=None, block_q=None,
     ring step.  Differentiable in both outputs via custom VJP: the ``lse``
     cotangent folds into the ``delta`` operand of the same Pallas backward
     kernels (``ds += p * dlse``), so the merged-partials form trains
-    end-to-end.  Off-TPU falls back to the lax blockwise kernel unless
-    ``interpret=True``.
+    end-to-end.  Under a mesh, outside a ``shard_map`` body, it is the lax
+    blockwise kernel.
     """
     return _attend(q, k, v, causal, scale, block_q, block_k, interpret,
-                   with_lse=True)
-
-
-def flash_self_attention(q, k, v, causal=True, batch_axis="dp",
-                         head_axis="tp"):
-    """Mesh-aware flash attention: q/k/v [B, T, H, D] with batch possibly
-    sharded on ``batch_axis`` and heads on ``head_axis``.
-
-    GSPMD cannot partition a custom call, so under an active mesh the kernel
-    is wrapped in ``shard_map`` over the batch/head axes (attention is
-    independent per batch element and head; sequence stays local — the
-    sequence-sharded case is `parallel.ring_attention`).  Without a mesh, or
-    off-TPU, dispatches straight to :func:`flash_attention`.
-    """
-    from ...parallel.mesh import current_mesh
-    mesh = current_mesh()
-    if jax.default_backend() != "tpu" or mesh is None:
-        return flash_attention(q, k, v, causal=causal)
-    b = batch_axis if mesh.size(batch_axis) > 1 else None
-    h = head_axis if mesh.size(head_axis) > 1 else None
-    if b is None and h is None:
-        return flash_attention(q, k, v, causal=causal)
-    if (b is not None and q.shape[0] % mesh.size(batch_axis)) or \
-            (h is not None and q.shape[2] % mesh.size(head_axis)):
-        # shard_map needs exact divisibility; under a mesh the raw pallas
-        # call is unpartitionable by GSPMD, so fall back to the blockwise
-        # lax path (which GSPMD shards/replicates freely)
-        from ...parallel.ring_attention import blockwise_attention
-        return blockwise_attention(q, k, v, causal=causal)
-    from ...parallel.collectives import shard_map
-    from jax.sharding import PartitionSpec as P
-    spec = P(b, None, h, None)
-    fn = functools.partial(flash_attention, causal=causal)
-    return shard_map(fn, mesh=mesh.mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
-
-
-def _blockwise_fallback(q, k, v, causal=True, scale=None, interpret=None):
-    from ...parallel.ring_attention import blockwise_attention
-    return blockwise_attention(q, k, v, causal=causal, scale=scale)
-
-
-register_impl("flash_attention", pallas=flash_attention,
-              fallback=_blockwise_fallback, sharded=flash_self_attention)
+                   per_device=per_device, with_lse=True)

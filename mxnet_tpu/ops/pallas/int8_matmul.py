@@ -10,10 +10,10 @@ separate dequantize pass over an int32 intermediate in HBM.
 
 Layouts match `ops/quantization.py`'s FullyConnected: ``a`` is activations
 [M, K] int8, ``b`` is the weight [N, K] int8 (contraction over K on both),
-``scale_b`` may be per-output-channel [N].  Off-TPU the public entry falls
-back to the XLA lowering (`int8_matmul_lax`, identical math — the parity
-oracle); ``interpret=True`` runs the real kernel through the Pallas
-interpreter for CPU parity tests.  See docs/KERNELS.md.
+``scale_b`` may be per-output-channel [N].  Where ``common.kernel_impl``
+says so the public entry takes the XLA lowering (`int8_matmul_lax`,
+identical math — the parity oracle); ``interpret=True`` runs the real kernel
+through the Pallas interpreter for CPU parity tests.  See docs/KERNELS.md.
 """
 from __future__ import annotations
 
@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import _round_up, register_impl, select_impl
+from .common import _round_up, kernel_impl
 
 __all__ = ["int8_matmul", "int8_matmul_lax"]
 
@@ -149,21 +149,16 @@ def int8_matmul(a, b, scale_a=None, scale_b=None, block_m=None, block_n=None,
     ``scale_b`` (scalar or per-output-channel [N], weight scale) the product
     is dequantized in-register on the output tile -> f32 (fused dequant).
 
-    ``interpret=None`` routes through the ``select_impl`` registry
-    (``MXTPU_PALLAS``): Pallas on single-device TPU, XLA lowering elsewhere.
+    ``interpret=None`` asks ``common.kernel_impl`` (``MXTPU_PALLAS``): the
+    kernel on a single-device TPU, the XLA lowering elsewhere.
     ``interpret=True``/``False`` force the kernel through the interpreter /
-    compiled, bypassing selection.
+    compiled.
     """
-    if interpret is not None:
-        return _int8_matmul_pallas(a, b, scale_a, scale_b, block_m=block_m,
-                                   block_n=block_n, block_k=block_k,
-                                   interpret=interpret)
-    fn, impl = select_impl("int8_matmul")
-    if impl == "fallback":
-        return fn(a, b, scale_a, scale_b)
-    return fn(a, b, scale_a, scale_b, block_m=block_m, block_n=block_n,
-              block_k=block_k)
-
-
-register_impl("int8_matmul", pallas=_int8_matmul_pallas,
-              fallback=int8_matmul_lax)
+    if interpret is None:
+        impl = kernel_impl("int8_matmul")
+        if impl == "fallback":
+            return int8_matmul_lax(a, b, scale_a, scale_b)
+        interpret = impl == "interpret"
+    return _int8_matmul_pallas(a, b, scale_a, scale_b, block_m=block_m,
+                               block_n=block_n, block_k=block_k,
+                               interpret=interpret)

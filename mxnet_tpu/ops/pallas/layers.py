@@ -6,9 +6,9 @@ TPU-native replacements for the reference's fused layer kernels
 one VMEM pass instead of separate normalize/scale (RMSNorm) or
 softmax/log/gather (cross-entropy) HBM round-trips.
 
-Both ops fall back to pure-lax math off-TPU (identical semantics, used as
-the parity oracle in tests); ``interpret=True`` runs the Pallas kernels on
-CPU through the interpreter.
+Called with ``interpret=None`` both ask ``common.kernel_impl`` and take the
+kernel or the same mathematics in lax (the parity oracle in tests);
+``interpret=True`` / ``False`` force the kernel, interpreted or compiled.
 """
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import _NEG, _mesh_active, _round_up, register_impl
+from .common import _NEG, _round_up, kernel_impl
 
 __all__ = ["fused_rmsnorm", "fused_softmax_xent"]
 
@@ -88,14 +88,13 @@ _rmsnorm_op.defvjp(_rmsnorm_op_fwd, _rmsnorm_op_bwd)
 def fused_rmsnorm(x, scale, eps=1e-6, interpret=None):
     """RMSNorm over the last axis: ``x * rsqrt(mean(x^2) + eps) * scale``.
 
-    x: [..., E]; scale: [E].  Pallas kernel on TPU, lax fallback elsewhere.
+    x: [..., E]; scale: [E].
     """
     if interpret is None:
-        interpret = False
-        if jax.default_backend() != "tpu" or _mesh_active():
-            # off-TPU, or under an active mesh (GSPMD can't partition the
-            # custom call): identical lax math, which XLA fuses/shards
+        impl = kernel_impl("fused_rmsnorm")
+        if impl == "fallback":
             return _rmsnorm_lax(x, scale, eps)
+        interpret = impl == "interpret"
     E = x.shape[-1]
     lead = x.shape[:-1]
     N = 1
@@ -237,9 +236,10 @@ def fused_softmax_xent(logits, labels, interpret=None):
     probability tensor in a separate pass).
     """
     if interpret is None:
-        interpret = False
-        if jax.default_backend() != "tpu" or _mesh_active():
+        impl = kernel_impl("fused_softmax_xent")
+        if impl == "fallback":
             return _xent_lax(logits, labels)
+        interpret = impl == "interpret"
     V = logits.shape[-1]
     lead = logits.shape[:-1]
     N = 1
@@ -261,16 +261,3 @@ def fused_softmax_xent(logits, labels, interpret=None):
         loss = loss[:N]
     return loss.reshape(lead)
 
-
-def _rmsnorm_fallback(x, scale, eps=1e-6, interpret=None):
-    return _rmsnorm_lax(x, scale, eps)
-
-
-def _xent_fallback(logits, labels, interpret=None):
-    return _xent_lax(logits, labels)
-
-
-register_impl("fused_rmsnorm", pallas=fused_rmsnorm,
-              fallback=_rmsnorm_fallback)
-register_impl("fused_softmax_xent", pallas=fused_softmax_xent,
-              fallback=_xent_fallback)

@@ -82,7 +82,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import _round_up, register_impl
+from .common import _round_up, kernel_impl
 
 __all__ = ["selective_scan", "selective_scan_lax"]
 
@@ -621,8 +621,7 @@ _scan.defvjp(_scan_fwd, _scan_bwd)
 # the same chunked mathematics in lax
 # ---------------------------------------------------------------------------
 
-def selective_scan_lax(u, delta, A, B, C, D, z, chunk=None, d_block=None,
-                       interpret=None):
+def selective_scan_lax(u, delta, A, B, C, D, z, chunk=None):
     """The recurrence as a ``lax.scan`` over chunks whose body, a scan over
     the chunk's steps, is recomputed in the backward: the states kept are
     those at the chunk boundaries, as in the kernel."""
@@ -667,17 +666,19 @@ def selective_scan(u, delta, A, B, C, D, z, chunk=None, d_block=None,
 
     u, delta, z: [batch, T, Di]; A: [Di, N] (negative: ``-exp(A_log)``);
     B, C: [batch, T, N]; D: [Di].  Returns ``(C_t . h_t + D u_t) * silu(z_t)``
-    as [batch, T, Di] in ``u``'s type.  On the TPU the Pallas kernels above
-    (differentiable in every operand through their own backward kernel);
-    elsewhere :func:`selective_scan_lax`.  ``interpret=True`` runs the
-    kernels through the Pallas interpreter.  ``chunk`` / ``d_block``
+    as [batch, T, Di] in ``u``'s type.  With ``interpret=None`` by
+    ``common.kernel_impl``: the Pallas kernels above (differentiable in
+    every operand through their own backward kernel) or
+    :func:`selective_scan_lax`.  ``interpret=True`` / ``False`` force the
+    kernels, interpreted or compiled.  ``chunk`` / ``d_block``
     override what ``_choose_blocks`` takes from the shape (a chunk is a
     multiple of 16, and of 128 where it is longer).
     """
     if interpret is None:
-        interpret = False
-        if jax.default_backend() != "tpu":
+        impl = kernel_impl("selective_scan")
+        if impl == "fallback":
             return selective_scan_lax(u, delta, A, B, C, D, z, chunk=chunk)
+        interpret = impl == "interpret"
     Bt, T, Di = u.shape
     N = A.shape[1]
     auto = _choose_blocks(T, Di, N)
@@ -702,6 +703,3 @@ def selective_scan(u, delta, A, B, C, D, z, chunk=None, d_block=None,
                 wide(z), chunk, d_block, interpret)
     return out[:, :T, :Di]
 
-
-register_impl("selective_scan", pallas=selective_scan,
-              fallback=selective_scan_lax)

@@ -31,13 +31,12 @@ def _scale(mn, mx, qmax=INT8_MAX):
 
 
 def _int8_dot(data, weight, scale_a=None, scale_b=None):
-    """int8 [M, K] x int8 [N, K] contraction via the Pallas kernel registry
-    (``select_impl('int8_matmul')``, docs/KERNELS.md).  Without scales the
-    raw int32 accumulator; with them the fused in-register dequant -> f32."""
-    from .pallas.common import select_impl
+    """int8 [M, K] x int8 [N, K] contraction (``ops.pallas.int8_matmul``,
+    docs/KERNELS.md).  Without scales the raw int32 accumulator; with them
+    the fused in-register dequant -> f32."""
+    from .pallas.int8_matmul import int8_matmul
 
-    fn, _ = select_impl("int8_matmul")
-    return fn(data, weight, scale_a, scale_b)
+    return int8_matmul(data, weight, scale_a, scale_b)
 
 
 @register("_contrib_quantize_v2", aliases=("quantize_v2",), no_grad=True,

@@ -155,7 +155,7 @@ def _ring_attention_flash(q, k, v, axis_name, causal, scale, interpret):
     def blk(k_blk, v_blk, blk_causal):
         o, lse = flash_attention_lse(
             q, k_blk, v_blk, causal=blk_causal, scale=scale,
-            interpret=(True if interpret else None))
+            interpret=(True if interpret else None), per_device=True)
         return o.astype(jnp.float32), lse
 
     o, lse = blk(k, v, causal)

@@ -8,6 +8,7 @@ import subprocess
 import sys
 
 import jax
+import pytest
 
 import mxnet_tpu as mx
 
@@ -20,8 +21,10 @@ try:
 finally:
     sys.path.remove(REPO)
 
+# dense_attn_max_score_mb=0: flash at every size, as the full-width legs
 TOY_LM = dict(vocab_size=96, d_model=32, n_heads=2, n_layers=1, d_ff=64,
-              max_len=64, dtype="float32", remat=False)
+              max_len=64, dtype="float32", remat=False,
+              dense_attn_max_score_mb=0)
 TOY_HYBRID = dict(TOY_LM, n_layers=2, n_kv_heads=1, mlp="swiglu",
                   tie_embeddings=True, layer_types=("mamba", "attention"),
                   ssm_state=8, ssm_dt_rank=4)
@@ -51,13 +54,26 @@ def test_leg_lm_train_and_multichip_toy(monkeypatch):
                                   "fused_rmsnorm": {"interpret": 5},
                                   "fused_softmax_xent": {"interpret": 1},
                                   "selective_scan": {"interpret": 1}}
+    # a leg that says its attention stays dense by the model's own gate
+    # (the full-width wide LM) counts no flash selection, and one that says
+    # so of a model that takes flash fails
+    dense = chip_smoke.leg_lm_train(dict(TOY_LM, dense_attn_max_score_mb=768),
+                                    batch=2, seq=32, steps=2,
+                                    expect_impl="interpret", flash=False)
+    assert dense["selected"] == {"fused_rmsnorm": {"interpret": 3},
+                                 "fused_softmax_xent": {"interpret": 1}}
+    with pytest.raises(chip_smoke.SmokeFailure, match="expects dense"):
+        chip_smoke.leg_lm_train(TOY_LM, batch=2, seq=32, steps=2,
+                                expect_impl="interpret", flash=False)
     mesh = chip_smoke.leg_multichip(TOY_LM, batch=2, seq=32,
                                     ref_loss=out["losses"][0],
                                     devices=jax.devices()[:4])
     assert mesh["param_devices"] == 4
-    # under a mesh select_impl must not offer the unpartitionable kernels
-    assert all("interpret" not in by_impl
-               for by_impl in mesh["selected"].values())
+    # under a mesh kernel_impl must not offer the unpartitionable kernels
+    assert mesh["selected"] == {"flash_attention": {"fallback": 1},
+                                "fused_rmsnorm": {"fallback": 3},
+                                "fused_softmax_xent": {"fallback": 1},
+                                "selective_scan": {}}
 
 
 def test_leg_kernel_parity_toy():

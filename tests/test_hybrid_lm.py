@@ -178,9 +178,21 @@ def test_hybrid_trains_and_the_kernels_through_the_interpreter_agree(
         monkeypatch):
     """Three steps of ``make_train_step`` with the lax paths, then the same
     with every Pallas kernel (scan, flash, rmsnorm, xent) through the
-    interpreter: same losses, same parameters."""
-    model = TransformerLM(TransformerConfig(**HYBRID))
+    interpreter: same losses, same parameters.  The dense gate is at 0, so
+    that attention is flash at this toy size (dense would pass this test
+    without a kernel): flash forward and backward inside the model, under
+    remat and the layer scan, with the shared key/value head broadcast."""
+    from mxnet_tpu import telemetry
+    model = TransformerLM(TransformerConfig(
+        **dict(HYBRID, dense_attn_max_score_mb=0)))
     x, y = tokens(seq=32)
+    kernels = ("selective_scan", "flash_attention", "fused_rmsnorm",
+               "fused_softmax_xent")
+
+    def selected(impl):
+        counters = telemetry.registry().snapshot()["counters"]
+        return [int(counters.get("pallas.select.%s.%s" % (k, impl), 0))
+                for k in kernels]
 
     def three_steps():
         p = model.init(jax.random.PRNGKey(0))
@@ -192,10 +204,15 @@ def test_hybrid_trains_and_the_kernels_through_the_interpreter_agree(
             losses.append(float(loss))
         return losses, p
 
+    before = selected("fallback")
     losses, p = three_steps()
     assert losses[2] < losses[0]
+    assert all(b > a for a, b in zip(before, selected("fallback")))
     monkeypatch.setenv("MXTPU_PALLAS", "interpret")
+    before = selected("interpret")
     losses_k, p_k = three_steps()
+    # every kernel was asked for, and through the interpreter
+    assert all(b > a for a, b in zip(before, selected("interpret")))
     np.testing.assert_allclose(losses_k, losses, rtol=2e-5)
     for name in p:
         np.testing.assert_allclose(p_k[name], p[name], rtol=1e-3, atol=2e-5,
@@ -239,7 +256,11 @@ def test_the_dense_lm_traces_the_program_it_traced_before_layer_types(
     """``pythia-1.4b-sizes`` (its rehearsal sizes) through
     ``jax.jit(make_train_step)`` lowers to the text it lowered to at the
     commit before ``layer_types``, ``n_kv_heads``, ``mlp`` and
-    ``tie_embeddings`` existed (sha256 taken on that commit with this JAX).
+    ``tie_embeddings`` existed (sha256 taken on that commit with this JAX),
+    but for one thing: since PR 30 the layer norms' lax form is
+    ``layers._rmsnorm_lax`` (``xf * xf``) and no longer the model's own copy
+    (``jnp.square(xf)``, whose derivative is written ``2 x dx``).  With the
+    copy put back the text is the old one, byte for byte.
     A PR that means to change the dense LM's program records the new
     digest here and says so."""
     monkeypatch.setenv("MXTPU_PALLAS", "off")
@@ -257,4 +278,58 @@ def test_the_dense_lm_traces_the_program_it_traced_before_layer_types(
                    donate_argnums=(0, 1)).lower(shapes, shapes, tok,
                                                 tok).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "7d3dc56af5e81507bf714312a62ad736ec9413ee1874db678b616ffa773eb277")
+        "6be2beb41200df101e5cfd39e19e5f5946a09a0dce5b2b3ab31c37d567a5ef5e")
+
+
+def test_the_models_name_no_implementation_and_contract_each_weight_once():
+    """Which kernel runs is the kernel's entry point's business, and the
+    block is written once: one function a weight."""
+    import inspect
+    from mxnet_tpu.models import transformer
+    for module in (transformer, mamba):
+        src = inspect.getsource(module)
+        for word in ('"pallas"', '"interpret"', '"fallback"', '"sharded"',
+                     "select_impl", "kernel_impl", "logsumexp", "rsqrt"):
+            assert word not in src, (module.__name__, word)
+    src = inspect.getsource(transformer)
+    for leaf in ("wqkv", "wo", "w_up", "w_down", "w_gate"):
+        assert src.count('bp["%s"]' % leaf) == 1, leaf
+    for gone in ("with_kv", "_ssm_block", "def attn_half", "def mlp_half"):
+        assert gone not in src, gone
+
+
+@pytest.mark.parametrize("pad_pages", [0, 3])
+def test_prefill_and_decode_run_the_one_block_and_agree_with_apply(
+        monkeypatch, pad_pages):
+    """``prefill`` and ``decode_step`` trace ``_block`` once each (one scan
+    body for both layers) and give the full forward's logits position by
+    position, with a page table padded past the pages in use and beside an
+    inactive slot."""
+    model = TransformerLM(TransformerConfig(**dict(TOY, n_layers=2)))
+    p = model.init(jax.random.PRNGKey(0))
+    block, calls = model._block, []
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return block(*a, **kw)
+
+    monkeypatch.setattr(model, "_block", counted)
+    ps, n_prompt, n = 4, 6, 11
+    seq = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, 128)
+    full = model.apply(p, seq[None])[0][0]
+    calls.clear()
+    table = jnp.asarray([1, 2, 3] + [0] * pad_pages, jnp.int32)
+    kp, vp = model.init_kv_pages(5, ps)
+    prompt = jnp.zeros((1, 8), jnp.int32).at[0, :n_prompt].set(seq[:n_prompt])
+    kp, vp, logits = model.prefill(p, kp, vp, prompt, n_prompt, table)
+    assert calls == [1]
+    np.testing.assert_allclose(logits, full[n_prompt - 1], rtol=1e-5,
+                               atol=1e-5)
+    step = jax.jit(model.decode_step)
+    tables = jnp.stack([table, jnp.zeros_like(table)])
+    for t in range(n_prompt, n):
+        kp, vp, logits = step(
+            p, kp, vp, jnp.stack([seq[t], seq[0]]), tables,
+            jnp.asarray([t, 0], jnp.int32), jnp.asarray([True, False]))
+        np.testing.assert_allclose(logits[0], full[t], rtol=1e-5, atol=1e-5)
+    assert calls == [1, 1]

@@ -11,8 +11,9 @@ import pytest
 
 from mxnet_tpu.ops.pallas import (flash_attention, flash_attention_lse,
                                   fused_rmsnorm, fused_softmax_xent,
-                                  int8_matmul, int8_matmul_lax, kernel_unit,
-                                  select_impl)
+                                  int8_matmul, int8_matmul_lax, kernel_impl,
+                                  kernel_unit, selective_scan,
+                                  selective_scan_lax)
 from mxnet_tpu.ops.pallas.flash_attention import _flash  # noqa: F401
 from mxnet_tpu.ops.pallas.flash_attention import (_VMEM_LIMIT, _choose_tiles,
                                                    _padded, _working_set)
@@ -342,45 +343,106 @@ class TestInt8Matmul:
                                       np.asarray(int8_matmul_lax(a, w)))
 
 
-class TestSelectImpl:
-    def test_auto_on_cpu_selects_fallback(self, monkeypatch):
-        monkeypatch.delenv("MXTPU_PALLAS", raising=False)
-        fn, impl = select_impl("int8_matmul")
-        assert impl == "fallback"
-        assert fn is int8_matmul_lax
+KERNELS = ("flash_attention", "fused_rmsnorm", "fused_softmax_xent",
+           "selective_scan", "int8_matmul")
 
-    def test_interpret_mode_runs_real_kernel(self, monkeypatch):
-        monkeypatch.setenv("MXTPU_PALLAS", "interpret")
-        fn, impl = select_impl("int8_matmul")
-        assert impl == "interpret"
-        a = jnp.asarray(np.arange(-32, 32).reshape(8, 8) % 100, jnp.int8)
-        np.testing.assert_array_equal(np.asarray(fn(a, a)),
-                                      np.asarray(int8_matmul_lax(a, a)))
 
-    def test_off_forces_fallback(self, monkeypatch):
-        monkeypatch.setenv("MXTPU_PALLAS", "off")
-        for name in ("int8_matmul", "flash_attention", "fused_rmsnorm",
-                     "fused_softmax_xent"):
-            _, impl = select_impl(name)
-            assert impl == "fallback", name
+def _entry_and_lax(name):
+    """``(entry point, its lax form, operands)`` of kernel ``name``."""
+    if name == "flash_attention":
+        args = tuple(_rand(i, (1, 16, 2, 8)) for i in range(3))
+        return flash_attention, blockwise_attention, args
+    if name == "fused_rmsnorm":
+        args = (_rand(0, (4, 128)), 1.0 + 0.1 * _rand(1, (128,)))
+        return fused_rmsnorm, lambda x, s: _rmsnorm_lax(x, s, 1e-6), args
+    if name == "fused_softmax_xent":
+        labels = jax.random.randint(jax.random.PRNGKey(1), (8,), 0, 100)
+        return fused_softmax_xent, _xent_lax, (_rand(0, (8, 100)), labels)
+    if name == "selective_scan":
+        wide, narrow = (1, 16, 128), (1, 16, 8)
+        args = (_rand(0, wide), jax.nn.softplus(_rand(1, wide)),
+                -jnp.exp(_rand(2, (128, 8))), _rand(3, narrow),
+                _rand(4, narrow), _rand(5, (128,)), _rand(6, wide))
+        return selective_scan, selective_scan_lax, args
+    a = jnp.asarray(np.arange(-32, 32).reshape(8, 8) % 100, jnp.int8)
+    return int8_matmul, int8_matmul_lax, (a, a)
+
+
+def _selections(name):
+    from mxnet_tpu import telemetry
+    counters = telemetry.registry().snapshot()["counters"]
+    return {impl: counters.get("pallas.select.%s.%s" % (name, impl), 0)
+            for impl in ("pallas", "sharded", "interpret", "fallback")}
+
+
+class TestKernelImpl:
+    @pytest.mark.parametrize("mode,impl", [("auto", "fallback"),
+                                           ("off", "fallback"),
+                                           ("interpret", "interpret")])
+    @pytest.mark.parametrize("name", KERNELS)
+    def test_entry_point_called_directly_follows_the_rule(
+            self, monkeypatch, name, mode, impl):
+        """On this CPU: one selection counted, under the rule's answer, and
+        the result equal to the lax form's."""
+        monkeypatch.setenv("MXTPU_PALLAS", mode)
+        entry, lax_form, args = _entry_and_lax(name)
+        before = _selections(name)
+        out = entry(*args)
+        moved = {k: v - before[k] for k, v in _selections(name).items()
+                 if v != before[k]}
+        assert moved == {impl: 1}
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(lax_form(*args)),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("mode", ["auto", "interpret"])
+    @pytest.mark.parametrize("name", KERNELS)
+    def test_on_a_tpu_under_a_mesh_no_kernel_is_left_to_gspmd(
+            self, monkeypatch, name, mode):
+        """The backend said to be a TPU, four virtual devices as a mesh: the
+        rule offers the kernel only inside flash's ``shard_map`` wrapper,
+        and the entry point called directly does as the rule says."""
+        import importlib
+        from jax.experimental import pallas as pl
+        from mxnet_tpu.parallel import make_mesh
+        fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+        monkeypatch.setenv("MXTPU_PALLAS", mode)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        flash = name == "flash_attention"
+        want = "sharded" if flash and mode == "auto" else "fallback"
+
+        def no_kernel(*a, **kw):
+            raise AssertionError("a pallas_call under a mesh")
+
+        monkeypatch.setattr(pl, "pallas_call", no_kernel)
+        monkeypatch.setattr(fa, "_over_mesh", lambda q, *a: q)
+        entry, lax_form, args = _entry_and_lax(name)
+        with make_mesh(devices=jax.devices()[:4], dp=2, tp=2):
+            assert kernel_impl(name, sharded=flash) == want
+            # a shard_map body is its own device's business
+            assert kernel_impl(name, per_device=True) == (
+                "pallas" if mode == "auto" else "interpret")
+            before = _selections(name)
+            out = entry(*args)
+            assert _selections(name)[want] == before[want] + 1
+        if want == "sharded":
+            assert out is args[0]
+        else:
+            np.testing.assert_allclose(np.asarray(out),
+                                       np.asarray(lax_form(*args)),
+                                       rtol=1e-5, atol=1e-5)
 
     def test_invalid_mode_raises(self, monkeypatch):
         monkeypatch.setenv("MXTPU_PALLAS", "sideways")
         with pytest.raises(ValueError, match="MXTPU_PALLAS"):
-            select_impl("int8_matmul")
+            kernel_impl("int8_matmul")
 
-    def test_unknown_kernel_raises(self):
-        with pytest.raises(KeyError):
-            select_impl("no_such_kernel")
-
-    def test_selection_counter_bumped(self, monkeypatch):
-        from mxnet_tpu import telemetry
+    def test_a_forced_kernel_counts_no_selection(self, monkeypatch):
         monkeypatch.setenv("MXTPU_PALLAS", "off")
-        c = telemetry.registry().counter(
-            "pallas.select.flash_attention.fallback")
-        before = c.value
-        select_impl("flash_attention")
-        assert c.value == before + 1
+        a = jnp.asarray(np.arange(-32, 32).reshape(8, 8) % 100, jnp.int8)
+        before = _selections("int8_matmul")
+        int8_matmul(a, a, interpret=True)
+        assert _selections("int8_matmul") == before
 
     def test_kernel_unit_memoized_and_labeled(self):
         from mxnet_tpu.dispatch import TrackedJit

@@ -2,7 +2,7 @@
 kernels through the interpreter and the chunked lax fallback against a
 step-by-step ``lax.scan`` from a zero state, forward and every gradient
 (``u``, ``delta``, ``B``, ``C``, ``A_log``, ``D``, ``z``); the block chooser;
-the registry entry and the trace-time telemetry."""
+which implementation the entry point takes and the trace-time telemetry."""
 import importlib
 
 import jax
@@ -12,7 +12,7 @@ import pytest
 from jax import lax
 
 from mxnet_tpu import telemetry
-from mxnet_tpu.ops.pallas import select_impl
+from mxnet_tpu.ops.pallas import kernel_impl
 
 ss = importlib.import_module("mxnet_tpu.ops.pallas.selective_scan")
 
@@ -205,9 +205,14 @@ def test_a_chunk_is_whole_loop_bodies_and_whole_tiles_of_columns(chunk):
                           chunk=chunk, interpret=True)
 
 
-def test_registry_entry_and_trace_time_telemetry(monkeypatch):
-    fn, impl = select_impl("selective_scan")
-    assert impl == "fallback" and fn is ss.selective_scan_lax
+def test_off_the_tpu_the_entry_point_is_the_lax_form(monkeypatch):
+    assert kernel_impl("selective_scan") == "fallback"
+    monkeypatch.setattr(ss, "selective_scan_lax", lambda *a, **kw: "lax")
+    args, _w = operands(1, 16, 128, 8)
+    assert ss.selective_scan(*args) == "lax"
+
+
+def test_the_rule_and_trace_time_telemetry(monkeypatch):
     monkeypatch.setenv("MXTPU_PALLAS", "interpret")
     reg = telemetry.registry()
 
@@ -218,10 +223,9 @@ def test_registry_entry_and_trace_time_telemetry(monkeypatch):
         "pallas.select.selective_scan.interpret",
         "pallas.ssm_scan.chunk.fwd.16x128",
         "pallas.ssm_scan.chunk.bwd.16x128")}
-    fn, impl = select_impl("selective_scan")
-    assert impl == "interpret"
     args, w = operands(1, 16, 128, 8)
     A = -jnp.exp(args[2])
-    jax.grad(lambda u: jnp.sum(fn(u, args[1], A, *args[3:]) * w))(args[0])
+    jax.grad(lambda u: jnp.sum(
+        ss.selective_scan(u, args[1], A, *args[3:]) * w))(args[0])
     for k, v in before.items():
         assert count(k) == v + 1, k

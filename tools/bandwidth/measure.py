@@ -93,7 +93,7 @@ def _host_device_rows(sizes, iters):
             return out
 
         t_d2h, _ = _timed(d2h_n, iters)
-        print("%12.2f %14.2f %14.2f" % (
+        print("%12.2f %14.4g %14.4g" % (
             host.nbytes / 1e6, host.nbytes / t_h2d / 1e9,
             host.nbytes / t_d2h / 1e9))
 
@@ -159,7 +159,7 @@ def main():
 
                 dt, _ = _timed(once, args.iters)
                 gbs = bytes_model(host.nbytes) / dt / 1e9
-                row.append("%14.2f" % gbs)
+                row.append("%14.4g" % gbs)
             print(" ".join(row))
 
 
