@@ -19,13 +19,18 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 # the three inner norms are 160 and 16 wide: the plain lax form, not the
 # kernel the layer norms take
 from ..ops.pallas.layers import _rmsnorm_lax as rms
 from ..ops.pallas.selective_scan import selective_scan
 
-__all__ = ["mamba_mixer", "mamba_leaf_shapes", "mamba_init"]
+__all__ = ["mamba_mixer", "mamba_leaf_shapes", "mamba_init", "IN_PROJ_NAME"]
+
+# checkpoint_name of ``in_proj``'s output [B, T, 2 Di]: the widest product
+# of the mixer, which a rematerialised layer may keep
+IN_PROJ_NAME = "ssm_in_proj"
 
 # leaves kept in float32 whatever the model's dtype (Mamba's convention)
 F32_LEAVES = ("A_log", "D", "dt_bias")
@@ -101,7 +106,8 @@ def mamba_mixer(bp, h, cfg):
         return jnp.einsum("btf,fg->btg", x, w,
                           preferred_element_type=jnp.float32)
 
-    u, z = jnp.split(proj(h, bp["in_proj"]).astype(dt), 2, axis=-1)
+    u, z = jnp.split(checkpoint_name(proj(h, bp["in_proj"]).astype(dt),
+                                     IN_PROJ_NAME), 2, axis=-1)
     with jax.named_scope("conv"):
         u = jax.nn.silu(_causal_conv(u, bp["conv_w"], bp["conv_b"])
                         ).astype(dt)

@@ -28,13 +28,16 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.pallas import (flash_attention, fused_rmsnorm,
                           fused_softmax_xent)
+from ..ops.pallas.flash_attention import SAVED_NAMES as _FLASH_KEPT
+from ..ops.pallas.selective_scan import SAVED_NAMES as _SCAN_KEPT
 from ..parallel.sharding import ShardingRules, constraint, PartitionSpec as P
 from ..parallel.ring_attention import ring_self_attention
 from ..parallel.moe import moe_layer
-from .mamba import mamba_mixer
+from .mamba import IN_PROJ_NAME, mamba_mixer
 
 __all__ = ["TransformerConfig", "TransformerLM", "make_train_step",
            "default_rules"]
@@ -161,6 +164,17 @@ def _dense_self_attention(q, k, v, causal=True):
     return o.transpose(0, 2, 1, 3)
 
 
+# What a rematerialised layer (``remat=True``) keeps of its forward, by
+# ``checkpoint_name``: the results whose second forward costs most a byte
+# kept (PERF.md §6, PR 31) -- the selective scan's output and chunk
+# boundaries, flash attention's ``o`` and ``lse``, the Mamba ``in_proj``'s
+# output and every mixer's output before the residual add.  Everything else
+# (``wqkv``, the norms, the convolution, the MLP up to ``w_down``) the
+# backward re-makes.  A name no layer of a model produces costs nothing.
+MIXER_OUT = "mixer_out"
+KEPT = _SCAN_KEPT + _FLASH_KEPT + (IN_PROJ_NAME, MIXER_OUT)
+
+
 class TransformerLM:
     """Decoder-only LM.  Methods are pure functions over a flat param dict."""
 
@@ -220,7 +234,8 @@ class TransformerLM:
         with jax.named_scope(scope):
             h = self._rmsnorm(x, bp["ln1_scale"])
             o, state = mixer(bp, h)
-            x = x + constraint(o, "dp", "sp", None)
+            x = x + constraint(checkpoint_name(o, MIXER_OUT),
+                               "dp", "sp", None)
         with jax.named_scope("mlp"):
             x, aux = self._mlp_half(bp, x)
         return x, aux, state
@@ -455,11 +470,19 @@ class TransformerLM:
             x, a, _ = self._block(bp, x, self._ssm, scope="ssm")
             return (x, aux + a), None
 
+        def layers(carry, run_body, run):
+            """A run of layers of one kind, scanned over ``run``, their
+            slice of the stacked leaves."""
+            if cfg.remat:
+                run_body = jax.checkpoint(
+                    run_body, policy=jax.checkpoint_policies
+                    .save_only_these_names(*KEPT))
+            return lax.scan(run_body, carry, run,
+                            unroll=bool(cfg.scan_unroll))[0]
+
         carry = (x, jnp.float32(0.0))
         if not cfg.layer_types:
-            body_fn = jax.checkpoint(body) if cfg.remat else body
-            carry, _ = lax.scan(body_fn, carry, stacked,
-                                unroll=bool(cfg.scan_unroll))
+            carry = layers(carry, body, stacked)
         else:
             # one scan a run of layers of one kind, over that run's slice of
             # the common stack and of the kind's own
@@ -470,18 +493,8 @@ class TransformerLM:
             for kind, lo, hi, klo, khi in cfg.layer_runs():
                 run = {k: v[lo:hi] for k, v in stacked.items()}
                 run.update({k: v[klo:khi] for k, v in own[kind].items()})
-                run_body = body if kind == "attention" else ssm_body
-                if cfg.remat:
-                    # a Mamba layer keeps the scan's output and chunk
-                    # boundaries (52 MB a layer at 4096 x 5120): the
-                    # backward re-makes everything else but does not run
-                    # the scan forward twice
-                    from ..ops.pallas.selective_scan import SAVED_NAMES
-                    run_body = jax.checkpoint(
-                        run_body, policy=jax.checkpoint_policies
-                        .save_only_these_names(*SAVED_NAMES))
-                carry, _ = lax.scan(run_body, carry, run,
-                                    unroll=bool(cfg.scan_unroll))
+                carry = layers(
+                    carry, body if kind == "attention" else ssm_body, run)
         x, aux = carry
 
         x = self._rmsnorm(x, params["final_ln_scale"])

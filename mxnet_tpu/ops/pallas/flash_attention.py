@@ -22,6 +22,9 @@ flash attention entirely; its kernel corpus lives in
 * forward saves per-row logsumexp; backward recomputes probabilities from
   (q, k, lse) in two Pallas kernels (dq over k blocks; dk/dv over q blocks)
   — no O(T^2) residuals;
+* under differentiation ``o`` and ``lse`` carry ``checkpoint_name``s
+  (``SAVED_NAMES``): a rematerialised layer that keeps both does not run
+  the forward kernel a second time;
 * operands reach the MXU in the input's dtype (``p`` and ``ds`` are cast to
   it; float32 callers keep float32 products), accumulated in f32 via
   ``preferred_element_type``; scores, softmax statistics, ``lse``,
@@ -43,12 +46,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .common import _NEG, _round_up, kernel_impl
 
-__all__ = ["flash_attention", "flash_attention_lse"]
+__all__ = ["flash_attention", "flash_attention_lse", "SAVED_NAMES"]
+
+# checkpoint_names of the forward's two results, [B, H, T, D] and
+# [B, H, T, 1]: with both kept, nothing of a second forward needs the kernel
+SAVED_NAMES = ("flash_o", "flash_lse")
 
 
 # ---------------------------------------------------------------------------
@@ -447,8 +455,20 @@ def _flash(q, k, v, causal, scale, tiles, kv_len, interpret):
     return o
 
 
-def _flash_fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
+def _named_fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
+    """The forward of both VJP rules, its results named (``SAVED_NAMES``).
+    ``lse`` is named as the kernel writes it, [B, H, T, 1]: a row of 128
+    lanes a value in HBM (67 MB at 4 x 16 x 2048 for 0.5 MB of values).
+    Named as [B, H, T] it is kept small and costs two copies a layer, 0.8 %
+    of the Pythia cell's step where the room was not needed (PERF.md §6,
+    PR 31)."""
     o, lse = _fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
+    return (checkpoint_name(o, SAVED_NAMES[0]),
+            checkpoint_name(lse, SAVED_NAMES[1]))
+
+
+def _flash_fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
+    o, lse = _named_fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
     return o, (q, k, v, o, lse)
 
 
@@ -466,7 +486,7 @@ def _flash_lse(q, k, v, causal, scale, tiles, kv_len, interpret):
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
-    o, lse = _fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
+    o, lse = _named_fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
     return (o, lse), (q, k, v, o, lse)
 
 

@@ -7,6 +7,9 @@ file, and the topology only inside a fixture: one process at a time may load
 the TPU's library."""
 import functools
 import importlib
+import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +21,6 @@ ss = importlib.import_module("mxnet_tpu.ops.pallas.selective_scan")
 
 @pytest.fixture(scope="module")
 def one_chip():
-    import os
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -196,3 +198,56 @@ def test_scan_kernels_spread_a_row_by_a_load_of_stride_0():
         found[call.params["name"]] = loads
     assert found == {"ssm_scan_fwd": 2 * ss._ROWS * groups,
                      "ssm_scan_bwd": 6 * ss._ROWS * groups}
+
+
+# (configuration, traffic, the layers of the cut, custom calls by kernel)
+LM_CASES = [
+    pytest.param("pythia-1.4b-sizes", "pretrain_b4_s2048", 2,
+                 {"flash_fwd": 2, "flash_dq": 2, "flash_dkv": 2},
+                 id="pythia14_train_2_layers"),
+    pytest.param("jamba2-3b-l14", "pretrain_b1_s4096", ("mamba", "attention"),
+                 {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1,
+                  "ssm_scan_fwd": 1, "ssm_scan_bwd": 1},
+                 id="jamba2_3b_train_mamba_and_attention"),
+]
+
+
+@pytest.mark.parametrize("config,traffic,layers,calls", LM_CASES)
+def test_lm_train_step_runs_each_kept_kernel_once_on_v5e(
+        one_chip, monkeypatch, config, traffic, layers, calls):
+    """The training step of each LM cell, cut to two layers at the cell's
+    batch and widths, through libtpu's compiler: what the rematerialised
+    layer keeps by name (``models.transformer.KEPT``) reaches through
+    ``custom_vjp`` and the compiler, so the optimized program holds one
+    flash forward an attention layer and one scan forward a Mamba layer,
+    not two (the backward's kernels once, as ever)."""
+    from mxnet_tpu.models import TransformerConfig, TransformerLM
+    from mxnet_tpu.models.transformer import make_train_step
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(root, "configs", config + ".json")) as f:
+        m = json.load(f)["model"]
+    with open(os.path.join(root, "traffic", traffic + ".json")) as f:
+        t = json.load(f)
+    if isinstance(layers, tuple):
+        m.update(layer_types=layers, n_layers=len(layers))
+    else:
+        m.update(n_layers=layers)
+    # the kernel rule sees the CPU this test runs on: tell it the backend
+    # the program is compiled for
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(TransformerConfig(**m))
+    state = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    tok = jax.ShapeDtypeStruct((t["batch"], t["seq"]), jnp.int32,
+                               sharding=one_chip)
+    # the precision the cells run at, not the suite's ``highest``
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(make_train_step(model), donate_argnums=(0, 1)).lower(
+            state, state, tok, tok).compile().as_text()
+    found = {}
+    for name in re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*custom-call\([^\n]*"
+                           r"tpu_custom_call", text):
+        found[name] = found.get(name, 0) + 1
+    assert {k: found.get(k, 0) for k in calls} == calls, found
