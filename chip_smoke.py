@@ -268,7 +268,11 @@ def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100, 1100),
                       xent_rows=1024, vocab=32000,
                       mm_shapes=((512, 768, 1024), (100, 70, 200)),
                       scan_shapes=((2, 300, 256, 16), (1, 1100, 640, 16)),
-                      scan_cell_shape=(1, 4096, 5120, 16)):
+                      scan_cell_shape=(1, 4096, 5120, 16),
+                      mla_shape=(1, 4096, 16, 192, 128),
+                      gmm_shapes=((1000, 96, 40, (300, 0, 513, 100)),),
+                      gmm_cell_shape=(24576, 2048, 1408,
+                                      (384,) * 31 + (501,))):
     """Every Pallas kernel (compiled unless ``interpret``) against its lax
     reference on the same device, forward and gradient.
 
@@ -289,6 +293,7 @@ def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100, 1100),
 
     from mxnet_tpu.ops.pallas.flash_attention import (flash_attention,
                                                        flash_attention_lse)
+    from mxnet_tpu.ops.pallas.grouped_matmul import grouped_matmul
     from mxnet_tpu.ops.pallas.int8_matmul import (int8_matmul,
                                                    int8_matmul_lax)
     from mxnet_tpu.ops.pallas.layers import (fused_rmsnorm,
@@ -489,6 +494,52 @@ def leg_kernel_parity(interpret=False, seqs=(1024, 1000, 100, 1100),
             lambda *a: selective_scan(*a, interpret=interpret),
             scan_reference, scan_args(*scan_cell_shape, jnp.bfloat16),
             weighted(rand(18, scan_cell_shape[:3])), tuple(range(7)))
+
+    # -- flash attention at a value width of its own (latent attention:
+    # scores over D, values over Dv), bfloat16, against dense attention ----
+    Bm, Tm, Hm, Dm, Dvm = mla_shape
+    mla_scale = 1.58963 * Dm ** -0.5
+
+    def dense_two_widths(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * mla_scale
+        s = jnp.where(jnp.tril(jnp.ones((Tm, Tm), bool)), s, -1e30)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+    compare("flash_attention bfloat16 %dx%dx%dx%d/%d" % mla_shape,
+            lambda q, k, v: flash_attention(q, k, v, scale=mla_scale,
+                                            interpret=interpret),
+            dense_two_widths,
+            [rand(20, (Bm, Tm, Hm, Dm), jnp.bfloat16),
+             rand(21, (Bm, Tm, Hm, Dm), jnp.bfloat16),
+             rand(22, (Bm, Tm, Hm, Dvm), jnp.bfloat16)],
+            weighted(rand(23, (Bm, Tm, Hm, Dvm))), (0, 1, 2))
+
+    # -- grouped_matmul: against every group's rows, masked, by its matrix,
+    # one group after another; an empty group, sizes off the tile, rows past
+    # the last group; float32, and the expert cell's own shape in bfloat16 --
+    for (M, K, N, sizes), dtype in ([(g, jnp.float32) for g in gmm_shapes]
+                                    + [(gmm_cell_shape, jnp.bfloat16)]):
+        gs = jnp.asarray(sizes, jnp.int32)
+        group_of_row = jnp.searchsorted(jnp.cumsum(gs), jnp.arange(M),
+                                        side="right")
+
+        def group_by_group(x, w, group_of_row=group_of_row):
+            def add(acc, gw):
+                g, wg = gw
+                return acc + jnp.where((group_of_row == g)[:, None], x,
+                                       0.0) @ wg, None
+            return jax.lax.scan(add, jnp.zeros((x.shape[0], w.shape[2])),
+                                (jnp.arange(w.shape[0]), w))[0]
+
+        compare("grouped_matmul %s %dx%dx%d" % (jnp.dtype(dtype).name, M, K,
+                                                N),
+                lambda x, w, gs=gs: grouped_matmul(x, w, gs,
+                                                   interpret=interpret),
+                group_by_group,
+                [rand(24, (M, K), dtype),
+                 (rand(25, (len(sizes), K, N)) / K ** 0.5).astype(dtype)],
+                weighted(rand(26, (M, N))), (0, 1), (2e-5, 2e-5),
+                (2e-4, 2e-4))
 
     # -- int8_matmul: int32 path bit-identical, fused dequant close --------
     for M, N, K in mm_shapes:

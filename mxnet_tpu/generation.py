@@ -461,8 +461,8 @@ class GenerationEngine:
         self.model = model
         self.params = params
         self.cfg = config or GenerationConfig()
-        if model.cfg.use_moe:
-            raise NotImplementedError("paged decode does not support MoE yet")
+        if model.cfg.has_experts or model.cfg.attention == "mla":
+            model._refuse_serving()     # names what serving still refuses
         self.page_size = int(self.cfg.page_size)
         self.max_seq = int(self.cfg.max_seq_len or model.cfg.max_len)
         self.pages_per_seq = -(-self.max_seq // self.page_size)

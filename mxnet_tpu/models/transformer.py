@@ -5,8 +5,11 @@ Everything the reference lacked for long-context/distributed (SURVEY.md §2.4,
 (``fsdp``), tensor parallel (``tp`` — Megatron-style column/row splits
 expressed *declaratively* as GSPMD shardings, XLA inserts the collectives),
 sequence parallel via ring attention (``sp``, `parallel/ring_attention.py`),
-expert parallel MoE (``ep``, `parallel/moe.py`), and a GPipe pipeline variant
-(``pp``, `parallel/pipeline.py`).
+routed experts (`parallel/moe.py`: a chip's share of them without dropped
+tokens; the capacity dispatch over ``ep``), and a GPipe pipeline variant
+(``pp``, `parallel/pipeline.py`).  A layer's attention half is fused-QKV
+attention, latent attention (`models/mla.py`) or a Mamba mixer
+(`models/mamba.py`); its MLP half dense or experts, by layer.
 
 Design notes (TPU-first):
 * parameters are a flat ``{name: jax.Array}`` dict; layer stacks use a leading
@@ -36,8 +39,9 @@ from ..ops.pallas.flash_attention import SAVED_NAMES as _FLASH_KEPT
 from ..ops.pallas.selective_scan import SAVED_NAMES as _SCAN_KEPT
 from ..parallel.sharding import ShardingRules, constraint, PartitionSpec as P
 from ..parallel.ring_attention import ring_self_attention
-from ..parallel.moe import moe_layer
+from ..parallel.moe import expert_layer, moe_layer
 from .mamba import IN_PROJ_NAME, mamba_mixer
+from .mla import mla_leaf_shapes, mla_mixer
 
 __all__ = ["TransformerConfig", "TransformerLM", "make_train_step",
            "default_rules"]
@@ -83,10 +87,45 @@ class TransformerConfig:
     ssm_state: int = 16
     ssm_dt_rank: int = 0                  # 0: ceil(d_model / 16)
     ssm_conv: int = 4
+    # "mha": the fused-QKV attention; "mla": latent attention
+    # (`models/mla.py`) with the widths below and no ``wqkv``.  Training
+    # path only: the paged pool keeps no latent (ROADMAP R-m2).
+    attention: str = "mha"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # rotary term of the ``mla`` mixer's rotary part (nothing else has a
+    # positional term, ROADMAP R-m1); ``rope_factor`` > 1 is YaRN
+    rope_theta: float = 10000.0
+    rope_factor: float = 1.0
+    rope_orig_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # One entry a layer, "dense" or "moe": which layers' MLP half is routed
+    # experts (`parallel/moe.py::expert_layer`); () is dense everywhere, or
+    # with ``use_moe`` experts everywhere.  With it the dense layers'
+    # matrices stack under ``dense.`` and the expert layers' under ``moe.``.
+    # An expert has the form ``mlp`` names at width ``moe_d_ff`` (0:
+    # ``d_ff``); ``moe_shared_d_ff`` > 0 adds an always-on expert of that
+    # width beside the routed ones.  The router is over ``n_experts``;
+    # ``experts_held`` names the ones whose matrices this model holds (():
+    # all): a chip's share of an expert-parallel layer, whose result is
+    # those experts' part alone.
+    mlp_types: tuple = ()
+    moe_top_k: int = 1
+    moe_d_ff: int = 0
+    moe_shared_d_ff: int = 0
+    moe_renormalize: bool = True
+    experts_held: tuple = ()
 
     def __post_init__(self):
         # a configuration read from JSON brings a list
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "mlp_types", tuple(self.mlp_types))
+        object.__setattr__(self, "experts_held", tuple(self.experts_held))
         if not self.ssm_dt_rank:
             object.__setattr__(self, "ssm_dt_rank", -(-self.d_model // 16))
         if self.layer_types:
@@ -94,7 +133,19 @@ class TransformerConfig:
                 "layer_types names %d layers, n_layers is %d" % (
                     len(self.layer_types), self.n_layers)
             assert set(self.layer_types) <= {"attention", "mamba"}
-            assert not self.use_moe, "layer_types with MoE: not built"
+            assert not self.has_experts and self.attention == "mha", \
+                "layer_types beside experts or latent attention: the " \
+                "runs of one mixer kind and of one MLP kind are not cut " \
+                "together yet"
+        if self.mlp_types:
+            assert len(self.mlp_types) == self.n_layers, \
+                "mlp_types names %d layers, n_layers is %d" % (
+                    len(self.mlp_types), self.n_layers)
+            assert set(self.mlp_types) <= {"dense", "moe"}
+        if self.has_experts:
+            assert 1 <= self.moe_top_k <= self.n_experts
+            assert all(0 <= e < self.n_experts for e in self.experts_held)
+        assert self.attention in ("mha", "mla")
         assert self.mlp in ("gelu", "swiglu")
         assert self.n_heads % self.kv_heads == 0
 
@@ -110,19 +161,33 @@ class TransformerConfig:
     def d_inner(self):
         return self.ssm_expand * self.d_model
 
+    @property
+    def has_experts(self):
+        return self.use_moe or "moe" in self.mlp_types
+
+    @property
+    def n_held(self):
+        return len(self.experts_held) or self.n_experts
+
     def layer_runs(self):
         """``[(kind, lo, hi, kind_lo, kind_hi)]``: maximal runs of layers of
         one kind, as ranges over all layers and over the layers of that
         kind (the index into the ``attn.`` / ``ssm.`` stacks)."""
-        runs, seen = [], {"attention": 0, "mamba": 0}
-        for i, kind in enumerate(self.layer_types):
-            if runs and runs[-1][0] == kind:
-                runs[-1][2] = i + 1
-                runs[-1][4] = seen[kind] + 1
-            else:
-                runs.append([kind, i, i + 1, seen[kind], seen[kind] + 1])
-            seen[kind] += 1
-        return [tuple(r) for r in runs]
+        return _runs(self.layer_types)
+
+
+def _runs(types):
+    """Maximal runs of equal entries of ``types`` (see ``layer_runs``)."""
+    runs, seen = [], {}
+    for i, kind in enumerate(types):
+        at = seen.get(kind, 0)
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] = i + 1
+            runs[-1][4] = at + 1
+        else:
+            runs.append([kind, i, i + 1, at, at + 1])
+        seen[kind] = at + 1
+    return [tuple(r) for r in runs]
 
 
 def default_rules() -> ShardingRules:
@@ -132,11 +197,15 @@ def default_rules() -> ShardingRules:
     return ShardingRules([
         (r"embed",        P("tp", "fsdp")),
         (r".*wqkv",       P(None, "fsdp", "tp")),
+        (r".*wq",         P(None, "fsdp", "tp")),
+        (r".*wkv_a",      P(None, "fsdp", None)),
+        (r".*wkv_b",      P(None, None, "tp")),
         (r".*wo",         P(None, "tp", "fsdp")),
-        (r".*w_up",       P(None, "fsdp", "tp")),
-        (r".*w_gate",     P(None, "fsdp", "tp")),
-        (r".*w_down",     P(None, "tp", "fsdp")),
+        (r".*(w|shared)_up",   P(None, "fsdp", "tp")),
+        (r".*(w|shared)_gate", P(None, "fsdp", "tp")),
+        (r".*(w|shared)_down", P(None, "tp", "fsdp")),
         (r".*moe_up",     P(None, "ep", "fsdp", None)),
+        (r".*moe_gate",   P(None, "ep", "fsdp", None)),
         (r".*moe_down",   P(None, "ep", None, "fsdp")),
         (r".*gate",       P(None, "fsdp", None)),
         (r"unembed",      P("fsdp", "tp")),
@@ -144,7 +213,7 @@ def default_rules() -> ShardingRules:
     ])
 
 
-def _dense_self_attention(q, k, v, causal=True):
+def _dense_self_attention(q, k, v, causal=True, scale=None):
     """Plain materialized attention for short sequences, one fused QK^T ->
     softmax -> PV chain; memory is O(T^2) so the caller gates it by
     ``dense_attn_max_score_mb`` (not re-measured against the flash kernel
@@ -154,7 +223,8 @@ def _dense_self_attention(q, k, v, causal=True):
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
     s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
-                   preferred_element_type=jnp.float32) / math.sqrt(D)
+                   preferred_element_type=jnp.float32)
+    s = s / math.sqrt(D) if scale is None else s * scale
     if causal:
         mask = jnp.tril(jnp.ones((T, T), bool))
         s = jnp.where(mask, s, -1e30)
@@ -201,10 +271,18 @@ class TransformerLM:
             "embed": norm(keys[0], (cfg.vocab_size, E), E),
             "blocks.ln1_scale": jnp.ones((L, E), dt),
             "blocks.ln2_scale": jnp.ones((L, E), dt),
-            attn + "wqkv": norm(keys[1], (n_attn, E, QKV), E),
-            attn + "wo": norm(keys[2], (n_attn, HD, E), HD),
             "final_ln_scale": jnp.ones((E,), dt),
         }
+        if cfg.attention == "mla":
+            for i, (k, (shape, fan_in)) in enumerate(
+                    sorted(mla_leaf_shapes(cfg).items())):
+                p["blocks." + k] = (
+                    jnp.ones((L,) + shape, dt) if fan_in is None else
+                    norm(jax.random.fold_in(keys[1], i), (L,) + shape,
+                         fan_in))
+        else:
+            p[attn + "wqkv"] = norm(keys[1], (n_attn, E, QKV), E)
+            p[attn + "wo"] = norm(keys[2], (n_attn, HD, E), HD)
         if not cfg.tie_embeddings:
             p["unembed"] = norm(keys[3], (E, cfg.vocab_size), E)
         if cfg.layer_types:
@@ -212,15 +290,34 @@ class TransformerLM:
             for k, v in mamba_init(cfg, keys[7],
                                    L - n_attn).items():
                 p["ssm." + k] = v
-        if cfg.mlp == "swiglu":
-            p["blocks.w_gate"] = norm(keys[4], (L, E, F), E)
-        if cfg.use_moe:
-            p["blocks.gate"] = norm(keys[4], (L, E, cfg.n_experts), E)
-            p["blocks.moe_up"] = norm(keys[5], (L, cfg.n_experts, E, F), E)
-            p["blocks.moe_down"] = norm(keys[6], (L, cfg.n_experts, F, E), F)
-        else:
-            p["blocks.w_up"] = norm(keys[5], (L, E, F), E)
-            p["blocks.w_down"] = norm(keys[6], (L, F, E), F)
+        # the MLP halves: one stack under ``blocks.``, or with ``mlp_types``
+        # the dense layers' under ``dense.`` and the expert layers' under
+        # ``moe.``
+        gated = cfg.mlp == "swiglu"
+        n_moe = (cfg.mlp_types.count("moe") if cfg.mlp_types
+                 else L * cfg.use_moe)
+        dense, moe = (("dense.", "moe.") if cfg.mlp_types
+                      else ("blocks.", "blocks."))
+        if n_moe < L:
+            Ld = L - n_moe
+            if gated:
+                p[dense + "w_gate"] = norm(keys[4], (Ld, E, F), E)
+            p[dense + "w_up"] = norm(keys[5], (Ld, E, F), E)
+            p[dense + "w_down"] = norm(keys[6], (Ld, F, E), F)
+        if n_moe:
+            n, held = cfg.n_experts, cfg.n_held
+            Fe, Fs = cfg.moe_d_ff or F, cfg.moe_shared_d_ff
+            p[moe + "gate"] = norm(keys[4], (n_moe, E, n), E)
+            p[moe + "moe_up"] = norm(keys[5], (n_moe, held, E, Fe), E)
+            p[moe + "moe_down"] = norm(keys[6], (n_moe, held, Fe, E), Fe)
+            own = [jax.random.fold_in(keys[7], 100 + i) for i in range(4)]
+            if gated:
+                p[moe + "moe_gate"] = norm(own[0], (n_moe, held, E, Fe), E)
+            if Fs:
+                p[moe + "shared_up"] = norm(own[1], (n_moe, E, Fs), E)
+                p[moe + "shared_down"] = norm(own[2], (n_moe, Fs, E), Fs)
+                if gated:
+                    p[moe + "shared_gate"] = norm(own[3], (n_moe, E, Fs), E)
         return p
 
     # -- forward -------------------------------------------------------
@@ -277,36 +374,78 @@ class TransformerLM:
             # kernels' grid does anyway.
             kh = jnp.repeat(k, H // KV, axis=2)
             vh = jnp.repeat(v, H // KV, axis=2)
+        return self._attn_out(bp, self._attend(q, kh, vh, None, use_ring)
+                              ), (k, v)
+
+    def _attend(self, q, k, v, scale=None, use_ring=False):
+        """Causal attention of q, k [B, T, H, D] and v [B, T, H, Dv] by
+        the implementation the shape and the mesh call for."""
+        B, T, H, _ = q.shape
         if use_ring:
-            attn = ring_self_attention(q, kh, vh, causal=True)
-        elif B * H * T * T * 4 / 1e6 <= cfg.dense_attn_max_score_mb:
-            attn = _dense_self_attention(q, kh, vh, causal=True)
-        else:
-            attn = flash_attention(q, kh, vh, causal=True)
-        return self._attn_out(bp, attn), (k, v)
+            return ring_self_attention(q, k, v, causal=True)
+        if B * H * T * T * 4 / 1e6 <= self.cfg.dense_attn_max_score_mb:
+            return _dense_self_attention(q, k, v, causal=True, scale=scale)
+        return flash_attention(q, k, v, causal=True, scale=scale)
 
     def _ssm(self, bp, h):
         """The mixer of a state-space layer (`models/mamba.py`)."""
         return mamba_mixer(bp, h, self.cfg), None
 
-    def _mlp_half(self, bp, x):
-        cfg = self.cfg
-        h = self._rmsnorm(x, bp["ln2_scale"])
-        aux = jnp.float32(0.0)
-        if cfg.use_moe:
-            ff, aux = moe_layer(h, bp["gate"], bp["moe_up"], bp["moe_down"])
+    def _mla(self, bp, h):
+        """The mixer of a latent-attention layer (`models/mla.py`)."""
+        return mla_mixer(bp, h, self.cfg, self._attend), None
+
+    def _mlp(self, h, w_up, w_down, w_gate):
+        """``down(gelu(up(h)))``, or with ``w_gate`` ``down(silu(gate(h)) *
+        up(h))``: a dense layer's MLP and an expert layer's shared expert."""
+        up = jnp.einsum("bte,ef->btf", h, w_up,
+                        preferred_element_type=jnp.float32)
+        if w_gate is not None:
+            gate = jnp.einsum("bte,ef->btf", h, w_gate,
+                              preferred_element_type=jnp.float32)
+            up = jax.nn.silu(gate) * up
         else:
-            up = jnp.einsum("bte,ef->btf", h, bp["w_up"],
-                            preferred_element_type=jnp.float32)
-            if cfg.mlp == "swiglu":
-                gate = jnp.einsum("bte,ef->btf", h, bp["w_gate"],
-                                  preferred_element_type=jnp.float32)
-                up = jax.nn.silu(gate) * up
-            else:
-                up = jax.nn.gelu(up)
-            up = constraint(up.astype(x.dtype), "dp", "sp", "tp")
-            ff = jnp.einsum("btf,fe->bte", up, bp["w_down"],
-                            preferred_element_type=jnp.float32).astype(x.dtype)
+            up = jax.nn.gelu(up)
+        up = constraint(up.astype(h.dtype), "dp", "sp", "tp")
+        return jnp.einsum("btf,fe->bte", up, w_down,
+                          preferred_element_type=jnp.float32).astype(h.dtype)
+
+    def _experts(self, bp, h):
+        """An expert layer's MLP on the normed ``h``: the routed experts
+        held here (under a mesh with an ``ep`` axis still the capacity
+        dispatch over all of them) plus the shared expert.  Returns ``(ff,
+        [balance term, pairs that landed on held experts])``."""
+        cfg = self.cfg
+        from ..parallel.mesh import current_mesh
+        mesh = current_mesh()
+        if mesh is not None and mesh.size("ep") > 1:
+            assert cfg.mlp == "gelu" and not cfg.experts_held, \
+                "the capacity dispatch over ep: ungated experts, all held"
+            ff, aux = moe_layer(h, bp["gate"], bp["moe_up"], bp["moe_down"],
+                                top_k=cfg.moe_top_k,
+                                renormalize=cfg.moe_renormalize,
+                                act=jax.nn.gelu)
+            held = jnp.float32(0.0)
+        else:
+            ff, aux, held = expert_layer(
+                h, bp["gate"], bp["moe_up"], bp["moe_down"],
+                bp.get("moe_gate"), top_k=cfg.moe_top_k,
+                experts_held=cfg.experts_held or None,
+                renormalize=cfg.moe_renormalize)
+        if "shared_up" in bp:
+            with jax.named_scope("moe.shared"):
+                ff = ff + self._mlp(h, bp["shared_up"], bp["shared_down"],
+                                    bp.get("shared_gate"))
+        return ff, jnp.stack([aux, held])
+
+    def _mlp_half(self, bp, x):
+        h = self._rmsnorm(x, bp["ln2_scale"])
+        if "gate" in bp:                    # the router: an expert layer
+            ff, aux = self._experts(bp, h)
+        else:
+            ff = self._mlp(h, bp["w_up"], bp["w_down"],
+                           bp["w_gate"] if "w_gate" in bp else None)
+            aux = jnp.float32(0.0)
         return x + constraint(ff, "dp", "sp", None), aux
 
     # -- generative decode (paged KV cache) ----------------------------
@@ -323,11 +462,19 @@ class TransformerLM:
     # mxnet_tpu/generation.py (docs/GENERATIVE.md).
 
     def _refuse_serving(self):
-        """The paged decode path knows one block: equal head counts, a GELU
-        MLP, an untied head, attention in every layer, no experts."""
+        """The paged decode path knows one block: fused-QKV attention with
+        equal head counts in every layer, a dense GELU MLP, an untied head."""
         cfg = self.cfg
-        if cfg.use_moe:
-            raise NotImplementedError("paged decode does not support MoE yet")
+        if cfg.has_experts:
+            raise NotImplementedError(
+                "paged decode does not support expert layers yet: a decode "
+                "step's few tokens would go through the sorted dispatch "
+                "(ROADMAP R-m3)")
+        if cfg.attention == "mla":
+            raise NotImplementedError(
+                "paged decode does not support latent attention yet: the "
+                "pool would keep the latent and the rotary key, not K and "
+                "V (ROADMAP R-m2)")
         if cfg.layer_types:
             raise NotImplementedError(
                 "paged decode does not support layer_types yet: a "
@@ -342,7 +489,8 @@ class TransformerLM:
     def init_kv_pages(self, num_pages, page_size):
         """Allocate zeroed paged KV storage: ([L,P,ps,H,D], same) pair."""
         cfg = self.cfg
-        if cfg.layer_types or cfg.kv_heads != cfg.n_heads:
+        if (cfg.layer_types or cfg.kv_heads != cfg.n_heads
+                or cfg.attention == "mla"):
             self._refuse_serving()
         shape = (cfg.n_layers, num_pages, page_size, cfg.n_heads,
                  cfg.head_dim)
@@ -446,8 +594,10 @@ class TransformerLM:
                             preferred_element_type=jnp.float32)
         return k_pages, v_pages, logits
 
-    def apply(self, params, tokens):
-        """tokens [B, T] int32 -> logits [B, T, V] (f32)."""
+    def _trunk(self, params, tokens):
+        """tokens [B, T] -> the last layer's output [B, T, E] and what the
+        MLP halves handed back, summed over the layers: 0.0, or in a model
+        with expert layers ``[balance term, pairs on held experts]``."""
         cfg = self.cfg
         x = params["embed"][tokens]
         x = constraint(x, "dp", "sp", None)
@@ -459,10 +609,16 @@ class TransformerLM:
         mesh = current_mesh()
         use_ring = mesh is not None and mesh.size("sp") > 1
 
+        if cfg.attention == "mla":
+            assert not use_ring, "latent attention over sp: not built"
+            attention = self._mla
+        else:
+            attention = functools.partial(self._self_attention,
+                                          use_ring=use_ring)
+
         def body(carry, bp):
             x, aux = carry
-            x, a, _kv = self._block(bp, x, functools.partial(
-                self._self_attention, use_ring=use_ring))
+            x, a, _kv = self._block(bp, x, attention)
             return (x, aux + a), None
 
         def ssm_body(carry, bp):
@@ -480,8 +636,9 @@ class TransformerLM:
             return lax.scan(run_body, carry, run,
                             unroll=bool(cfg.scan_unroll))[0]
 
-        carry = (x, jnp.float32(0.0))
-        if not cfg.layer_types:
+        carry = (x, jnp.zeros((2,), jnp.float32) if cfg.has_experts
+                 else jnp.float32(0.0))
+        if not (cfg.layer_types or cfg.mlp_types):
             carry = layers(carry, body, stacked)
         else:
             # one scan a run of layers of one kind, over that run's slice of
@@ -489,13 +646,31 @@ class TransformerLM:
             own = {kind: {k.split(".", 1)[1]: v for k, v in params.items()
                           if k.startswith(prefix)}
                    for kind, prefix in (("attention", "attn."),
-                                        ("mamba", "ssm."))}
-            for kind, lo, hi, klo, khi in cfg.layer_runs():
+                                        ("mamba", "ssm."),
+                                        ("dense", "dense."),
+                                        ("moe", "moe."))}
+            for kind, lo, hi, klo, khi in _runs(cfg.layer_types
+                                                or cfg.mlp_types):
                 run = {k: v[lo:hi] for k, v in stacked.items()}
                 run.update({k: v[klo:khi] for k, v in own[kind].items()})
                 carry = layers(
-                    carry, body if kind == "attention" else ssm_body, run)
-        x, aux = carry
+                    carry, ssm_body if kind == "mamba" else body, run)
+        return carry
+
+    def held_slot_share(self, params, tokens):
+        """Share of the (token, slot) pairs of all expert layers that the
+        router sent to experts held here (``experts_held``)."""
+        cfg = self.cfg
+        n_moe = cfg.mlp_types.count("moe") or cfg.n_layers
+        pairs = tokens.size * cfg.moe_top_k * n_moe
+        return self._trunk(params, tokens)[1][1] / pairs
+
+    def apply(self, params, tokens):
+        """tokens [B, T] int32 -> logits [B, T, V] (f32)."""
+        cfg = self.cfg
+        x, aux = self._trunk(params, tokens)
+        if cfg.has_experts:
+            aux = aux[0]
 
         x = self._rmsnorm(x, params["final_ln_scale"])
         if cfg.tie_embeddings:

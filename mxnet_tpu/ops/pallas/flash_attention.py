@@ -6,7 +6,10 @@ flash attention entirely; its kernel corpus lives in
 `/root/reference/src/operator/nn/` and `src/operator/nn/cudnn/`).  Design:
 
 * layout [B, T, H, D] at the API (matching `parallel/ring_attention.py`),
-  [B, H, T, D] inside the kernels;
+  [B, H, T, D] inside the kernels; ``v`` (and with it ``o``, ``do``,
+  ``dv``) may have a width ``Dv`` of its own, narrower or wider than the
+  ``D`` of ``q`` and ``k`` (latent attention: 192 for the scores, 128 for
+  the values);
 * grid (B, H, num_q_blocks, num_k_blocks), ``parallel`` x 3 and
   ``arbitrary`` on the innermost (sequential) axis, so f32 VMEM scratch
   accumulators implement the streaming-softmax recurrence across k blocks
@@ -79,29 +82,33 @@ def _padded(T):
     return _round_up(T, 8) if T <= 128 else _round_up(T, 128)
 
 
-def _working_set(kernel, block_q, block_k, D, itemsize):
+def _working_set(kernel, block_q, block_k, D, itemsize, Dv=None):
     """Reckoned VMEM bytes of one grid step: the operand and result tiles
     twice (the pipeline double-buffers them), the float32 score-sized tiles
-    and their casts, the scratch accumulators.  A last dimension under 128
-    occupies 128 lanes."""
+    and their casts, the scratch accumulators.  A last dimension occupies
+    whole rows of 128 lanes.  ``Dv`` is the width of ``v``, ``o``, ``do``
+    and ``dv`` where it is not ``D``."""
     lanes = _round_up(D, 128)
-    q_tile = block_q * lanes * itemsize
-    k_tile = block_k * lanes * itemsize
+    lanes_v = lanes if Dv is None else _round_up(Dv, 128)
+    q_tile = block_q * lanes * itemsize        # q, dq
+    o_tile = block_q * lanes_v * itemsize      # o, do
+    k_tile = block_k * lanes * itemsize        # k, dk
+    v_tile = block_k * lanes_v * itemsize      # v, dv
     row = block_q * 128 * 4                    # lse / delta / m / l: (bq, 1)
     score = block_q * block_k * 4
     cast = block_q * block_k * itemsize
     if kernel == "fwd":                        # q k v -> o lse; acc m l; s p
-        return (2 * (2 * q_tile + 2 * k_tile + row)
-                + block_q * lanes * 4 + 2 * row + 2 * score + cast)
+        return (2 * (q_tile + o_tile + k_tile + v_tile + row)
+                + block_q * lanes_v * 4 + 2 * row + 2 * score + cast)
     if kernel == "dq":                         # q k v do lse delta -> dq
-        return (2 * (3 * q_tile + 2 * k_tile + 2 * row)
+        return (2 * (2 * q_tile + o_tile + k_tile + v_tile + 2 * row)
                 + block_q * lanes * 4 + 3 * score + cast)
     # dkv: q k v do lse delta -> dk dv; two accumulators; sT/pT dpT dsT
-    return (2 * (2 * q_tile + 4 * k_tile + 2 * row)
-            + 2 * block_k * lanes * 4 + 3 * score + 2 * cast)
+    return (2 * (q_tile + o_tile + 2 * k_tile + 2 * v_tile + 2 * row)
+            + block_k * (lanes + lanes_v) * 4 + 3 * score + 2 * cast)
 
 
-def _choose_tiles(Tq, Tk, D, itemsize):
+def _choose_tiles(Tq, Tk, D, itemsize, Dv=None):
     """``((block_q, block_k) of fwd, of dq, of dkv)`` from what the call can
     see.  Each side is the largest rung of the ladder that divides the
     padded length (so nothing pads further than ``_padded``); while the
@@ -115,7 +122,8 @@ def _choose_tiles(Tq, Tk, D, itemsize):
     out = []
     for kernel in ("fwd", "dq", "dkv"):
         qs, ks = rungs(_padded(Tq)), rungs(_padded(Tk))
-        while (_working_set(kernel, qs[0], ks[0], D, itemsize) > _VMEM_LIMIT
+        while (_working_set(kernel, qs[0], ks[0], D, itemsize, Dv)
+               > _VMEM_LIMIT
                and (len(qs) > 1 or len(ks) > 1)):
             q_steps = len(qs) > 1 and (qs[0] >= ks[0] or len(ks) == 1)
             (qs if q_steps else ks).pop(0)
@@ -252,7 +260,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
 
 def _fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]
     block_q, block_k = tiles[0]
     nq, nk = Tq // block_q, Tk // block_k
     _note_tiles("fwd", block_q, block_k, nq, nk, causal)
@@ -269,24 +277,24 @@ def _fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), q_map),
             pl.BlockSpec((1, 1, block_k, D), k_map),
-            pl.BlockSpec((1, 1, block_k, D), k_map),
+            pl.BlockSpec((1, 1, block_k, Dv), k_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), q_map),
+            pl.BlockSpec((1, 1, block_q, Dv), q_map),
             pl.BlockSpec((1, 1, block_q, 1), q_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Tq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=4 * B * H * Tq * Tk * D,
-            bytes_accessed=2 * (B * H * (Tq + 2 * Tk) * D),
+            flops=2 * B * H * Tq * Tk * (D + Dv),
+            bytes_accessed=2 * (B * H * ((Tq + Tk) * D + Tk * Dv)),
             transcendentals=B * H * Tq * Tk),
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
@@ -371,7 +379,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
          dlse=None):
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]
     # delta_i = rowsum(do_i * o_i) — cheap elementwise, XLA fuses it
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                     keepdims=True)
@@ -390,6 +398,8 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
     k_map = _inner_map(causal, block_q, block_k, nk, inner_is_k=True)
     qspec = pl.BlockSpec((1, 1, block_q, D), q_map)
     kspec = pl.BlockSpec((1, 1, block_k, D), k_map)
+    vspec = pl.BlockSpec((1, 1, block_k, Dv), k_map)
+    dospec = pl.BlockSpec((1, 1, block_q, Dv), q_map)
     rowq = pl.BlockSpec((1, 1, block_q, 1), q_map)
     dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -397,13 +407,13 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
                           Tk=Tk),
         name="flash_dq",
         grid=(B, H, nq, nk),
-        in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
+        in_specs=[qspec, kspec, vspec, dospec, rowq, rowq],
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         cost_estimate=pl.CostEstimate(
-            flops=6 * B * H * Tq * Tk * D,
-            bytes_accessed=4 * B * H * (Tq + Tk) * D,
+            flops=2 * B * H * Tq * Tk * (2 * D + Dv),
+            bytes_accessed=2 * B * H * (Tq + Tk) * (D + Dv),
             transcendentals=B * H * Tq * Tk),
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
@@ -420,6 +430,8 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
     k_map2 = _row_map
     qspec2 = pl.BlockSpec((1, 1, block_q, D), q_map2)
     kspec2 = pl.BlockSpec((1, 1, block_k, D), k_map2)
+    vspec2 = pl.BlockSpec((1, 1, block_k, Dv), k_map2)
+    dospec2 = pl.BlockSpec((1, 1, block_q, Dv), q_map2)
     rowq2 = pl.BlockSpec((1, 1, block_q, 1), q_map2)
     dkv_call = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
@@ -427,15 +439,15 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
                           Tk=Tk),
         name="flash_dkv",
         grid=(B, H, nk, nq),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2],
-        out_specs=[kspec2, kspec2],
+        in_specs=[qspec2, kspec2, vspec2, dospec2, rowq2, rowq2],
+        out_specs=[kspec2, vspec2],
         out_shape=[jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
-                   jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype)],
+                   jax.ShapeDtypeStruct((B, H, Tk, Dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
         cost_estimate=pl.CostEstimate(
-            flops=8 * B * H * Tq * Tk * D,
-            bytes_accessed=4 * B * H * (Tq + 2 * Tk) * D,
+            flops=4 * B * H * Tq * Tk * (D + Dv),
+            bytes_accessed=2 * B * H * (Tq + 2 * Tk) * (D + Dv),
             transcendentals=B * H * Tq * Tk),
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
@@ -506,7 +518,7 @@ def _attend(q, k, v, causal, scale, block_q, block_k, interpret, per_device,
     tiles, the [B, H, T, D] layout and the padding to whole tiles.  Returns
     ``(o, lse)`` with ``lse`` [B, H, T] or None."""
     B, T, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[3]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
@@ -523,7 +535,8 @@ def _attend(q, k, v, causal, scale, block_q, block_k, interpret, per_device,
         interpret = impl == "interpret"
 
     tiles = tuple((block_q or bq, block_k or bk) for bq, bk in
-                  _choose_tiles(T, Tk, D, jnp.dtype(q.dtype).itemsize))
+                  _choose_tiles(T, Tk, D, jnp.dtype(q.dtype).itemsize,
+                                None if Dv == D else Dv))
     # every kernel's tile divides the largest (rungs of one ladder)
     pq = _round_up(T, max(bq for bq, _ in tiles)) - T
     pk = _round_up(Tk, max(bk for _, bk in tiles)) - Tk
@@ -575,7 +588,9 @@ def _over_mesh(q, k, v, causal, scale, block_q, block_k):
 
 def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
                     block_k=None, interpret=None):
-    """Flash attention over [B, T, H, D] tensors.
+    """Flash attention over q, k [B, T, H, D] and v [B, T, H, Dv] (``Dv``
+    is ``D`` unless the values have a width of their own); the result is
+    [B, T, H, Dv].  ``scale`` defaults to ``D ** -0.5``.
 
     With ``interpret=None`` the implementation is ``common.kernel_impl``'s
     answer: the Pallas kernels above, the same inside a ``shard_map`` under

@@ -26,4 +26,4 @@ from .sharding import (ShardingRules, auto_shard, constraint,  # noqa: F401
 from . import collectives  # noqa: F401
 from .ring_attention import ring_attention, blockwise_attention  # noqa: F401
 from .pipeline import pipeline_spmd  # noqa: F401
-from .moe import moe_layer  # noqa: F401
+from .moe import expert_layer, moe_layer  # noqa: F401

@@ -1,27 +1,165 @@
-"""Expert parallelism — mixture-of-experts layer over mesh axis ``ep``.
+"""Mixture-of-experts layers.
 
 Net-new vs the reference (SURVEY.md §2.4 lists expert parallelism/MoE as
-absent).  TPU-native design: GShard-style einsum dispatch.  Routing is
-top-k (k=1 Switch-style or k>=2 GShard-style) with an auxiliary
-load-balancing loss; dispatch/combine are dense einsums over one-hot
-[token, slot, expert, capacity] masks, so the whole layer is
-static-shaped and GSPMD shards the expert dimension over ``ep`` (the
-all-to-all is inserted by XLA from the sharding constraints — no
-hand-written NCCL-style routing as the reference would have needed).
+absent).  Two layers live here:
+
+* :func:`expert_layer` -- what ``TransformerLM`` runs: a router over all the
+  experts the model has, top-k without a capacity, and the part of the
+  result that the experts *held here* give.  The (token, slot) pairs are
+  sorted by expert, the held experts' pairs first, group after group; each
+  group's rows go through its expert's matrices as one grouped product
+  (`ops/pallas/grouped_matmul.py`), and the rows come back to their tokens
+  weighted by the router.  No token is dropped whatever the imbalance, no
+  product is computed for a pair whose expert is not held (beyond a row
+  tile's rounding), and what the absent experts would have added is left
+  out: with ``experts_held`` a chip's share of an expert-parallel layer,
+  that partial result is what the chip has before the exchange.  The
+  exchange itself is not written (ROADMAP R-m3).
+* :func:`moe_layer` -- the older GShard-style einsum dispatch with a capacity
+  factor (overflow tokens are dropped), whose dense one-hot masks GSPMD
+  shards over the mesh axis ``ep``: what a model under an ``ep``-sharded
+  mesh still gets until that exchange exists.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
+from ..ops.pallas.grouped_matmul import grouped_matmul
 from .sharding import constraint
 
-__all__ = ["moe_layer"]
+__all__ = ["expert_layer", "moe_layer", "route"]
+
+
+def route(tokens, router_w, top_k, renormalize=False, seq_shape=None):
+    """The router, in float32 whatever the model's dtype: ``s = softmax(
+    tokens . router_w)`` over all experts, the ``top_k`` largest of each
+    token (greedy) as ``(weights [S, k], experts [S, k])``, and the
+    load-balancing term of each sequence, averaged: ``sum_i f_i P_i`` with
+    ``f_i`` the slots routed to expert ``i`` times ``n / (k T)`` (a count:
+    no gradient) and ``P_i`` the mean of ``s_i`` over the sequence.
+    ``seq_shape`` is ``(B, T)`` of the flattened ``tokens`` [S, E]."""
+    n = router_w.shape[1]
+    S = tokens.shape[0]
+    B, T = seq_shape or (1, S)
+    logits = jnp.einsum("se,en->sn", tokens.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    s = jax.nn.softmax(logits, axis=-1)
+    weights, experts = lax.top_k(s, top_k)
+    if renormalize and top_k > 1:
+        weights = weights / jnp.maximum(
+            weights.sum(-1, keepdims=True), 1e-9)
+    counts = jax.nn.one_hot(experts.reshape(B, T * top_k), n,
+                            dtype=jnp.float32).sum(axis=1)       # [B, n]
+    f = lax.stop_gradient(counts) * (n / (top_k * T))
+    aux = jnp.mean(jnp.sum(f * s.reshape(B, T, n).mean(axis=1), axis=-1))
+    return weights, experts, aux
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_sorted(tokens, order, inverse, k):
+    """tokens [S, E] -> the row of each (token, slot) pair in sorted order
+    [S k, E]: pair ``p`` is token ``p // k``."""
+    return tokens[order // k]
+
+
+def _to_sorted_fwd(tokens, order, inverse, k):
+    return tokens[order // k], (order, inverse, tokens.shape[0])
+
+
+def _to_sorted_bwd(k, res, g):
+    # the transpose of a permutation is a gather by its inverse, not a
+    # scatter; a token's gradient is the sum over its slots
+    order, inverse, S = res
+    back = g[inverse].reshape(S, k, g.shape[-1])
+    return back.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+
+
+_to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
+
+
+@jax.custom_vjp
+def _from_sorted(rows, order, inverse):
+    """Sorted rows [P, E] -> the pairs' own order [P, E]."""
+    return rows[inverse]
+
+
+def _from_sorted_fwd(rows, order, inverse):
+    return rows[inverse], (order,)
+
+
+def _from_sorted_bwd(res, g):
+    return g[res[0]], None, None
+
+
+_from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
+
+
+def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
+                 experts_held=None, renormalize=True, act=jax.nn.gelu):
+    """The held experts' part of a routed feed-forward, no token dropped.
+
+    x: [B, T, E]; router_w: [E, n] over all ``n`` experts; w_up (and
+    w_gate): [held, E, F]; w_down: [held, F, E], stacked in the order of
+    ``experts_held`` (ids among ``0 .. n - 1``; None: all of them).  An
+    expert is ``down(act(up(h)))``, or with ``w_gate`` ``down(silu(gate(h))
+    * up(h))``.  Returns ``(y [B, T, E], aux, held)``: ``y_t = sum over the
+    slots of t whose expert is held of w * expert(x_t)``, the router's
+    balance term (over all ``n``, so every share computes it alike), and
+    how many of the ``B T k`` pairs landed on held experts."""
+    from .. import telemetry as _telemetry
+    B, T, E = x.shape
+    n = router_w.shape[1]
+    k = int(top_k)
+    assert 1 <= k <= n, "top_k must be in [1, n_experts]"
+    held = tuple(range(n)) if experts_held is None else tuple(experts_held)
+    assert len(set(held)) == len(held) == w_up.shape[0] and all(
+        0 <= e < n for e in held), "experts_held names the stacked experts"
+    S, P, n_held = B * T, B * T * k, len(held)
+    reg = _telemetry.registry()
+    reg.counter("moe.experts_held.%dof%d" % (n_held, n)).inc()
+    reg.counter("moe.buffer_rows.%d" % P).inc()
+
+    tokens = x.reshape(S, E)
+    with jax.named_scope("moe.route"):
+        weights, experts, aux = route(tokens, router_w, k, renormalize,
+                                      (B, T))
+    with jax.named_scope("moe.dispatch"):
+        # a pair's key is its expert's place in the stack; an absent
+        # expert's pairs sort behind every group and are never computed
+        place = np.full((n,), n_held, np.int32)
+        place[list(held)] = np.arange(n_held, dtype=np.int32)
+        key = jnp.asarray(place)[experts.reshape(P)]
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        sizes = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=jnp.int32),
+                        axis=0, dtype=jnp.int32)
+        rows = _to_sorted(tokens, order, inverse, k)
+    with jax.named_scope("moe.experts"):
+        up = grouped_matmul(rows, w_up, sizes).astype(jnp.float32)
+        if w_gate is not None:
+            gate = grouped_matmul(rows, w_gate, sizes).astype(jnp.float32)
+            up = jax.nn.silu(gate) * up
+        else:
+            up = act(up)
+        out = grouped_matmul(up.astype(x.dtype), w_down, sizes)
+    with jax.named_scope("moe.combine"):
+        # rows past the groups are zero, so an absent expert's slot adds 0
+        slots = _from_sorted(out, order, inverse).reshape(S, k, E)
+        y = jnp.einsum("ske,sk->se", slots.astype(jnp.float32), weights)
+    return (y.astype(x.dtype).reshape(B, T, E), aux,
+            jnp.sum(sizes).astype(jnp.float32))
 
 
 def moe_layer(x, gate_w, w_up, w_down, ep_axis="ep", capacity_factor=1.25,
-              top_k=1, renormalize=True):
-    """Top-k routed MoE feed-forward.
+              top_k=1, renormalize=True, act=jax.nn.relu):
+    """Top-k routed MoE feed-forward with a capacity (see the module's
+    docstring: the layer of an ``ep``-sharded mesh).
 
     x: [B, T, E]; gate_w: [E, n_exp]; w_up: [n_exp, E, H];
     w_down: [n_exp, H, E].  Returns (y [B, T, E], aux_loss scalar).
@@ -73,7 +211,7 @@ def moe_layer(x, gate_w, w_up, w_down, ep_axis="ep", capacity_factor=1.25,
 
     h = jnp.einsum("nce,neh->nch", expert_in, w_up,
                    preferred_element_type=jnp.float32)
-    h = jax.nn.relu(h).astype(x.dtype)
+    h = act(h).astype(x.dtype)
     expert_out = jnp.einsum("nch,nhe->nce", h, w_down,
                             preferred_element_type=jnp.float32
                             ).astype(x.dtype)

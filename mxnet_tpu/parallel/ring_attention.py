@@ -196,10 +196,10 @@ def blockwise_attention(q, k, v, block_size=512, causal=True, scale=None,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kb = k.reshape(B, nb, block_size, H, D)
-    vb = v.reshape(B, nb, block_size, H, D)
+    vb = v.reshape(B, nb, block_size, H, v.shape[-1])
     q_pos = jnp.arange(T)
 
-    o0 = jnp.zeros((B, T, H, D), jnp.float32)
+    o0 = jnp.zeros((B, T, H, v.shape[-1]), jnp.float32)
     m0 = jnp.full((B, H, T), _NEG, jnp.float32)
     l0 = jnp.zeros((B, H, T), jnp.float32)
 

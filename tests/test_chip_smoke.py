@@ -81,8 +81,11 @@ def test_leg_kernel_parity_toy():
         interpret=True, seqs=(24,), head_dims=(8,), cell_shape=(2, 48, 2, 8),
         cross_seqs=(16, 40), norm_shape=(2, 8, 32),
         xent_rows=8, vocab=100, mm_shapes=((40, 24, 72),),
-        scan_shapes=((2, 40, 128, 8),), scan_cell_shape=(1, 48, 256, 16))
-    assert out["checks"] > 56
+        scan_shapes=((2, 40, 128, 8),), scan_cell_shape=(1, 48, 256, 16),
+        mla_shape=(1, 40, 2, 24, 16),
+        gmm_shapes=((50, 24, 16, (12, 0, 21, 9)),),
+        gmm_cell_shape=(64, 32, 24, (20, 30)))
+    assert out["checks"] > 56 + 4 + 6
 
 
 def test_leg_server_toy():

@@ -251,3 +251,64 @@ def test_lm_train_step_runs_each_kept_kernel_once_on_v5e(
                            r"tpu_custom_call", text):
         found[name] = found.get(name, 0) + 1
     assert {k: found.get(k, 0) for k in calls} == calls, found
+
+
+def test_flash_at_a_value_width_of_its_own_compiles_for_v5e(one_chip):
+    """Latent attention's shape in the expert cell: scores 192 wide, values
+    128, 1 x 16 heads x 4096, bfloat16: the three kernels keep the top rung."""
+    B, T, H, D, Dv = 1, 4096, 16, 192, 128
+    assert fa._choose_tiles(T, T, D, 2, Dv) == ((1024, 1024),) * 3
+    qk = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one_chip)
+    vo = jax.ShapeDtypeStruct((B, T, H, Dv), jnp.bfloat16, sharding=one_chip)
+
+    def fwd_and_grads(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, scale=0.1147, interpret=False), q, k, v)
+        return out, vjp(do)
+
+    # bfloat16 at the default precision the models use (Mosaic takes no
+    # multi-pass precision on bfloat16 operands)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(fwd_and_grads).trace(qk, qk, vo, vo).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 3
+
+
+@pytest.mark.parametrize("M,K,N,G,tiles", [
+    pytest.param(24576, 2048, 1408, 32,
+                 {"gmm_fwd": (2048, 1408), "gmm_dx": (1408, 2048),
+                  "gmm_dw": (1024, 1408)}, id="dsv2_lite_gate_up"),
+    pytest.param(24576, 1408, 2048, 32,
+                 {"gmm_fwd": (1408, 2048), "gmm_dx": (2048, 1408),
+                  "gmm_dw": (1408, 1024)}, id="dsv2_lite_down"),
+    pytest.param(1000, 96, 40, 3,
+                 {"gmm_fwd": (96, 40), "gmm_dx": (40, 96),
+                  "gmm_dw": (96, 40)}, id="rows_and_widths_off_the_tiling"),
+])
+def test_grouped_product_kernels_compile_for_v5e(one_chip, M, K, N, G, tiles):
+    """Forward, ``dx`` and ``dW`` of the grouped product at the expert
+    cell's two shapes (the whole contraction and output width one block in
+    forward and ``dx``), and at sizes nothing divides."""
+    gm = importlib.import_module("mxnet_tpu.ops.pallas.grouped_matmul")
+    dtype = jnp.bfloat16 if M > 1000 else jnp.float32
+    size = jnp.dtype(dtype).itemsize
+    for kernel, (tk, tn) in tiles.items():
+        k, n = (N, K) if kernel == "gmm_dx" else (K, N)
+        assert gm._choose_tiles(kernel, M, k, n, size)[1:] == (tk, tn), kernel
+    x = jax.ShapeDtypeStruct((M, K), dtype, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((G, K, N), dtype, sharding=one_chip)
+    dy = jax.ShapeDtypeStruct((M, N), dtype, sharding=one_chip)
+    gs = jax.ShapeDtypeStruct((G,), jnp.int32, sharding=one_chip)
+
+    def fwd_and_grads(x, w, gs, dy):
+        out, vjp = jax.vjp(lambda x, w: gm.grouped_matmul(
+            x, w, gs, interpret=False), x, w)
+        return out, vjp(dy)
+
+    with jax.default_matmul_precision(
+            "highest" if dtype == jnp.float32 else "default"):
+        text = jax.jit(fwd_and_grads).trace(x, w, gs, dy).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 3
