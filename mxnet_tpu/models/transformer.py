@@ -14,11 +14,12 @@ attention, latent attention (`models/mla.py`) or a Mamba mixer
 Design notes (TPU-first):
 * parameters are a flat ``{name: jax.Array}`` dict; layer stacks use a leading
   ``L`` dim + ``lax.scan`` over blocks (ONE traced block body, remat-friendly)
-  — not L separately-traced python layers.  By default the scan is UNROLLED
-  at compile time (``scan_unroll=True``: XLA may overlap and fuse across
-  layers; not re-measured since PR 27; ROADMAP S1 (c)) at a compile-time
-  cost ~ n_layers; deep configs can set ``scan_unroll=False`` to regain
-  one-body compiles;
+  — not L separately-traced python layers.  A run of equal layers no longer
+  than ``_UNROLLED_RUN`` is UNROLLED at compile time (XLA may overlap and
+  fuse across layers); a longer run is a loop of one layer a body, which
+  bounds the buffers the schedule holds live at once and the compile time
+  (``scan_unroll=True``, the default).  ``scan_unroll=False`` is one layer
+  a body whatever the run;
 * compute dtype bf16, accumulation f32 (MXU-native);
 * causal LM loss is computed from sharded logits; everything is static-shaped.
 """
@@ -60,9 +61,10 @@ class TransformerConfig:
     n_experts: int = 8
     moe_aux_weight: float = 0.01
     remat: bool = True
-    # Unroll the layer scan: one traced body, unrolled execution, so XLA
-    # may overlap and fuse across layers.  Costs compile time ~ n_layers.
-    # Not re-measured since PR 27; ROADMAP S1 (c).
+    # True: a run of equal layers no longer than ``_UNROLLED_RUN`` is
+    # unrolled whole (one traced body, unrolled execution: XLA may overlap
+    # and fuse across layers), a longer one loops a layer a body
+    # (``_layers_a_body``).  False: one layer a body whatever the run.
     scan_unroll: bool = True
     # Small attention problems use plain dense attention (the scores
     # materialize); bigger ones take flash so memory stays O(T).  The
@@ -236,13 +238,33 @@ def _dense_self_attention(q, k, v, causal=True, scale=None):
 
 # What a rematerialised layer (``remat=True``) keeps of its forward, by
 # ``checkpoint_name``: the results whose second forward costs most a byte
-# kept (PERF.md §6, PR 31) -- the selective scan's output and chunk
+# kept (PERF.md §6, PR 31, PR 33) -- the selective scan's output and chunk
 # boundaries, flash attention's ``o`` and ``lse``, the Mamba ``in_proj``'s
-# output and every mixer's output before the residual add.  Everything else
-# (``wqkv``, the norms, the convolution, the MLP up to ``w_down``) the
-# backward re-makes.  A name no layer of a model produces costs nothing.
+# output, ``wqkv``'s output split into heads and every mixer's output before
+# the residual add.  Everything else (the norms, the convolution, the MLP up
+# to ``w_down``) the backward re-makes.  A name no layer of a model produces
+# costs nothing.
 MIXER_OUT = "mixer_out"
-KEPT = _SCAN_KEPT + _FLASH_KEPT + (IN_PROJ_NAME, MIXER_OUT)
+QKV_NAME = "attn_qkv"
+KEPT = _SCAN_KEPT + _FLASH_KEPT + (IN_PROJ_NAME, QKV_NAME, MIXER_OUT)
+
+# The longest run of equal layers the layer scan unrolls whole
+# (``scan_unroll=True``); a longer run is a loop of one layer a body.
+# Unrolled, the schedule holds a ``[B, T, d_ff]`` buffer a layer live at
+# once (19 of 24 at the dense LM's peak, 99 % of the chip); looped, the kept
+# values travel through stacked buffers.  On the v5e (PERF.md §6, PR 33):
+# one run of 24 at 4 x 2048 x 2048 reads 13,105 tokens/s unrolled whole and
+# 13,471 / 13,080 / 13,023 / 12,915 / 12,790 / 12,021 / 11,513 at 1 / 2 / 3 /
+# 4 / 6 / 8 / 12 layers a body; runs of 7, 1, 6 (a hybrid at 1 x 4096 x
+# 2560) read 10,898 whole, 10,423 at 1 and 9,634 at 2.  Whole or one: a body
+# of a few layers loses to both.  Nothing between 7 and 24 is measured.
+_UNROLLED_RUN = 8
+
+
+def _layers_a_body(n, unrolled=True):
+    """How many of a run's ``n`` equal layers one body of the layer loop
+    holds: all of a run up to ``_UNROLLED_RUN`` long, else one."""
+    return n if unrolled and n <= _UNROLLED_RUN else 1
 
 
 class TransformerLM:
@@ -339,16 +361,23 @@ class TransformerLM:
 
     def _qkv(self, bp, h):
         """The fused projection of ``h`` [B, T, E], split into heads:
-        q [B, T, H, D] and k, v [B, T, KV, D]."""
+        q [B, T, H, D] and k, v [B, T, KV, D].  Each is named
+        (``QKV_NAME``) heads first, [B, heads, T, D], as the flash kernels
+        take it: a rematerialised layer that keeps the name hands the
+        backward kernels the stored value as it lies (PERF.md §6, PR 33)."""
         cfg = self.cfg
         B, T, _ = h.shape
         H, D, KV = cfg.n_heads, cfg.head_dim, cfg.kv_heads
         qkv = jnp.einsum("bte,ef->btf", h, bp["wqkv"],
                          preferred_element_type=jnp.float32).astype(h.dtype)
         qkv = constraint(qkv, "dp", "sp", "tp")
+
+        def heads(x, n):
+            x = x.reshape(B, T, n, D).transpose(0, 2, 1, 3)
+            return checkpoint_name(x, QKV_NAME).transpose(0, 2, 1, 3)
+
         q, k, v = jnp.split(qkv, [H * D, (H + KV) * D], axis=-1)
-        return (q.reshape(B, T, H, D), k.reshape(B, T, KV, D),
-                v.reshape(B, T, KV, D))
+        return heads(q, H), heads(k, KV), heads(v, KV)
 
     def _attn_out(self, bp, attn):
         """The output projection of ``attn`` [B, T, H, D]."""
@@ -626,15 +655,20 @@ class TransformerLM:
             x, a, _ = self._block(bp, x, self._ssm, scope="ssm")
             return (x, aux + a), None
 
-        def layers(carry, run_body, run):
+        def layers(carry, run_body, run, kind="attention"):
             """A run of layers of one kind, scanned over ``run``, their
-            slice of the stacked leaves."""
+            slice of the stacked leaves, ``_layers_a_body`` layers a loop
+            body."""
             if cfg.remat:
                 run_body = jax.checkpoint(
                     run_body, policy=jax.checkpoint_policies
                     .save_only_these_names(*KEPT))
-            return lax.scan(run_body, carry, run,
-                            unroll=bool(cfg.scan_unroll))[0]
+            n = len(run["ln1_scale"])
+            unroll = _layers_a_body(n, cfg.scan_unroll)
+            from .. import telemetry as _telemetry
+            _telemetry.registry().counter(
+                "lm.layers.%s.%dx%d" % (kind, n, unroll)).inc()
+            return lax.scan(run_body, carry, run, unroll=unroll)[0]
 
         carry = (x, jnp.zeros((2,), jnp.float32) if cfg.has_experts
                  else jnp.float32(0.0))
@@ -654,7 +688,7 @@ class TransformerLM:
                 run = {k: v[lo:hi] for k, v in stacked.items()}
                 run.update({k: v[klo:khi] for k, v in own[kind].items()})
                 carry = layers(
-                    carry, ssm_body if kind == "mamba" else body, run)
+                    carry, ssm_body if kind == "mamba" else body, run, kind)
         return carry
 
     def held_slot_share(self, params, tokens):

@@ -264,8 +264,15 @@ def test_the_dense_lm_traces_the_program_it_traced_before_layer_types(
     A PR that means to change the dense LM's program records the new
     digest here and says so.  PR 31: the rematerialised layer keeps the
     mixer's output (at these sizes attention is dense, so flash names
-    nothing); with nothing kept the text is the one before, byte for
-    byte."""
+    nothing); with nothing kept the text was the one before, byte for
+    byte (``6be2beb4...``).  PR 33: ``_qkv`` names q, k and v heads first
+    (a transposition, the name, the transposition back: XLA cancels the
+    pair against flash's own) and the policy keeps them; the layer loop's
+    ``unroll`` is the run's length where it was ``True`` (2 layers are
+    under the bound: the same text).  With nothing kept the names and
+    their transpositions still stand in the text, so that digest moved
+    too; that the values are PR 31's is held by
+    ``test_lm_remat_kept.py`` (every gradient to the bit)."""
     from mxnet_tpu.models import transformer
     monkeypatch.setenv("MXTPU_PALLAS", "off")
     with open(os.path.join(REPO, "benchmarks", "configs",
@@ -286,10 +293,10 @@ def test_the_dense_lm_traces_the_program_it_traced_before_layer_types(
         return hashlib.sha256(text.encode()).hexdigest()
 
     assert digest() == (
-        "3aced4cae8bf5d88fe133e7b05f38e2d992545251d57f166d4f5b4d69fc270e3")
+        "6d3d6a61eb9daa07cda7a85dd60b1494c2d8ff4389e16010d62b5049ec180287")
     monkeypatch.setattr(transformer, "KEPT", ())
     assert digest() == (
-        "6be2beb41200df101e5cfd39e19e5f5946a09a0dce5b2b3ab31c37d567a5ef5e")
+        "5e4ceb107bd67b49d31fbbaa234de3ed343f03772c34ed9b6872b61fd23c82c7")
 
 
 def test_the_models_name_no_implementation_and_contract_each_weight_once():

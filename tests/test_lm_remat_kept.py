@@ -1,7 +1,8 @@
 """What a rematerialised ``TransformerLM`` layer keeps of its forward
 (``models.transformer.KEPT``): the results named there are not made a second
 time for the backward, and every gradient is the bare ``jax.checkpoint``'s
-to the bit."""
+to the bit.  And how many layers of a run one body of the layer loop holds
+(``models.transformer._layers_a_body``)."""
 import importlib
 
 import jax
@@ -23,7 +24,17 @@ MODELS = {
     "hybrid": dict(TOY, layer_types=("mamba", "attention"), ssm_state=8,
                    ssm_dt_rank=4),
     "moe": dict(TOY, use_moe=True, n_experts=4),
+    # runs of 5; of 3, 1, 2; of 1, 3
+    "dense5": dict(TOY, n_layers=5),
+    "hybrid6": dict(TOY, n_layers=6, ssm_state=8, ssm_dt_rank=4,
+                    layer_types=("mamba",) * 3 + ("attention",)
+                    + ("mamba",) * 2),
+    "mlp_types4": dict(TOY, n_layers=4, n_experts=4, moe_top_k=2,
+                       mlp_types=("dense",) + ("moe",) * 3),
 }
+RUNS = {"dense5": [("attention", 5)],
+        "hybrid6": [("mamba", 3), ("attention", 1), ("mamba", 2)],
+        "mlp_types4": [("dense", 1), ("moe", 3)]}
 
 
 def build(kind, **over):
@@ -67,30 +78,44 @@ def test_a_kept_result_is_not_made_a_second_time(kind, monkeypatch):
     """A scan body stands once in the forward and once in the backward of
     the jaxpr, whatever the layers it runs over.  With nothing kept (a bare
     ``jax.checkpoint``) the backward's body holds the whole forward but
-    ``w_down``; with ``KEPT`` it holds no flash forward kernel, no ``wo``,
-    no ``in_proj`` and no ``out_proj`` -- ``wqkv`` is re-made as before.
+    ``w_down``; with ``KEPT`` it holds no flash forward kernel, no ``wqkv``,
+    no ``wo``, no ``in_proj`` and no ``out_proj``.
     (The experts of ``use_moe`` have weights of their own, no ``w_down``.)"""
     monkeypatch.setenv("MXTPU_PALLAS", "interpret")
     mamba = int(kind == "hybrid")
     w_down = 0 if kind == "moe" else 1 + mamba
     assert forward_work(kind) == {
         "flash_fwd": 1, "wo": 1, "in_proj": mamba, "out_proj": mamba,
-        "wqkv": 2, "w_down": w_down}
+        "wqkv": 1, "w_down": w_down}
     monkeypatch.setattr(transformer, "KEPT", ())
     assert forward_work(kind) == {
         "flash_fwd": 2, "wo": 2, "in_proj": 2 * mamba, "out_proj": 2 * mamba,
         "wqkv": 2, "w_down": w_down}
 
 
-@pytest.mark.parametrize("kind,over", [
-    ("dense", {}), ("hybrid", {}), ("moe", {}),
-    ("dense", dict(scan_unroll=False)), ("hybrid", dict(scan_unroll=False)),
-], ids=["dense", "hybrid", "moe", "dense_rolled", "hybrid_rolled"])
-def test_every_gradient_is_the_bare_checkpoints_to_the_bit(kind, over,
+def test_wqkvs_product_alone_is_kept_by_its_name(monkeypatch):
+    """With ``KEPT`` cut to ``QKV_NAME`` only ``wqkv``'s product leaves the
+    backward's body."""
+    monkeypatch.setenv("MXTPU_PALLAS", "interpret")
+    monkeypatch.setattr(transformer, "KEPT", (transformer.QKV_NAME,))
+    assert forward_work("dense") == {
+        "flash_fwd": 2, "wo": 2, "in_proj": 0, "out_proj": 0, "wqkv": 1,
+        "w_down": 1}
+
+
+@pytest.mark.parametrize("kind,over,kept", [
+    ("dense", {}, None), ("hybrid", {}, None), ("moe", {}, None),
+    ("dense", dict(scan_unroll=False), None),
+    ("hybrid", dict(scan_unroll=False), None),
+    ("dense", {}, "wqkv"), ("hybrid", {}, "wqkv"), ("moe", {}, "wqkv"),
+], ids=["dense", "hybrid", "moe", "dense_rolled", "hybrid_rolled",
+        "dense_wqkv_alone", "hybrid_wqkv_alone", "moe_wqkv_alone"])
+def test_every_gradient_is_the_bare_checkpoints_to_the_bit(kind, over, kept,
                                                            monkeypatch):
     """A kept value is the value the second forward would re-make: loss and
     every gradient leaf equal those of a policy that keeps nothing (the
-    parent's dense branch), kernels through the interpreter.  In a rolled
+    parent's dense branch), kernels through the interpreter; so with all of
+    ``KEPT`` and with ``wqkv``'s product alone.  In a rolled
     layer loop the kept values are a scan's stacked residuals, and there
     XLA's CPU backend re-makes ``x + o`` fused otherwise than it made it
     the first time: with the mixer's output kept the backward sees the
@@ -99,6 +124,8 @@ def test_every_gradient_is_the_bare_checkpoints_to_the_bit(kind, over,
     if kind != "moe":                      # the experts' body has no kernel
         monkeypatch.setenv("MXTPU_PALLAS", "interpret")
     model, p, x, y = build(kind, **over)
+    if kept:
+        monkeypatch.setattr(transformer, "KEPT", (transformer.QKV_NAME,))
     loss, grads = jax.jit(jax.value_and_grad(model.loss))(p, x, y)
     monkeypatch.setattr(transformer, "KEPT", ())
     loss0, grads0 = jax.jit(jax.value_and_grad(model.loss))(p, x, y)
@@ -153,5 +180,93 @@ def test_the_names_are_one_tuple():
         for name in ("flash_attention", "selective_scan"))
     assert transformer.KEPT == (
         selective_scan.SAVED_NAMES + flash_attention.SAVED_NAMES
-        + (mamba.IN_PROJ_NAME, transformer.MIXER_OUT))
-    assert len(set(transformer.KEPT)) == 6
+        + (mamba.IN_PROJ_NAME, transformer.QKV_NAME, transformer.MIXER_OUT))
+    assert len(set(transformer.KEPT)) == 7
+
+
+# -- the layer loop ---------------------------------------------------------
+def layer_loops(kind, **over):
+    """The layer scans of the forward as ``[(length, unroll)]``, and what
+    tracing it added to the ``lm.layers.*`` counters."""
+    from mxnet_tpu import telemetry
+
+    def counters():
+        return {k: v for k, v in
+                telemetry.registry().snapshot()["counters"].items()
+                if k.startswith("lm.layers.")}
+
+    model, p, x, y = build(kind, **over)
+    before = counters()
+    jaxpr = jax.make_jaxpr(model.loss)(p, x, y).jaxpr
+    counted = {k: v - before.get(k, 0) for k, v in counters().items()
+               if v != before.get(k, 0)}
+    loops = [(eqn.params["length"], eqn.params["unroll"])
+             for eqn in jaxpr.eqns if eqn.primitive.name == "scan"]
+    return loops, counted
+
+
+@pytest.mark.parametrize("kind", sorted(RUNS))
+@pytest.mark.parametrize("bound", [1, 2, 4, None],
+                         ids=["1", "2", "4", "module"])
+def test_a_run_longer_than_the_bound_is_a_loop_of_one_layer_a_body(
+        kind, bound, monkeypatch):
+    """``layers()`` unrolls a run of n equal layers whole (what
+    ``unroll=True`` gave) where n is at most ``_UNROLLED_RUN``, and scans a
+    longer one a layer a body.  The module's own bound leaves these toys'
+    runs whole."""
+    monkeypatch.setenv("MXTPU_PALLAS", "interpret")
+    if bound:
+        monkeypatch.setattr(transformer, "_UNROLLED_RUN", bound)
+    bound = transformer._UNROLLED_RUN
+    bodies = [(run_kind, n, n if n <= bound else 1)
+              for run_kind, n in RUNS[kind]]
+    loops, counted = layer_loops(kind)
+    assert loops == [(n, body) for _, n, body in bodies]
+    want = {}
+    for run in bodies:
+        name = "lm.layers.%s.%dx%d" % run
+        want[name] = want.get(name, 0) + 1
+    assert counted == want
+
+
+@pytest.mark.parametrize("kind", sorted(RUNS))
+def test_scan_unroll_false_is_one_layer_a_body(kind, monkeypatch):
+    monkeypatch.setenv("MXTPU_PALLAS", "interpret")
+    loops, counted = layer_loops(kind, scan_unroll=False)
+    assert loops == [(n, 1) for _, n in RUNS[kind]]
+    assert sorted(counted) == sorted(
+        {"lm.layers.%s.%dx1" % run for run in RUNS[kind]})
+
+
+def test_the_benchmarks_runs_on_both_sides_of_the_bound():
+    """The cells' runs of equal layers: the dense LM's one run of 24 is
+    longer than the bound and loops a layer a body; the hybrid's (7, 1, 6)
+    and the expert cell's (1, 4) are under it and stay unrolled whole."""
+    body = transformer._layers_a_body
+    assert [body(n) for n in (24, 7, 6, 4, 1)] == [1, 7, 6, 4, 1]
+    assert [body(n, False) for n in (24, 7, 1)] == [1, 1, 1]
+
+
+@pytest.mark.parametrize("kind", sorted(RUNS))
+def test_loss_and_gradients_are_alike_at_every_body_length(kind,
+                                                           monkeypatch):
+    """Bodies of 1, 2 and all of a run's layers are one computation: the
+    loss and every gradient leaf agree (float32 on the CPU, to 1e-6 of the
+    leaf's largest entry: XLA fuses a loop's body otherwise than the
+    unrolled layers)."""
+    monkeypatch.setenv("MXTPU_PALLAS", "interpret")
+    model, p, x, y = build(kind)
+    got = {}
+    for body in (1, 2, 8):
+        monkeypatch.setattr(transformer, "_layers_a_body",
+                            lambda n, unrolled=True: min(n, body))
+        got[body] = jax.jit(jax.value_and_grad(model.loss))(p, x, y)
+    loss, grads = got[8]
+    for body in (1, 2):
+        np.testing.assert_allclose(got[body][0], loss, rtol=1e-6)
+        for name in grads:
+            top = float(jnp.abs(grads[name]).max())
+            assert top > 0, name
+            np.testing.assert_allclose(got[body][1][name], grads[name],
+                                       rtol=0, atol=1e-6 * top,
+                                       err_msg=name)
