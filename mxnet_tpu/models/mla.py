@@ -15,26 +15,21 @@ positional term lives in a rotary part of its own, decoupled from it:
 ``v_head_dim``, ``r`` is ``kv_lora_rank``; the query is not compressed
 (``q_lora_rank`` null).  The scores are ``dn + dr`` wide and the values
 ``dv``: the flash kernels take the two widths (`ops/pallas/
-flash_attention.py`).  The rotary frequencies are YaRN's (:func:`yarn_inv_
-freq`), in the rotate-half layout (the first ``dr / 2`` columns of a rotary
-part pair with the last); ``scale = (dn + dr) ** -0.5 * mscale ** 2``
-(:func:`softmax_scale`).  Cosines, sines and the rotation are float32.
+flash_attention.py`).  The rotary term is `models/rope.py`'s (YaRN's
+frequencies, the rotate-half layout); ``scale = (dn + dr) ** -0.5 * mscale
+** 2`` (:func:`softmax_scale`).
 Training path only: serving would keep ``c`` and ``k_pe`` (a latent cache),
 which the paged pool does not (ROADMAP R-m2).
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..ops.pallas import fused_rmsnorm
+from .rope import rope_tables, rotate_half, yarn_mscale
 
-__all__ = ["mla_mixer", "mla_leaf_shapes", "yarn_inv_freq", "yarn_mscale",
-           "yarn_correction_range", "softmax_scale", "rope_tables",
-           "rotate_half"]
+__all__ = ["mla_mixer", "mla_leaf_shapes", "softmax_scale"]
 
 
 def mla_leaf_shapes(cfg):
@@ -51,36 +46,6 @@ def mla_leaf_shapes(cfg):
     }
 
 
-def yarn_mscale(factor, mscale):
-    """YaRN's attention temperature: ``0.1 mscale ln(factor) + 1``."""
-    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
-def yarn_correction_range(dim, base, orig_len, beta_fast, beta_slow):
-    """``(low, high)``: the rotary pairs between which YaRN blends from the
-    published frequencies (below ``low``: pairs that turn more than
-    ``beta_fast`` times over the original context) to the interpolated ones
-    (above ``high``: fewer than ``beta_slow`` turns)."""
-    def pair_of(turns):
-        return dim * math.log(orig_len / (turns * 2 * math.pi)) / (
-            2 * math.log(base))
-    return (max(math.floor(pair_of(beta_fast)), 0),
-            min(math.ceil(pair_of(beta_slow)), dim - 1))
-
-
-def yarn_inv_freq(dim, base, factor, orig_len, beta_fast, beta_slow):
-    """[dim / 2] float64 inverse frequencies: ``base ** (-2 i / dim)``,
-    divided by ``factor`` where the ramp over the correction range is 1."""
-    extra = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if factor <= 1:
-        return extra
-    low, high = yarn_correction_range(dim, base, orig_len, beta_fast,
-                                      beta_slow)
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
-                   / max(high - low, 1e-3), 0.0, 1.0)
-    return extra / factor * ramp + extra * (1.0 - ramp)
-
-
 def softmax_scale(cfg):
     """``(dn + dr) ** -0.5`` times the square of YaRN's temperature over
     all dimensions (``rope_mscale_all_dim``; 0: none)."""
@@ -88,29 +53,6 @@ def softmax_scale(cfg):
     if cfg.rope_mscale_all_dim:
         scale *= yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
     return scale
-
-
-def rope_tables(cfg, T):
-    """``(cos, sin)`` [T, dr] float32 for positions 0 .. T - 1, each pair's
-    angle in columns ``i`` and ``i + dr / 2``."""
-    inv = yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta,
-                        cfg.rope_factor, cfg.rope_orig_len,
-                        cfg.rope_beta_fast, cfg.rope_beta_slow)
-    angle = np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
-    angle = np.concatenate([angle, angle], axis=-1)
-    m = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
-         / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
-    return (jnp.asarray(np.cos(angle) * m, jnp.float32),
-            jnp.asarray(np.sin(angle) * m, jnp.float32))
-
-
-def rotate_half(x, cos, sin):
-    """x [B, T, heads, dr] turned by its position's angles, in float32."""
-    xf = x.astype(jnp.float32)
-    a, b = jnp.split(xf, 2, axis=-1)
-    turned = jnp.concatenate([-b, a], axis=-1)
-    return (xf * cos[None, :, None, :]
-            + turned * sin[None, :, None, :]).astype(x.dtype)
 
 
 def mla_mixer(bp, h, cfg, attend):
@@ -136,7 +78,7 @@ def mla_mixer(bp, h, cfg, attend):
         k_nope, v = jnp.split(
             proj(c, bp["wkv_b"]).reshape(B, T, H, dn + dv), [dn], axis=-1)
     with jax.named_scope("mla.rope"):
-        cos, sin = rope_tables(cfg, T)
+        cos, sin = rope_tables(cfg, dr, T)
         q_pe = rotate_half(q_pe, cos, sin)
         k_pe = rotate_half(k_pe[:, :, None, :], cos, sin)
         q = jnp.concatenate([q_nope, q_pe], axis=-1)

@@ -8,8 +8,9 @@ sequence parallel via ring attention (``sp``, `parallel/ring_attention.py`),
 routed experts (`parallel/moe.py`: a chip's share of them without dropped
 tokens; the capacity dispatch over ``ep``), and a GPipe pipeline variant
 (``pp``, `parallel/pipeline.py`).  A layer's attention half is fused-QKV
-attention, latent attention (`models/mla.py`) or a Mamba mixer
-(`models/mamba.py`); its MLP half dense or experts, by layer.
+attention (over the whole causal prefix or a window of it, with a rotary
+term or none, by layer), latent attention (`models/mla.py`) or a Mamba
+mixer (`models/mamba.py`); its MLP half dense or experts, by layer.
 
 Design notes (TPU-first):
 * parameters are a flat ``{name: jax.Array}`` dict; layer stacks use a leading
@@ -40,9 +41,10 @@ from ..ops.pallas.flash_attention import SAVED_NAMES as _FLASH_KEPT
 from ..ops.pallas.selective_scan import SAVED_NAMES as _SCAN_KEPT
 from ..parallel.sharding import ShardingRules, constraint, PartitionSpec as P
 from ..parallel.ring_attention import ring_self_attention
-from ..parallel.moe import expert_layer, moe_layer
+from ..parallel.moe import expert_layer, moe_layer, route
 from .mamba import IN_PROJ_NAME, mamba_mixer
 from .mla import mla_leaf_shapes, mla_mixer
+from .rope import rope_tables, rotate_half
 
 __all__ = ["TransformerConfig", "TransformerLM", "make_train_step",
            "default_rules"]
@@ -76,7 +78,8 @@ class TransformerConfig:
     # 0 means as many as ``n_heads``.  Training path only: the paged pool
     # and the MXKV blob still take equal head counts (ROADMAP R-m2).
     n_kv_heads: int = 0
-    # "gelu": down(gelu(up(h))); "swiglu": down(silu(gate(h)) * up(h))
+    # "gelu": down(gelu(up(h))); "swiglu": down(silu(gate(h)) * up(h));
+    # "reglu": down(relu(gate(h)) * up(h))
     mlp: str = "gelu"
     # logits = x . embed^T, no ``unembed`` leaf
     tie_embeddings: bool = False
@@ -97,8 +100,9 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    # rotary term of the ``mla`` mixer's rotary part (nothing else has a
-    # positional term, ROADMAP R-m1); ``rope_factor`` > 1 is YaRN
+    # rotary term (`models/rope.py`) of the ``mla`` mixer's rotary part and
+    # of the fused-QKV mixer's layers that ``attn_rope`` names;
+    # ``rope_factor`` > 1 is YaRN
     rope_theta: float = 10000.0
     rope_factor: float = 1.0
     rope_orig_len: int = 4096
@@ -122,14 +126,48 @@ class TransformerConfig:
     moe_shared_d_ff: int = 0
     moe_renormalize: bool = True
     experts_held: tuple = ()
+    # True: an expert layer's router reads the layer's input, before the
+    # first norm and before attention (the experts are known while
+    # attention still runs); False: the rows it dispatches, ``norm2``'s.
+    moe_router_pre_attention: bool = False
+    # Width of a head of the fused-QKV mixer; 0: ``d_model // n_heads``.
+    # With it ``wqkv`` is [E, (n_heads + 2 kv_heads) head_dim] and ``wo``
+    # [n_heads head_dim, E].
+    head_dim: int = 0
+    # The fused-QKV mixer's attention setting, one entry a layer (() is 0
+    # everywhere): ``attn_windows`` the window ``W`` (query t sees the keys
+    # ``0 <= t - j < W``; 0: the whole causal prefix), ``attn_rope`` 1
+    # where q and k are rotated (rotate-half over the whole head, positions
+    # 0 .. T - 1).  Both are static: a run of equal layers is cut where
+    # either changes.  Training path only (ROADMAP R-m4).
+    attn_windows: tuple = ()
+    attn_rope: tuple = ()
 
     def __post_init__(self):
         # a configuration read from JSON brings a list
-        object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        object.__setattr__(self, "mlp_types", tuple(self.mlp_types))
-        object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        for key in ("layer_types", "mlp_types", "experts_held",
+                    "attn_windows", "attn_rope"):
+            object.__setattr__(self, key, tuple(getattr(self, key)))
         if not self.ssm_dt_rank:
             object.__setattr__(self, "ssm_dt_rank", -(-self.d_model // 16))
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
+        for key in ("attn_windows", "attn_rope"):
+            per_layer = getattr(self, key)
+            if not per_layer:
+                continue
+            assert len(per_layer) == self.n_layers, \
+                "%s names %d layers, n_layers is %d" % (
+                    key, len(per_layer), self.n_layers)
+            assert all(int(v) == v and v >= 0 for v in per_layer), key
+            assert self.attention == "mha" or not any(per_layer), \
+                "%s beside latent attention: its rotary part and its " \
+                "scores are `models/mla.py`'s own" % key
+            assert not any(v and kind == "mamba" for v, kind in
+                           zip(per_layer, self.layer_types)), \
+                "%s names a Mamba layer: a window or a rotary term is " \
+                "an attention layer's" % key
         if self.layer_types:
             assert len(self.layer_types) == self.n_layers, \
                 "layer_types names %d layers, n_layers is %d" % (
@@ -148,12 +186,10 @@ class TransformerConfig:
             assert 1 <= self.moe_top_k <= self.n_experts
             assert all(0 <= e < self.n_experts for e in self.experts_held)
         assert self.attention in ("mha", "mla")
-        assert self.mlp in ("gelu", "swiglu")
+        assert self.mlp in _ACT
         assert self.n_heads % self.kv_heads == 0
-
-    @property
-    def head_dim(self):
-        return self.d_model // self.n_heads
+        assert self.has_experts or not self.moe_router_pre_attention, \
+            "moe_router_pre_attention without an expert layer"
 
     @property
     def kv_heads(self):
@@ -171,25 +207,40 @@ class TransformerConfig:
     def n_held(self):
         return len(self.experts_held) or self.n_experts
 
+    def layer_keys(self):
+        """One key a layer, ``(mixer kind, (window, rotary), MLP kind)``:
+        what the layers of one scanned run have in common."""
+        L = self.n_layers
+        mixers = self.layer_types or ("attention",) * L
+        mlps = self.mlp_types or ("moe" if self.use_moe else "dense",) * L
+        windows = self.attn_windows or (0,) * L
+        ropes = self.attn_rope or (0,) * L
+        return [(mixers[i], (int(windows[i]), bool(ropes[i])), mlps[i])
+                for i in range(L)]
+
     def layer_runs(self):
-        """``[(kind, lo, hi, kind_lo, kind_hi)]``: maximal runs of layers of
-        one kind, as ranges over all layers and over the layers of that
-        kind (the index into the ``attn.`` / ``ssm.`` stacks)."""
-        return _runs(self.layer_types)
+        """``[(key, lo, hi, own)]``: maximal runs of layers of one key
+        (``layer_keys``), as a range over all layers and, in ``own``, over
+        the layers of the run's mixer kind and of its MLP kind (the index
+        into the ``attn.`` / ``ssm.`` and ``dense.`` / ``moe.`` stacks)."""
+        return _runs(self.layer_keys())
 
 
-def _runs(types):
-    """Maximal runs of equal entries of ``types`` (see ``layer_runs``)."""
+def _runs(keys):
+    """Maximal runs of equal entries of ``keys`` (see ``layer_runs``)."""
     runs, seen = [], {}
-    for i, kind in enumerate(types):
-        at = seen.get(kind, 0)
-        if runs and runs[-1][0] == kind:
+    for i, key in enumerate(keys):
+        mixer, _setting, mlp = key
+        if runs and runs[-1][0] == key:
             runs[-1][2] = i + 1
-            runs[-1][4] = at + 1
         else:
-            runs.append([kind, i, i + 1, at, at + 1])
-        seen[kind] = at + 1
-    return [tuple(r) for r in runs]
+            runs.append([key, i, i + 1,
+                         {kind: seen.get(kind, 0) for kind in (mixer, mlp)}])
+        for kind in (mixer, mlp):
+            seen[kind] = seen.get(kind, 0) + 1
+    return [(key, lo, hi, {kind: (at, at + hi - lo)
+                           for kind, at in own.items()})
+            for key, lo, hi, own in runs]
 
 
 def default_rules() -> ShardingRules:
@@ -215,11 +266,12 @@ def default_rules() -> ShardingRules:
     ])
 
 
-def _dense_self_attention(q, k, v, causal=True, scale=None):
+def _dense_self_attention(q, k, v, causal=True, scale=None, window=None):
     """Plain materialized attention for short sequences, one fused QK^T ->
     softmax -> PV chain; memory is O(T^2) so the caller gates it by
     ``dense_attn_max_score_mb`` (not re-measured against the flash kernel
-    since PR 27; ROADMAP S1 (c))."""
+    since PR 27; ROADMAP S1 (c)).  ``window``: the flash kernels' band,
+    ``0 <= t - j < window``."""
     B, T, H, D = q.shape
     qh = q.transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
@@ -229,6 +281,8 @@ def _dense_self_attention(q, k, v, causal=True, scale=None):
     s = s / math.sqrt(D) if scale is None else s * scale
     if causal:
         mask = jnp.tril(jnp.ones((T, T), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((T, T), bool), -int(window))
         s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, vh,
@@ -247,6 +301,10 @@ def _dense_self_attention(q, k, v, causal=True, scale=None):
 MIXER_OUT = "mixer_out"
 QKV_NAME = "attn_qkv"
 KEPT = _SCAN_KEPT + _FLASH_KEPT + (IN_PROJ_NAME, QKV_NAME, MIXER_OUT)
+
+# the activation ``TransformerConfig.mlp`` names: of ``up(h)`` in the first
+# form, of ``gate(h)`` in the gated ones
+_ACT = {"gelu": jax.nn.gelu, "swiglu": jax.nn.silu, "reglu": jax.nn.relu}
 
 # The longest run of equal layers the layer scan unrolls whole
 # (``scan_unroll=True``); a longer run is a loop of one layer a body.
@@ -315,7 +373,7 @@ class TransformerLM:
         # the MLP halves: one stack under ``blocks.``, or with ``mlp_types``
         # the dense layers' under ``dense.`` and the expert layers' under
         # ``moe.``
-        gated = cfg.mlp == "swiglu"
+        gated = cfg.mlp != "gelu"
         n_moe = (cfg.mlp_types.count("moe") if cfg.mlp_types
                  else L * cfg.use_moe)
         dense, moe = (("dense.", "moe.") if cfg.mlp_types
@@ -349,22 +407,41 @@ class TransformerLM:
     def _block(self, bp, x, mixer, scope="attn"):
         """The one layer body of training, prefill and decode: norm ->
         ``mixer(bp, h)`` -> residual -> MLP.  Returns ``(x, aux, state)``,
-        ``state`` being whatever the mixer hands back beside its output."""
+        ``state`` being whatever the mixer hands back beside its output.
+        Where the configuration places an expert layer's router before
+        attention, it reads the layer's input here, ahead of the norm, and
+        the MLP half is handed what it chose."""
+        routing = None
         with jax.named_scope(scope):
+            if self.cfg.moe_router_pre_attention and "gate" in bp:
+                routing = self._route(bp, x)
             h = self._rmsnorm(x, bp["ln1_scale"])
             o, state = mixer(bp, h)
             x = x + constraint(checkpoint_name(o, MIXER_OUT),
                                "dp", "sp", None)
         with jax.named_scope("mlp"):
-            x, aux = self._mlp_half(bp, x)
+            x, aux = self._mlp_half(bp, x, routing)
         return x, aux, state
 
-    def _qkv(self, bp, h):
+    def _route(self, bp, x):
+        """An expert layer's router on ``x`` [B, T, E], run apart from the
+        rows the layer dispatches: `parallel/moe.py::route`'s result."""
+        from .. import telemetry as _telemetry
+        cfg = self.cfg
+        B, T, E = x.shape
+        _telemetry.registry().counter("moe.router.pre_attention").inc()
+        with jax.named_scope("moe.route"):
+            return route(x.reshape(B * T, E), bp["gate"], cfg.moe_top_k,
+                         cfg.moe_renormalize, (B, T))
+
+    def _qkv(self, bp, h, rope=False):
         """The fused projection of ``h`` [B, T, E], split into heads:
-        q [B, T, H, D] and k, v [B, T, KV, D].  Each is named
-        (``QKV_NAME``) heads first, [B, heads, T, D], as the flash kernels
-        take it: a rematerialised layer that keeps the name hands the
-        backward kernels the stored value as it lies (PERF.md §6, PR 33)."""
+        q [B, T, H, D] and k, v [B, T, KV, D]; with ``rope`` q and k turned
+        by their positions 0 .. T - 1.  Each is named (``QKV_NAME``) heads
+        first, [B, heads, T, D], as the flash kernels take it, q and k
+        after the rotation: a rematerialised layer that keeps the name
+        hands the backward kernels the stored value as it lies (PERF.md §6,
+        PR 33)."""
         cfg = self.cfg
         B, T, _ = h.shape
         H, D, KV = cfg.n_heads, cfg.head_dim, cfg.kv_heads
@@ -372,12 +449,18 @@ class TransformerLM:
                          preferred_element_type=jnp.float32).astype(h.dtype)
         qkv = constraint(qkv, "dp", "sp", "tp")
 
-        def heads(x, n):
-            x = x.reshape(B, T, n, D).transpose(0, 2, 1, 3)
+        tables = rope_tables(cfg, D, T) if rope else None
+
+        def heads(x, n, turn=False):
+            x = x.reshape(B, T, n, D)
+            if turn:
+                with jax.named_scope("attn.rope"):
+                    x = rotate_half(x, *tables)
+            x = x.transpose(0, 2, 1, 3)
             return checkpoint_name(x, QKV_NAME).transpose(0, 2, 1, 3)
 
         q, k, v = jnp.split(qkv, [H * D, (H + KV) * D], axis=-1)
-        return heads(q, H), heads(k, KV), heads(v, KV)
+        return heads(q, H, rope), heads(k, KV, rope), heads(v, KV)
 
     def _attn_out(self, bp, attn):
         """The output projection of ``attn`` [B, T, H, D]."""
@@ -386,13 +469,15 @@ class TransformerLM:
                           preferred_element_type=jnp.float32
                           ).astype(attn.dtype)
 
-    def _self_attention(self, bp, h, use_ring=False):
+    def _self_attention(self, bp, h, use_ring=False, window=0, rope=False):
         """The mixer of training and prefill: causal self-attention over
-        ``h``; its state is the layer's ``(k, v)``, for the page write."""
+        ``h``, over a ``window`` of it where that is not 0, q and k rotated
+        with ``rope``; its state is the layer's ``(k, v)``, for the page
+        write."""
         cfg = self.cfg
         B, T, _ = h.shape
         H, KV = cfg.n_heads, cfg.kv_heads
-        q, k, v = self._qkv(bp, h)
+        q, k, v = self._qkv(bp, h, rope)
         kh, vh = k, v
         if KV != H:
             # Shared key/value heads are broadcast to their query heads
@@ -403,18 +488,24 @@ class TransformerLM:
             # kernels' grid does anyway.
             kh = jnp.repeat(k, H // KV, axis=2)
             vh = jnp.repeat(v, H // KV, axis=2)
-        return self._attn_out(bp, self._attend(q, kh, vh, None, use_ring)
-                              ), (k, v)
+        return self._attn_out(bp, self._attend(q, kh, vh, None, use_ring,
+                                               window or None)), (k, v)
 
-    def _attend(self, q, k, v, scale=None, use_ring=False):
+    def _attend(self, q, k, v, scale=None, use_ring=False, window=None):
         """Causal attention of q, k [B, T, H, D] and v [B, T, H, Dv] by
-        the implementation the shape and the mesh call for."""
+        the implementation the shape and the mesh call for; ``window``:
+        over the keys ``0 <= t - j < window`` alone."""
         B, T, H, _ = q.shape
         if use_ring:
+            assert window is None, \
+                "ring attention over sp with a window: not built (a " \
+                "shard would pass on only the blocks its band reaches)"
             return ring_self_attention(q, k, v, causal=True)
         if B * H * T * T * 4 / 1e6 <= self.cfg.dense_attn_max_score_mb:
-            return _dense_self_attention(q, k, v, causal=True, scale=scale)
-        return flash_attention(q, k, v, causal=True, scale=scale)
+            return _dense_self_attention(q, k, v, causal=True, scale=scale,
+                                         window=window)
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               window=window)
 
     def _ssm(self, bp, h):
         """The mixer of a state-space layer (`models/mamba.py`)."""
@@ -425,31 +516,37 @@ class TransformerLM:
         return mla_mixer(bp, h, self.cfg, self._attend), None
 
     def _mlp(self, h, w_up, w_down, w_gate):
-        """``down(gelu(up(h)))``, or with ``w_gate`` ``down(silu(gate(h)) *
-        up(h))``: a dense layer's MLP and an expert layer's shared expert."""
+        """``down(gelu(up(h)))``, or with ``w_gate`` ``down(act(gate(h)) *
+        up(h))``, ``act`` the gated form's that ``mlp`` names: a dense
+        layer's MLP and an expert layer's shared expert."""
         up = jnp.einsum("bte,ef->btf", h, w_up,
                         preferred_element_type=jnp.float32)
         if w_gate is not None:
             gate = jnp.einsum("bte,ef->btf", h, w_gate,
                               preferred_element_type=jnp.float32)
-            up = jax.nn.silu(gate) * up
+            up = _ACT[self.cfg.mlp](gate) * up
         else:
             up = jax.nn.gelu(up)
         up = constraint(up.astype(h.dtype), "dp", "sp", "tp")
         return jnp.einsum("btf,fe->bte", up, w_down,
                           preferred_element_type=jnp.float32).astype(h.dtype)
 
-    def _experts(self, bp, h):
+    def _experts(self, bp, h, routing=None):
         """An expert layer's MLP on the normed ``h``: the routed experts
         held here (under a mesh with an ``ep`` axis still the capacity
-        dispatch over all of them) plus the shared expert.  Returns ``(ff,
-        [balance term, pairs that landed on held experts])``."""
+        dispatch over all of them) plus the shared expert.  ``routing``:
+        what a router placed before attention chose (None: the router reads
+        ``h``).  Returns ``(ff, [balance term, pairs that landed on held
+        experts])``."""
         cfg = self.cfg
         from ..parallel.mesh import current_mesh
         mesh = current_mesh()
         if mesh is not None and mesh.size("ep") > 1:
             assert cfg.mlp == "gelu" and not cfg.experts_held, \
                 "the capacity dispatch over ep: ungated experts, all held"
+            assert routing is None, \
+                "the capacity dispatch over ep with a pre-attention " \
+                "router: its router reads the rows it dispatches"
             ff, aux = moe_layer(h, bp["gate"], bp["moe_up"], bp["moe_down"],
                                 top_k=cfg.moe_top_k,
                                 renormalize=cfg.moe_renormalize,
@@ -460,17 +557,18 @@ class TransformerLM:
                 h, bp["gate"], bp["moe_up"], bp["moe_down"],
                 bp.get("moe_gate"), top_k=cfg.moe_top_k,
                 experts_held=cfg.experts_held or None,
-                renormalize=cfg.moe_renormalize)
+                renormalize=cfg.moe_renormalize, act=_ACT[cfg.mlp],
+                routing=routing)
         if "shared_up" in bp:
             with jax.named_scope("moe.shared"):
                 ff = ff + self._mlp(h, bp["shared_up"], bp["shared_down"],
                                     bp.get("shared_gate"))
         return ff, jnp.stack([aux, held])
 
-    def _mlp_half(self, bp, x):
+    def _mlp_half(self, bp, x, routing=None):
         h = self._rmsnorm(x, bp["ln2_scale"])
         if "gate" in bp:                    # the router: an expert layer
-            ff, aux = self._experts(bp, h)
+            ff, aux = self._experts(bp, h, routing)
         else:
             ff = self._mlp(h, bp["w_up"], bp["w_down"],
                            bp["w_gate"] if "w_gate" in bp else None)
@@ -509,6 +607,11 @@ class TransformerLM:
                 "paged decode does not support layer_types yet: a "
                 "state-space layer needs its state kept beside the KV "
                 "pages (ROADMAP R-m5)")
+        if any(cfg.attn_windows) or any(cfg.attn_rope):
+            raise NotImplementedError(
+                "paged decode does not support per-layer windows or a "
+                "rotary term yet: the pool gives every layer every page "
+                "and a decode step knows no position (ROADMAP R-m4)")
         if (cfg.kv_heads != cfg.n_heads or cfg.mlp != "gelu"
                 or cfg.tie_embeddings):
             raise NotImplementedError(
@@ -519,7 +622,8 @@ class TransformerLM:
         """Allocate zeroed paged KV storage: ([L,P,ps,H,D], same) pair."""
         cfg = self.cfg
         if (cfg.layer_types or cfg.kv_heads != cfg.n_heads
-                or cfg.attention == "mla"):
+                or cfg.attention == "mla" or any(cfg.attn_windows)
+                or any(cfg.attn_rope)):
             self._refuse_serving()
         shape = (cfg.n_layers, num_pages, page_size, cfg.n_heads,
                  cfg.head_dim)
@@ -634,26 +738,33 @@ class TransformerLM:
         block_names = [k for k in params if k.startswith("blocks.")]
         stacked = {k.split(".", 1)[1]: params[k] for k in block_names}
         # ring attention contains shard_map, which composes under scan/jit
+        from .. import telemetry as _telemetry
         from ..parallel.mesh import current_mesh
         mesh = current_mesh()
         use_ring = mesh is not None and mesh.size("sp") > 1
+        counter = _telemetry.registry().counter
 
-        if cfg.attention == "mla":
-            assert not use_ring, "latent attention over sp: not built"
-            attention = self._mla
-        else:
-            attention = functools.partial(self._self_attention,
-                                          use_ring=use_ring)
+        @functools.lru_cache(maxsize=None)
+        def body_of(mixer, setting):
+            """The scanned body of a run of layers of one mixer kind and
+            one attention setting: one function however many runs share
+            it, so that they are traced once."""
+            if mixer == "mamba":
+                mix, scope = self._ssm, "ssm"
+            elif cfg.attention == "mla":
+                assert not use_ring, "latent attention over sp: not built"
+                mix, scope = self._mla, "attn"
+            else:
+                window, rope = setting
+                mix, scope = functools.partial(
+                    self._self_attention, use_ring=use_ring, window=window,
+                    rope=rope), "attn"
 
-        def body(carry, bp):
-            x, aux = carry
-            x, a, _kv = self._block(bp, x, attention)
-            return (x, aux + a), None
-
-        def ssm_body(carry, bp):
-            x, aux = carry
-            x, a, _ = self._block(bp, x, self._ssm, scope="ssm")
-            return (x, aux + a), None
+            def body(carry, bp):
+                x, aux = carry
+                x, a, _ = self._block(bp, x, mix, scope=scope)
+                return (x, aux + a), None
+            return body
 
         def layers(carry, run_body, run, kind="attention"):
             """A run of layers of one kind, scanned over ``run``, their
@@ -665,30 +776,30 @@ class TransformerLM:
                     .save_only_these_names(*KEPT))
             n = len(run["ln1_scale"])
             unroll = _layers_a_body(n, cfg.scan_unroll)
-            from .. import telemetry as _telemetry
-            _telemetry.registry().counter(
-                "lm.layers.%s.%dx%d" % (kind, n, unroll)).inc()
+            counter("lm.layers.%s.%dx%d" % (kind, n, unroll)).inc()
             return lax.scan(run_body, carry, run, unroll=unroll)[0]
 
         carry = (x, jnp.zeros((2,), jnp.float32) if cfg.has_experts
                  else jnp.float32(0.0))
-        if not (cfg.layer_types or cfg.mlp_types):
-            carry = layers(carry, body, stacked)
-        else:
-            # one scan a run of layers of one kind, over that run's slice of
-            # the common stack and of the kind's own
-            own = {kind: {k.split(".", 1)[1]: v for k, v in params.items()
-                          if k.startswith(prefix)}
-                   for kind, prefix in (("attention", "attn."),
-                                        ("mamba", "ssm."),
-                                        ("dense", "dense."),
-                                        ("moe", "moe."))}
-            for kind, lo, hi, klo, khi in _runs(cfg.layer_types
-                                                or cfg.mlp_types):
-                run = {k: v[lo:hi] for k, v in stacked.items()}
+        # one scan a run of equal layers, over that run's slice of the
+        # common stack and of its mixer's and its MLP kind's own
+        own = {kind: {k.split(".", 1)[1]: v for k, v in params.items()
+                      if k.startswith(prefix)}
+               for kind, prefix in (("attention", "attn."),
+                                    ("mamba", "ssm."),
+                                    ("dense", "dense."),
+                                    ("moe", "moe."))}
+        for (mixer, setting, mlp), lo, hi, at in cfg.layer_runs():
+            run = {k: v[lo:hi] for k, v in stacked.items()}
+            for kind in (mixer, mlp):
+                klo, khi = at[kind]
                 run.update({k: v[klo:khi] for k, v in own[kind].items()})
-                carry = layers(
-                    carry, ssm_body if kind == "mamba" else body, run, kind)
+            if mixer == "attention" and cfg.attention == "mha":
+                counter("lm.attn.%s.%s.%d" % (
+                    "window" if setting[0] else "full",
+                    "rope" if setting[1] else "nope", hi - lo)).inc()
+            carry = layers(carry, body_of(mixer, setting), run,
+                           mlp if cfg.mlp_types else mixer)
         return carry
 
     def held_slot_share(self, params, tokens):

@@ -21,7 +21,10 @@ flash attention entirely; its kernel corpus lives in
 * under ``causal`` a tile wholly above the diagonal is neither fetched (its
   ``index_map`` clamps to the last needed tile, so no DMA is issued) nor
   computed (``pl.when``); the mask runs only on tiles the diagonal crosses
-  or that hold key padding;
+  or that hold key padding; with ``window`` (``W``: query ``t`` sees the
+  keys ``0 <= t - j < W``) the same holds of the band: a tile wholly outside
+  it is neither fetched nor computed, one an edge of the band crosses is
+  masked, one inside runs bare; ``window=None`` builds the causal kernels;
 * forward saves per-row logsumexp; backward recomputes probabilities from
   (q, k, lse) in two Pallas kernels (dq over k blocks; dk/dv over q blocks)
   — no O(T^2) residuals;
@@ -33,8 +36,11 @@ flash attention entirely; its kernel corpus lives in
   ``preferred_element_type``; scores, softmax statistics, ``lse``,
   ``delta`` and all accumulators are f32 regardless of input dtype;
 * at trace time the counter ``pallas.flash.tile.<kernel>.<bq>x<bk>`` and the
-  gauge ``pallas.flash.causal_tiles_run_share`` (tiles visited / tiles of
-  the grid, last traced kernel) record the schedule;
+  gauge ``pallas.flash.causal_tiles_run_share`` (tiles on or under the
+  diagonal / tiles of the grid, last traced kernel) record the schedule, and
+  for a call with a window ``pallas.flash.window.<kernel>.<W>`` and the gauge
+  ``pallas.flash.band_tiles_run_share`` (tiles that touch the band / tiles
+  of the grid);
 * called with ``interpret=None`` the public entry points ask
   ``common.kernel_impl``: the kernels, the kernels inside a ``shard_map``
   over the mesh's batch and head axes (``_over_mesh``), or
@@ -136,25 +142,35 @@ def _row_map(b, h, i, j):
     return (b, h, i, 0)
 
 
-def _inner_map(causal, block_q, block_k, n_inner, inner_is_k):
+def _inner_map(causal, block_q, block_k, n_inner, inner_is_k, window=None):
     """Block index of an operand tiled along the grid's inner (reduction)
     axis.  Under ``causal`` a step that ``_visit`` skips keeps the index of
     the nearest tile that is needed, so the pipeline sees an unchanged block
     and issues no DMA: with k inside, the last k-tile the q-tile needs (the
     one holding its last row); with q inside, the first q-tile the k-tile
     needs (the one holding its first column; a k-tile past every query
-    needs none and keeps the last)."""
+    needs none and keeps the last).  With ``window`` the other end is held
+    too: with k inside, the first k-tile the q-tile needs (the one holding
+    its first row's oldest key); with q inside, the last q-tile the k-tile
+    needs (the one holding the last query that still sees its last
+    column)."""
     def index_map(b, h, i, j):
         if causal and inner_is_k:
             j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+            if window is not None:
+                j = jnp.maximum(
+                    j, jnp.maximum(i * block_q - window + 1, 0) // block_k)
         elif causal:
             j = jnp.maximum(j, jnp.minimum((i * block_k) // block_q,
                                            n_inner - 1))
+            if window is not None:
+                j = jnp.minimum(
+                    j, (i * block_k + block_k + window - 2) // block_q)
         return (b, h, j, 0)
     return index_map
 
 
-def _note_tiles(kernel, block_q, block_k, nq, nk, causal):
+def _note_tiles(kernel, block_q, block_k, nq, nk, causal, window=None):
     """Trace-time telemetry: which tile each kernel was built with, and the
     share of the grid's tiles the last traced call visits."""
     from ... import telemetry as _telemetry
@@ -162,21 +178,31 @@ def _note_tiles(kernel, block_q, block_k, nq, nk, causal):
     reg.counter("pallas.flash.tile.%s.%dx%d"
                 % (kernel, block_q, block_k)).inc()
     run = nq * nk
-    if causal:                                 # _visit's own condition
+    if causal:                                 # _visit's own conditions
         run = sum(ki * block_k <= qi * block_q + block_q - 1
                   for qi in range(nq) for ki in range(nk))
     reg.gauge("pallas.flash.causal_tiles_run_share").set(run / (nq * nk))
+    if window is not None:
+        reg.counter("pallas.flash.window.%s.%d" % (kernel, window)).inc()
+        band = sum(ki * block_k <= qi * block_q + block_q - 1
+                   and ki * block_k + block_k - 1 + window > qi * block_q
+                   for qi in range(nq) for ki in range(nk))
+        reg.gauge("pallas.flash.band_tiles_run_share").set(band / (nq * nk))
 
 
-def _visit(step, q0, k0, block_q, block_k, causal, kv_len, Tk):
+def _visit(step, q0, k0, block_q, block_k, causal, kv_len, Tk, window=None):
     """Run ``step(masked)`` for the tile whose first row is ``q0`` and first
     column ``k0``: not at all where it lies wholly above the causal
-    diagonal, masked where the diagonal crosses it or it holds key padding,
-    bare where it lies wholly below."""
+    diagonal (or, with ``window``, wholly behind the band ``0 <= q - k <
+    window``), masked where the diagonal (or the band's far edge) crosses
+    it or it holds key padding, bare where it lies wholly inside."""
     pad = None if kv_len == Tk else k0 + block_k > kv_len
     if causal:
         run = k0 <= q0 + block_q - 1
         need = k0 + block_k - 1 > q0
+        if window is not None:
+            run = run & (k0 + block_k - 1 + window > q0)
+            need = need | (k0 + window <= q0 + block_q - 1)
         if pad is not None:
             need = need | pad
         pl.when(run & need)(lambda: step(True))
@@ -188,14 +214,17 @@ def _visit(step, q0, k0, block_q, block_k, causal, kv_len, Tk):
         pl.when(jnp.logical_not(pad))(lambda: step(False))
 
 
-def _mask(s, q0, k0, causal, kv_len, k_axis):
-    """``_NEG`` where a key is padding or (``causal``) after its query;
-    ``k_axis`` is the axis of ``s`` that runs over keys."""
+def _mask(s, q0, k0, causal, kv_len, k_axis, window=None):
+    """``_NEG`` where a key is padding, (``causal``) after its query or
+    (``window``) that many positions or more before it; ``k_axis`` is the
+    axis of ``s`` that runs over keys."""
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
     valid = k_pos < kv_len
     if causal:
         q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - k_axis)
         valid = valid & (q_pos >= k_pos)
+        if window is not None:
+            valid = valid & (q_pos - k_pos < window)
     return jnp.where(valid, s, _NEG)
 
 
@@ -218,7 +247,7 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                scale, causal, block_q, block_k, kv_len, Tk):
+                scale, causal, block_q, block_k, kv_len, Tk, window):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -235,7 +264,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         v = v_ref[0, 0]
         s = _dot(q, k, _NT) * scale                    # (bq, bk) f32
         if masked:
-            s = _mask(s, qi * block_q, ki * block_k, causal, kv_len, 1)
+            s = _mask(s, qi * block_q, ki * block_k, causal, kv_len, 1, window)
         m_prev = m_ref[:, :1]                          # (bq, 1)
         l_prev = l_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -247,7 +276,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
     _visit(step, qi * block_q, ki * block_k, block_q, block_k, causal,
-           kv_len, Tk)
+           kv_len, Tk, window)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -258,21 +287,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         lse_ref[0, 0] = m_ref[:, :1] + jnp.log(safe)
 
 
-def _fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
+def _name(kernel, window):
+    """A call's name in the compiled program and the device trace."""
+    return kernel if window is None else kernel + "_window"
+
+
+def _fwd(q, k, v, causal, scale, tiles, kv_len, interpret, window=None):
     B, H, Tq, D = q.shape
     Tk, Dv = k.shape[2], v.shape[3]
     block_q, block_k = tiles[0]
     nq, nk = Tq // block_q, Tk // block_k
-    _note_tiles("fwd", block_q, block_k, nq, nk, causal)
+    _note_tiles("fwd", block_q, block_k, nq, nk, causal, window)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               kv_len=kv_len, Tk=Tk)
+                               kv_len=kv_len, Tk=Tk, window=window)
 
     q_map = _row_map
-    k_map = _inner_map(causal, block_q, block_k, nk, inner_is_k=True)
+    k_map = _inner_map(causal, block_q, block_k, nk, True, window)
     call = pl.pallas_call(
         kernel,
-        name="flash_fwd",
+        name=_name("flash_fwd", window),
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), q_map),
@@ -309,7 +343,7 @@ def _fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, scale, causal, block_q, block_k, kv_len, Tk):
+               acc_ref, *, scale, causal, block_q, block_k, kv_len, Tk, window):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -325,14 +359,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         do = do_ref[0, 0]
         s = _dot(q, k, _NT) * scale
         if masked:
-            s = _mask(s, qi * block_q, ki * block_k, causal, kv_len, 1)
+            s = _mask(s, qi * block_q, ki * block_k, causal, kv_len, 1, window)
         p = jnp.exp(s - lse_ref[0, 0])                 # lse: (bq, 1)
         dp = _dot(do, v, _NT)
         ds = p * (dp - delta_ref[0, 0]) * scale
         acc_ref[:] += _dot(ds.astype(k.dtype), k, _NN)
 
     _visit(step, qi * block_q, ki * block_k, block_q, block_k, causal,
-           kv_len, Tk)
+           kv_len, Tk, window)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -341,7 +375,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *,
-                scale, causal, block_q, block_k, kv_len, Tk):
+                scale, causal, block_q, block_k, kv_len, Tk, window):
     ki = pl.program_id(2)
     qi = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -360,7 +394,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = jnp.transpose(delta_ref[0, 0])
         sT = _dot(k, q, _NT) * scale                   # transposed: (bk, bq)
         if masked:
-            sT = _mask(sT, qi * block_q, ki * block_k, causal, kv_len, 0)
+            sT = _mask(sT, qi * block_q, ki * block_k, causal, kv_len, 0,
+                       window)
         pT = jnp.exp(sT - lse)
         dv_acc[:] += _dot(pT.astype(do.dtype), do, _NN)
         dpT = _dot(v, do, _NT)
@@ -368,7 +403,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] += _dot(dsT.astype(q.dtype), q, _NN)
 
     _visit(step, qi * block_q, ki * block_k, block_q, block_k, causal,
-           kv_len, Tk)
+           kv_len, Tk, window)
 
     @pl.when(qi == nq - 1)
     def _():
@@ -377,7 +412,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
-         dlse=None):
+         window=None, dlse=None):
     B, H, Tq, D = q.shape
     Tk, Dv = k.shape[2], v.shape[3]
     # delta_i = rowsum(do_i * o_i) — cheap elementwise, XLA fuses it
@@ -392,10 +427,10 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
 
     block_q, block_k = tiles[1]
     nq, nk = Tq // block_q, Tk // block_k
-    _note_tiles("dq", block_q, block_k, nq, nk, causal)
+    _note_tiles("dq", block_q, block_k, nq, nk, causal, window)
 
     q_map = _row_map
-    k_map = _inner_map(causal, block_q, block_k, nk, inner_is_k=True)
+    k_map = _inner_map(causal, block_q, block_k, nk, True, window)
     qspec = pl.BlockSpec((1, 1, block_q, D), q_map)
     kspec = pl.BlockSpec((1, 1, block_k, D), k_map)
     vspec = pl.BlockSpec((1, 1, block_k, Dv), k_map)
@@ -404,8 +439,8 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
     dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, kv_len=kv_len,
-                          Tk=Tk),
-        name="flash_dq",
+                          Tk=Tk, window=window),
+        name=_name("flash_dq", window),
         grid=(B, H, nq, nk),
         in_specs=[qspec, kspec, vspec, dospec, rowq, rowq],
         out_specs=[qspec],
@@ -424,9 +459,9 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
     # grid transposed: outer k blocks, inner (sequential) q blocks
     block_q, block_k = tiles[2]
     nq, nk = Tq // block_q, Tk // block_k
-    _note_tiles("dkv", block_q, block_k, nq, nk, causal)
+    _note_tiles("dkv", block_q, block_k, nq, nk, causal, window)
 
-    q_map2 = _inner_map(causal, block_q, block_k, nq, inner_is_k=False)
+    q_map2 = _inner_map(causal, block_q, block_k, nq, False, window)
     k_map2 = _row_map
     qspec2 = pl.BlockSpec((1, 1, block_q, D), q_map2)
     kspec2 = pl.BlockSpec((1, 1, block_k, D), k_map2)
@@ -436,8 +471,8 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
     dkv_call = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, kv_len=kv_len,
-                          Tk=Tk),
-        name="flash_dkv",
+                          Tk=Tk, window=window),
+        name=_name("flash_dkv", window),
         grid=(B, H, nk, nq),
         in_specs=[qspec2, kspec2, vspec2, dospec2, rowq2, rowq2],
         out_specs=[kspec2, vspec2],
@@ -461,59 +496,62 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
 # custom_vjp wrappers (operate on [B, H, T, D])
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, tiles, kv_len, interpret):
-    o, _ = _fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, scale, tiles, kv_len, interpret, window):
+    o, _ = _fwd(q, k, v, causal, scale, tiles, kv_len, interpret, window)
     return o
 
 
-def _named_fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
+def _named_fwd(q, k, v, causal, scale, tiles, kv_len, interpret, window):
     """The forward of both VJP rules, its results named (``SAVED_NAMES``).
     ``lse`` is named as the kernel writes it, [B, H, T, 1]: a row of 128
     lanes a value in HBM (67 MB at 4 x 16 x 2048 for 0.5 MB of values).
     Named as [B, H, T] it is kept small and costs two copies a layer, 0.8 %
     of the Pythia cell's step where the room was not needed (PERF.md §6,
     PR 31)."""
-    o, lse = _fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
+    o, lse = _fwd(q, k, v, causal, scale, tiles, kv_len, interpret, window)
     return (checkpoint_name(o, SAVED_NAMES[0]),
             checkpoint_name(lse, SAVED_NAMES[1]))
 
 
-def _flash_fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
-    o, lse = _named_fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
+def _flash_fwd(q, k, v, causal, scale, tiles, kv_len, interpret, window):
+    o, lse = _named_fwd(q, k, v, causal, scale, tiles, kv_len, interpret,
+                        window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, tiles, kv_len, interpret, res, do):
+def _flash_bwd(causal, scale, tiles, kv_len, interpret, window, res, do):
     q, k, v, o, lse = res
-    return _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret)
+    return _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
+                window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_lse(q, k, v, causal, scale, tiles, kv_len, interpret):
-    return _fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q, k, v, causal, scale, tiles, kv_len, interpret, window):
+    return _fwd(q, k, v, causal, scale, tiles, kv_len, interpret, window)
 
 
-def _flash_lse_fwd(q, k, v, causal, scale, tiles, kv_len, interpret):
-    o, lse = _named_fwd(q, k, v, causal, scale, tiles, kv_len, interpret)
+def _flash_lse_fwd(q, k, v, causal, scale, tiles, kv_len, interpret, window):
+    o, lse = _named_fwd(q, k, v, causal, scale, tiles, kv_len, interpret,
+                        window)
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_lse_bwd(causal, scale, tiles, kv_len, interpret, res, ct):
+def _flash_lse_bwd(causal, scale, tiles, kv_len, interpret, window, res, ct):
     q, k, v, o, lse = res
     do, dlse = ct
     return _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
-                dlse=dlse)
+                window, dlse=dlse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 def _attend(q, k, v, causal, scale, block_q, block_k, interpret, per_device,
-            with_lse):
+            with_lse, window=None):
     """What both entry points share: the choice of implementation, the
     tiles, the [B, H, T, D] layout and the padding to whole tiles.  Returns
     ``(o, lse)`` with ``lse`` [B, H, T] or None."""
@@ -521,16 +559,22 @@ def _attend(q, k, v, causal, scale, block_q, block_k, interpret, per_device,
     Tk, Dv = k.shape[1], v.shape[3]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
+    if window is not None:
+        assert causal and T == Tk and window >= 1, \
+            "a window is over a causal self-attention's own past"
+        if window >= Tk:                       # the whole causal prefix
+            window = None
     if interpret is None:
         # only the form without ``lse`` has a wrapper for a mesh
         impl = kernel_impl("flash_attention", sharded=not with_lse,
                            per_device=per_device)
         if impl == "sharded":
-            return _over_mesh(q, k, v, causal, scale, block_q, block_k), None
+            return _over_mesh(q, k, v, causal, scale, block_q, block_k,
+                              window), None
         if impl == "fallback":
             from ...parallel.ring_attention import blockwise_attention
             out = blockwise_attention(q, k, v, causal=causal, scale=scale,
-                                      return_lse=with_lse)
+                                      return_lse=with_lse, window=window)
             return out if with_lse else (out, None)
         interpret = impl == "interpret"
 
@@ -547,27 +591,29 @@ def _attend(q, k, v, causal, scale, block_q, block_k, interpret, per_device,
 
     qt, kt, vt = heads_first(q, pq), heads_first(k, pk), heads_first(v, pk)
     if with_lse:
-        o, lse = _flash_lse(qt, kt, vt, causal, scale, tiles, Tk, interpret)
+        o, lse = _flash_lse(qt, kt, vt, causal, scale, tiles, Tk, interpret,
+                            window)
         lse = lse[:, :, :T, 0]
     else:
-        o, lse = _flash(qt, kt, vt, causal, scale, tiles, Tk, interpret), None
+        o, lse = _flash(qt, kt, vt, causal, scale, tiles, Tk, interpret,
+                        window), None
     return o[:, :, :T].transpose(0, 2, 1, 3), lse
 
 
-def _over_mesh(q, k, v, causal, scale, block_q, block_k):
+def _over_mesh(q, k, v, causal, scale, block_q, block_k, window=None):
     """The kernels under an active mesh, q/k/v [B, T, H, D] with the batch
     possibly sharded on ``dp`` and the heads on ``tp``.
 
     GSPMD cannot partition a custom call, so the kernel is wrapped in
     ``shard_map`` over the batch/head axes (attention is independent per
-    batch element and head; sequence stays local — the sequence-sharded
-    case is `parallel.ring_attention`)."""
+    batch element and head; sequence stays local, and with it a window —
+    the sequence-sharded case is `parallel.ring_attention`)."""
     from ...parallel.mesh import current_mesh
     mesh = current_mesh()
     # inside the body each device holds its own shard: the kernel, forced
     local = functools.partial(flash_attention, causal=causal, scale=scale,
                               block_q=block_q, block_k=block_k,
-                              interpret=False)
+                              interpret=False, window=window)
     b = "dp" if mesh.size("dp") > 1 else None
     h = "tp" if mesh.size("tp") > 1 else None
     if b is None and h is None:
@@ -578,7 +624,8 @@ def _over_mesh(q, k, v, causal, scale, block_q, block_k):
         # call is unpartitionable by GSPMD, so fall back to the blockwise
         # lax path (which GSPMD shards/replicates freely)
         from ...parallel.ring_attention import blockwise_attention
-        return blockwise_attention(q, k, v, causal=causal, scale=scale)
+        return blockwise_attention(q, k, v, causal=causal, scale=scale,
+                                   window=window)
     from ...parallel.collectives import shard_map
     from jax.sharding import PartitionSpec as P
     spec = P(b, None, h, None)
@@ -587,10 +634,12 @@ def _over_mesh(q, k, v, causal, scale, block_q, block_k):
 
 
 def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
-                    block_k=None, interpret=None):
+                    block_k=None, interpret=None, window=None):
     """Flash attention over q, k [B, T, H, D] and v [B, T, H, Dv] (``Dv``
     is ``D`` unless the values have a width of their own); the result is
-    [B, T, H, Dv].  ``scale`` defaults to ``D ** -0.5``.
+    [B, T, H, Dv].  ``scale`` defaults to ``D ** -0.5``.  ``window`` (with
+    ``causal``): query ``t`` sees the keys ``0 <= t - j < window`` alone;
+    one that reaches over the whole sequence is the causal kernel.
 
     With ``interpret=None`` the implementation is ``common.kernel_impl``'s
     answer: the Pallas kernels above, the same inside a ``shard_map`` under
@@ -601,7 +650,7 @@ def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
     ``_choose_tiles`` takes from the shape.
     """
     return _attend(q, k, v, causal, scale, block_q, block_k, interpret,
-                   per_device=False, with_lse=False)[0]
+                   per_device=False, with_lse=False, window=window)[0]
 
 
 def flash_attention_lse(q, k, v, causal=True, scale=None, block_q=None,

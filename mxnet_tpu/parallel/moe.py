@@ -101,15 +101,20 @@ _from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
 
 
 def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
-                 experts_held=None, renormalize=True, act=jax.nn.gelu):
+                 experts_held=None, renormalize=True, act=None,
+                 routing=None):
     """The held experts' part of a routed feed-forward, no token dropped.
 
     x: [B, T, E]; router_w: [E, n] over all ``n`` experts; w_up (and
     w_gate): [held, E, F]; w_down: [held, F, E], stacked in the order of
     ``experts_held`` (ids among ``0 .. n - 1``; None: all of them).  An
-    expert is ``down(act(up(h)))``, or with ``w_gate`` ``down(silu(gate(h))
-    * up(h))``.  Returns ``(y [B, T, E], aux, held)``: ``y_t = sum over the
-    slots of t whose expert is held of w * expert(x_t)``, the router's
+    expert is ``down(act(up(h)))``, or with ``w_gate`` ``down(act(gate(h))
+    * up(h))``; ``act`` None is ``gelu`` for the first form and ``silu``
+    for the gated one.  ``routing`` is :func:`route`'s result where the
+    router did not read the rows dispatched here (one placed before
+    attention reads the layer's input, and has run by now); None: the
+    router reads ``x``.  Returns ``(y [B, T, E], aux, held)``: ``y_t = sum
+    over the slots of t whose expert is held of w * expert(x_t)``, the router's
     balance term (over all ``n``, so every share computes it alike), and
     how many of the ``B T k`` pairs landed on held experts."""
     from .. import telemetry as _telemetry
@@ -126,9 +131,12 @@ def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
     reg.counter("moe.buffer_rows.%d" % P).inc()
 
     tokens = x.reshape(S, E)
-    with jax.named_scope("moe.route"):
-        weights, experts, aux = route(tokens, router_w, k, renormalize,
-                                      (B, T))
+    if act is None:
+        act = jax.nn.gelu if w_gate is None else jax.nn.silu
+    if routing is None:
+        with jax.named_scope("moe.route"):
+            routing = route(tokens, router_w, k, renormalize, (B, T))
+    weights, experts, aux = routing
     with jax.named_scope("moe.dispatch"):
         # a pair's key is its expert's place in the stack; an absent
         # expert's pairs sort behind every group and are never computed
@@ -144,7 +152,7 @@ def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
         up = grouped_matmul(rows, w_up, sizes).astype(jnp.float32)
         if w_gate is not None:
             gate = grouped_matmul(rows, w_gate, sizes).astype(jnp.float32)
-            up = jax.nn.silu(gate) * up
+            up = act(gate) * up
         else:
             up = act(up)
         out = grouped_matmul(up.astype(x.dtype), w_down, sizes)

@@ -178,7 +178,7 @@ def _ring_attention_flash(q, k, v, axis_name, causal, scale, interpret):
 
 
 def blockwise_attention(q, k, v, block_size=512, causal=True, scale=None,
-                        return_lse=False):
+                        return_lse=False, window=None):
     """Single-device memory-efficient attention: lax.scan over key blocks with
     the same streaming-softmax recurrence (O(T) memory in sequence length).
     The in-shard counterpart of `ring_attention`; also the CPU/interpret
@@ -187,7 +187,8 @@ def blockwise_attention(q, k, v, block_size=512, causal=True, scale=None,
     ``return_lse=True`` additionally returns the per-row logsumexp
     [B, H, T] of the scaled masked scores (fully-masked rows get ``_NEG``),
     matching `ops.pallas.flash_attention_lse` so either can serve as a
-    flash-decoding block kernel."""
+    flash-decoding block kernel.  ``window`` (with ``causal``): a query sees
+    the keys ``0 <= t - j < window`` alone, as the kernels' own mask."""
     B, T, H, D = q.shape
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     nb = max(1, -(-T // block_size))
@@ -212,6 +213,8 @@ def blockwise_attention(q, k, v, block_size=512, causal=True, scale=None,
         mask = valid[None, :]
         if causal:
             mask = mask & (q_pos[:, None] >= k_pos[None, :])
+            if window is not None:
+                mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
         s = jnp.where(mask[None, None], s, _NEG)
         o, m, l = _stream_update(o, m, l, s, v_blk)
         return (o, m, l), None

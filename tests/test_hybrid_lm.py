@@ -35,13 +35,19 @@ def tokens(batch=2, seq=24, vocab=128, seed=1):
 
 def test_runs_of_layers_and_their_index_into_each_stack():
     cfg = TransformerConfig(**HYBRID)
-    assert cfg.layer_runs() == [("mamba", 0, 1, 0, 1),
-                                ("attention", 1, 3, 0, 2),
-                                ("mamba", 3, 4, 1, 2)]
-    assert TransformerConfig(**JAMBA).layer_runs() == [
+    att, ssm = ("attention", (0, False), "dense"), ("mamba", (0, False),
+                                                    "dense")
+    assert cfg.layer_runs() == [
+        (ssm, 0, 1, {"mamba": (0, 1), "dense": (0, 1)}),
+        (att, 1, 3, {"attention": (0, 2), "dense": (1, 3)}),
+        (ssm, 3, 4, {"mamba": (1, 2), "dense": (3, 4)})]
+    assert [(key[0], lo, hi, *own[key[0]]) for key, lo, hi, own in
+            TransformerConfig(**JAMBA).layer_runs()] == [
         ("mamba", 0, 7, 0, 7), ("attention", 7, 8, 0, 1),
         ("mamba", 8, 14, 7, 13)]
-    assert TransformerConfig(**TOY).layer_runs() == []
+    assert TransformerConfig(**TOY).layer_runs() == [
+        (att, 0, TOY["n_layers"], {"attention": (0, TOY["n_layers"]),
+                                   "dense": (0, TOY["n_layers"])})]
     # a configuration read from JSON brings a list; dt_rank defaults to
     # ceil(d_model / 16)
     cfg = TransformerConfig(**dict(HYBRID, layer_types=list(

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 from jax import lax
 
-from mxnet_tpu.models import TransformerConfig, TransformerLM, mla
+from mxnet_tpu.models import TransformerConfig, TransformerLM, mla, rope
 from mxnet_tpu.models.transformer import default_rules, make_train_step
 from mxnet_tpu.ops.pallas import flash_attention, grouped_matmul
 from mxnet_tpu.parallel.moe import expert_layer, route
@@ -41,13 +41,13 @@ def tokens(batch=2, seq=24, vocab=512, seed=1):
 # -- YaRN -------------------------------------------------------------------
 def test_yarn_check_values_of_the_published_rope_scaling():
     cfg = TransformerConfig(**FULL)
-    assert mla.yarn_correction_range(64, 10000, 4096, 32, 1) == (10, 23)
-    m = mla.yarn_mscale(40, 0.707)
+    assert rope.yarn_correction_range(64, 10000, 4096, 32, 1) == (10, 23)
+    m = rope.yarn_mscale(40, 0.707)
     assert m == pytest.approx(1.26080, abs=1e-5)
     assert m * m == pytest.approx(1.58963, abs=1e-5)
     assert mla.softmax_scale(cfg) == pytest.approx(192 ** -0.5 * 1.58963,
                                                    rel=1e-5)
-    inv = mla.yarn_inv_freq(64, 10000, 40, 4096, 32, 1)
+    inv = rope.yarn_inv_freq(64, 10000, 40, 4096, 32, 1)
     base = 10000.0 ** (-np.arange(0, 64, 2) / 64)
     # published frequencies up to pair 10, a fortieth from pair 23 on, and a
     # linear blend between
@@ -56,7 +56,7 @@ def test_yarn_check_values_of_the_published_rope_scaling():
     assert inv[16] == pytest.approx(
         base[16] * (1 - 6 / 13) + base[16] / 40 * (6 / 13), rel=1e-12)
     # the factor on cos and sin is mscale / mscale_all_dim = 1
-    cos, sin = mla.rope_tables(cfg, 8)
+    cos, sin = rope.rope_tables(cfg, cfg.qk_rope_head_dim, 8)
     np.testing.assert_allclose(cos[0], 1.0)
     np.testing.assert_allclose(sin[1, :32], np.sin(inv), rtol=1e-5)
     np.testing.assert_allclose(ref.yarn_inv_freq(FULL), inv, rtol=1e-12)
@@ -68,15 +68,15 @@ def test_no_scaling_is_plain_rope_and_a_plain_scale():
                                    rope_mscale_all_dim=0.0))
     assert mla.softmax_scale(cfg) == pytest.approx(24 ** -0.5)
     np.testing.assert_allclose(
-        mla.yarn_inv_freq(8, 10000, 1.0, 32, 32, 1),
+        rope.yarn_inv_freq(8, 10000, 1.0, 32, 32, 1),
         10000.0 ** (-np.arange(0, 8, 2) / 8))
 
 
 def test_rotate_half_turns_each_pair_by_its_angle_and_keeps_the_norm():
     cfg = TransformerConfig(**TOY)
-    cos, sin = mla.rope_tables(cfg, 5)
+    cos, sin = rope.rope_tables(cfg, cfg.qk_rope_head_dim, 5)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 5, 3, 8))
-    y = mla.rotate_half(x, cos, sin)
+    y = rope.rotate_half(x, cos, sin)
     np.testing.assert_allclose(y[:, 0], x[:, 0], rtol=1e-6)  # position 0
     np.testing.assert_allclose(jnp.linalg.norm(y, axis=-1),
                                jnp.linalg.norm(x, axis=-1), rtol=1e-5)
@@ -394,7 +394,7 @@ def _experts(seed=0, n=8, held=8, E=16, F=24, gated=True):
         gate=jax.random.normal(ks[3], (held, E, F)) / 4 if gated else None)
 
 
-def _dense_oracle(x, w, top_k, held, renormalize=False):
+def _dense_oracle(x, w, top_k, held, renormalize=False, relu_gate=False):
     """Every token through its chosen experts, one at a time, in numpy."""
     toks = np.asarray(x, np.float64).reshape(-1, x.shape[-1])
     logits = toks @ np.asarray(w["router"], np.float64)
@@ -411,17 +411,22 @@ def _dense_oracle(x, w, top_k, held, renormalize=False):
             up = toks[t] @ np.asarray(w["up"][i], np.float64)
             if w["gate"] is not None:
                 a = toks[t] @ np.asarray(w["gate"][i], np.float64)
-                up = a / (1 + np.exp(-a)) * up
+                up = (np.maximum(a, 0) if relu_gate
+                      else a / (1 + np.exp(-a))) * up
             else:
                 up = np.maximum(up, 0)
             out[t] += gi * (up @ np.asarray(w["down"][i], np.float64))
     return out.reshape(x.shape)
 
 
-@pytest.mark.parametrize("top_k,gated,interpret", [
-    (2, True, False), (3, True, True), (1, False, True)])
+@pytest.mark.parametrize("top_k,gated,interpret,act", [
+    (2, True, False, None), (3, True, True, None),
+    (1, False, True, jax.nn.relu), (2, True, False, jax.nn.relu)],
+    ids=["2-swiglu", "3-swiglu-interpret", "1-relu-interpret", "2-reglu"])
 def test_expert_layer_is_each_token_through_its_chosen_experts(
-        top_k, gated, interpret, monkeypatch):
+        top_k, gated, interpret, act, monkeypatch):
+    """``act`` is the activation of ``up(h)`` in the first form and of
+    ``gate(h)`` in the gated one (None: ``silu`` there)."""
     if interpret:
         monkeypatch.setenv("MXTPU_PALLAS", "interpret")
     w = _experts(gated=gated)
@@ -429,9 +434,10 @@ def test_expert_layer_is_each_token_through_its_chosen_experts(
     with jax.default_matmul_precision("highest"):
         y, aux, held = expert_layer(x, w["router"], w["up"], w["down"],
                                     w["gate"], top_k=top_k,
-                                    renormalize=False, act=jax.nn.relu)
-    np.testing.assert_allclose(y, _dense_oracle(x, w, top_k, range(8)),
-                               rtol=1e-4, atol=1e-5)
+                                    renormalize=False, act=act)
+    np.testing.assert_allclose(
+        y, _dense_oracle(x, w, top_k, range(8), relu_gate=act is not None),
+        rtol=1e-4, atol=1e-5)
     assert float(held) == 2 * 12 * top_k
     # balanced routing would read 1; any routing reads a positive number
     assert float(aux) > 0

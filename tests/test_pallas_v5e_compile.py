@@ -209,6 +209,11 @@ LM_CASES = [
                  {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1,
                   "ssm_scan_fwd": 1, "ssm_scan_bwd": 1},
                  id="jamba2_3b_train_mamba_and_attention"),
+    pytest.param("smallthinker-21b-l4-e16", "pretrain_b1_s16384_ep4", 2,
+                 {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1,
+                  "flash_fwd_window": 1, "flash_dq_window": 1,
+                  "flash_dkv_window": 1},
+                 id="smallthinker_train_s16k_full_and_window"),
 ]
 
 
@@ -233,6 +238,9 @@ def test_lm_train_step_runs_each_kept_kernel_once_on_v5e(
         m.update(layer_types=layers, n_layers=len(layers))
     else:
         m.update(n_layers=layers)
+        for key in ("attn_windows", "attn_rope"):       # an entry a layer
+            if key in m:
+                m[key] = m[key][:layers]
     # the kernel rule sees the CPU this test runs on: tell it the backend
     # the program is compiled for
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -273,6 +281,32 @@ def test_flash_at_a_value_width_of_its_own_compiles_for_v5e(one_chip):
             lowering_platforms=("tpu",)).compile().as_text()
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
                           text)) == 3
+
+
+@pytest.mark.parametrize("T,W", [
+    pytest.param(16384, 4096, id="smallthinker_train_s16k_window_layer"),
+    pytest.param(4096, 1000, id="band_edge_inside_a_tile"),
+    pytest.param(1100, 300, id="ragged_1100_128_tiles")])
+def test_flash_with_a_window_compiles_for_v5e(one_chip, T, W):
+    """The three kernels with a band at the window-and-full-attention
+    cell's shape (1 x 28 heads x 16,384 x 128, bfloat16, ``W`` 4,096) and
+    where the band's edges fall inside tiles: named for the window, the
+    index maps' clamps at both ends taken by Mosaic."""
+    B, H, D = 1, 28 if T == 16384 else 2, 128
+    if T == 16384:
+        assert fa._choose_tiles(T, T, D, 2) == ((1024, 1024),) * 3
+    x = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one_chip)
+
+    def fwd_and_grads(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=W, interpret=False), q, k, v)
+        return out, vjp(do)
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(fwd_and_grads).trace(x, x, x, x).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    for name in ("flash_fwd_window", "flash_dq_window", "flash_dkv_window"):
+        assert name in text, name
 
 
 @pytest.mark.parametrize("M,K,N,G,tiles", [
