@@ -22,9 +22,13 @@ flash attention entirely; its kernel corpus lives in
   ``index_map`` clamps to the last needed tile, so no DMA is issued) nor
   computed (``pl.when``); the mask runs only on tiles the diagonal crosses
   or that hold key padding; with ``window`` (``W``: query ``t`` sees the
-  keys ``0 <= t - j < W``) the same holds of the band: a tile wholly outside
-  it is neither fetched nor computed, one an edge of the band crosses is
-  masked, one inside runs bare; ``window=None`` builds the causal kernels;
+  keys ``0 <= t - j < W``) the inner grid axis is as long as the longest run
+  of inner tiles that any outer tile's band touches, not as the sequence
+  (``_inner_axis``: 5 steps of 16 at T = 16,384, W = 4,096, tiles of 1,024),
+  and step ``j`` is the tile ``first(i) + j``, in the index maps and in the
+  kernels alike; a tile an edge of the band crosses is masked, one inside
+  runs bare, and the few steps a cut band leaves over are neither fetched
+  nor computed; ``window=None`` builds the causal kernels;
 * forward saves per-row logsumexp; backward recomputes probabilities from
   (q, k, lse) in two Pallas kernels (dq over k blocks; dk/dv over q blocks)
   — no O(T^2) residuals;
@@ -40,7 +44,10 @@ flash attention entirely; its kernel corpus lives in
   diagonal / tiles of the grid, last traced kernel) record the schedule, and
   for a call with a window ``pallas.flash.window.<kernel>.<W>`` and the gauge
   ``pallas.flash.band_tiles_run_share`` (tiles that touch the band / tiles
-  of the grid);
+  of the ``nq x nk`` grid); for every call
+  ``pallas.flash.inner_steps.<kernel>.<steps>of<tiles>`` (the inner axis'
+  length, of the sequence's inner tiles) and the gauge
+  ``pallas.flash.grid_steps_run_share`` (tiles that run / grid steps);
 * called with ``interpret=None`` the public entry points ask
   ``common.kernel_impl``: the kernels, the kernels inside a ``shard_map``
   over the mesh's batch and head axes (``_over_mesh``), or
@@ -55,6 +62,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -142,37 +150,84 @@ def _row_map(b, h, i, j):
     return (b, h, i, 0)
 
 
-def _inner_map(causal, block_q, block_k, n_inner, inner_is_k, window=None):
+def _band_ends(i, block_q, block_k, n_inner, inner_is_k, window, xp=jnp):
+    """First and last inner tile that the band ``0 <= q - k < window`` of
+    outer tile ``i`` touches, both included.  With k inside: from the tile
+    that holds the first row's oldest key to the one that holds the last
+    row's own position.  With q inside: from the tile that holds the first
+    column's own position to the one that holds the last query that still
+    sees the last column, or the sequence's last.  ``xp`` is ``numpy`` for
+    every outer tile at once at trace time, ``jnp`` inside an index map or
+    a kernel."""
+    if inner_is_k:
+        return (xp.maximum(i * block_q - window + 1, 0) // block_k,
+                (i * block_q + block_q - 1) // block_k)
+    return ((i * block_k) // block_q,
+            xp.minimum((i * block_k + block_k + window - 2) // block_q,
+                       n_inner - 1))
+
+
+def _inner_axis(block_q, block_k, n_outer, n_inner, inner_is_k, window):
+    """``(steps, first)`` of the grid's inner (sequential) axis.  With no
+    window the axis is as long as the sequence and step ``j`` is tile ``j``:
+    ``first`` is None.  With one it is as long as the longest run of inner
+    tiles that any outer tile's band touches (a Python integer, never more
+    than ``n_inner``: 5 of 16 at T = 16,384, W = 4,096, tiles of 1,024), and
+    ``first(i)`` is the inner tile of outer tile ``i``'s step 0.  With k
+    inside the run starts at the band's far edge, and an outer tile whose
+    run is shorter (the sequence's start cuts its band) ends above the
+    diagonal; with q inside it ends at the band's far edge or the sequence's
+    last tile, and a shorter run starts above the diagonal (at a tile
+    index that may be negative): either way ``_visit`` skips those steps by
+    the conditions it has."""
+    if window is None:
+        return n_inner, None
+    lo, hi = _band_ends(np.arange(n_outer), block_q, block_k, n_inner,
+                        inner_is_k, window, np)
+    steps = int((hi - lo + 1).max())
+
+    def first(i):
+        lo, hi = _band_ends(i, block_q, block_k, n_inner, inner_is_k, window)
+        return lo if inner_is_k else hi - (steps - 1)
+    return steps, first
+
+
+def _inner_tile(first):
+    """``(outer tile, inner tile, inner step, inner steps)`` of a kernel's
+    grid step; tile and step are one where the call has no window."""
+    i, j, n = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    return i, (j if first is None else first(i) + j), j, n
+
+
+def _inner_map(causal, block_q, block_k, n_inner, inner_is_k, window=None,
+               first=None):
     """Block index of an operand tiled along the grid's inner (reduction)
     axis.  Under ``causal`` a step that ``_visit`` skips keeps the index of
     the nearest tile that is needed, so the pipeline sees an unchanged block
     and issues no DMA: with k inside, the last k-tile the q-tile needs (the
     one holding its last row); with q inside, the first q-tile the k-tile
     needs (the one holding its first column; a k-tile past every query
-    needs none and keeps the last).  With ``window`` the other end is held
-    too: with k inside, the first k-tile the q-tile needs (the one holding
-    its first row's oldest key); with q inside, the last q-tile the k-tile
-    needs (the one holding the last query that still sees its last
-    column)."""
+    needs none and keeps the last).  With ``window`` step ``j`` is the tile
+    ``first(i) + j`` (``_inner_axis``), held between the two ends of the
+    outer tile's band (``_band_ends``)."""
     def index_map(b, h, i, j):
-        if causal and inner_is_k:
+        if window is not None:
+            lo, hi = _band_ends(i, block_q, block_k, n_inner, inner_is_k,
+                                window)
+            j = jnp.minimum(jnp.maximum(first(i) + j, lo), hi)
+        elif causal and inner_is_k:
             j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
-            if window is not None:
-                j = jnp.maximum(
-                    j, jnp.maximum(i * block_q - window + 1, 0) // block_k)
         elif causal:
             j = jnp.maximum(j, jnp.minimum((i * block_k) // block_q,
                                            n_inner - 1))
-            if window is not None:
-                j = jnp.minimum(
-                    j, (i * block_k + block_k + window - 2) // block_q)
         return (b, h, j, 0)
     return index_map
 
 
 def _note_tiles(kernel, block_q, block_k, nq, nk, causal, window=None):
-    """Trace-time telemetry: which tile each kernel was built with, and the
-    share of the grid's tiles the last traced call visits."""
+    """Trace-time telemetry: which tile each kernel was built with, how long
+    its inner grid axis is, and the share of the grid's tiles (and of its
+    steps) the last traced call visits."""
     from ... import telemetry as _telemetry
     reg = _telemetry.registry()
     reg.counter("pallas.flash.tile.%s.%dx%d"
@@ -184,10 +239,17 @@ def _note_tiles(kernel, block_q, block_k, nq, nk, causal, window=None):
     reg.gauge("pallas.flash.causal_tiles_run_share").set(run / (nq * nk))
     if window is not None:
         reg.counter("pallas.flash.window.%s.%d" % (kernel, window)).inc()
-        band = sum(ki * block_k <= qi * block_q + block_q - 1
-                   and ki * block_k + block_k - 1 + window > qi * block_q
-                   for qi in range(nq) for ki in range(nk))
-        reg.gauge("pallas.flash.band_tiles_run_share").set(band / (nq * nk))
+        run = sum(ki * block_k <= qi * block_q + block_q - 1
+                  and ki * block_k + block_k - 1 + window > qi * block_q
+                  for qi in range(nq) for ki in range(nk))
+        reg.gauge("pallas.flash.band_tiles_run_share").set(run / (nq * nk))
+    n_outer, n_inner = (nk, nq) if kernel == "dkv" else (nq, nk)
+    steps, _ = _inner_axis(block_q, block_k, n_outer, n_inner,
+                           kernel != "dkv", window)
+    reg.counter("pallas.flash.inner_steps.%s.%dof%d"
+                % (kernel, steps, n_inner)).inc()
+    reg.gauge("pallas.flash.grid_steps_run_share").set(
+        run / (n_outer * steps))
 
 
 def _visit(step, q0, k0, block_q, block_k, causal, kv_len, Tk, window=None):
@@ -247,12 +309,10 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                scale, causal, block_q, block_k, kv_len, Tk, window):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+                scale, causal, block_q, block_k, kv_len, Tk, window, first):
+    qi, ki, j, n = _inner_tile(first)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _():
         m_ref[:] = jnp.full_like(m_ref, _NEG)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -278,7 +338,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     _visit(step, qi * block_q, ki * block_k, block_q, block_k, causal,
            kv_len, Tk, window)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(j == n - 1)
     def _():
         l = l_ref[:, :1]
         # fully-masked rows (padding) have l == 0; emit 0 not nan
@@ -298,16 +358,18 @@ def _fwd(q, k, v, causal, scale, tiles, kv_len, interpret, window=None):
     block_q, block_k = tiles[0]
     nq, nk = Tq // block_q, Tk // block_k
     _note_tiles("fwd", block_q, block_k, nq, nk, causal, window)
+    steps, first = _inner_axis(block_q, block_k, nq, nk, True, window)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               kv_len=kv_len, Tk=Tk, window=window)
+                               kv_len=kv_len, Tk=Tk, window=window,
+                               first=first)
 
     q_map = _row_map
-    k_map = _inner_map(causal, block_q, block_k, nk, True, window)
+    k_map = _inner_map(causal, block_q, block_k, nk, True, window, first)
     call = pl.pallas_call(
         kernel,
         name=_name("flash_fwd", window),
-        grid=(B, H, nq, nk),
+        grid=(B, H, nq, steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), q_map),
             pl.BlockSpec((1, 1, block_k, D), k_map),
@@ -343,12 +405,11 @@ def _fwd(q, k, v, causal, scale, tiles, kv_len, interpret, window=None):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, scale, causal, block_q, block_k, kv_len, Tk, window):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+               acc_ref, *, scale, causal, block_q, block_k, kv_len, Tk, window,
+               first):
+    qi, ki, j, n = _inner_tile(first)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
@@ -368,19 +429,17 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     _visit(step, qi * block_q, ki * block_k, block_q, block_k, causal,
            kv_len, Tk, window)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(j == n - 1)
     def _():
         dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *,
-                scale, causal, block_q, block_k, kv_len, Tk, window):
-    ki = pl.program_id(2)
-    qi = pl.program_id(3)
-    nq = pl.num_programs(3)
+                scale, causal, block_q, block_k, kv_len, Tk, window, first):
+    ki, qi, j, n = _inner_tile(first)
 
-    @pl.when(qi == 0)
+    @pl.when(j == 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -405,7 +464,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _visit(step, qi * block_q, ki * block_k, block_q, block_k, causal,
            kv_len, Tk, window)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(j == n - 1)
     def _():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -428,9 +487,10 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
     block_q, block_k = tiles[1]
     nq, nk = Tq // block_q, Tk // block_k
     _note_tiles("dq", block_q, block_k, nq, nk, causal, window)
+    steps, first = _inner_axis(block_q, block_k, nq, nk, True, window)
 
     q_map = _row_map
-    k_map = _inner_map(causal, block_q, block_k, nk, True, window)
+    k_map = _inner_map(causal, block_q, block_k, nk, True, window, first)
     qspec = pl.BlockSpec((1, 1, block_q, D), q_map)
     kspec = pl.BlockSpec((1, 1, block_k, D), k_map)
     vspec = pl.BlockSpec((1, 1, block_k, Dv), k_map)
@@ -439,9 +499,9 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
     dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, kv_len=kv_len,
-                          Tk=Tk, window=window),
+                          Tk=Tk, window=window, first=first),
         name=_name("flash_dq", window),
-        grid=(B, H, nq, nk),
+        grid=(B, H, nq, steps),
         in_specs=[qspec, kspec, vspec, dospec, rowq, rowq],
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype)],
@@ -460,8 +520,9 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
     block_q, block_k = tiles[2]
     nq, nk = Tq // block_q, Tk // block_k
     _note_tiles("dkv", block_q, block_k, nq, nk, causal, window)
+    steps, first = _inner_axis(block_q, block_k, nk, nq, False, window)
 
-    q_map2 = _inner_map(causal, block_q, block_k, nq, False, window)
+    q_map2 = _inner_map(causal, block_q, block_k, nq, False, window, first)
     k_map2 = _row_map
     qspec2 = pl.BlockSpec((1, 1, block_q, D), q_map2)
     kspec2 = pl.BlockSpec((1, 1, block_k, D), k_map2)
@@ -471,9 +532,9 @@ def _bwd(q, k, v, o, lse, do, causal, scale, tiles, kv_len, interpret,
     dkv_call = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, kv_len=kv_len,
-                          Tk=Tk, window=window),
+                          Tk=Tk, window=window, first=first),
         name=_name("flash_dkv", window),
-        grid=(B, H, nk, nq),
+        grid=(B, H, nk, steps),
         in_specs=[qspec2, kspec2, vspec2, dospec2, rowq2, rowq2],
         out_specs=[kspec2, vspec2],
         out_shape=[jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
@@ -584,6 +645,9 @@ def _attend(q, k, v, causal, scale, block_q, block_k, interpret, per_device,
     # every kernel's tile divides the largest (rungs of one ladder)
     pq = _round_up(T, max(bq for bq, _ in tiles)) - T
     pk = _round_up(Tk, max(bk for _, bk in tiles)) - Tk
+    if window is not None:
+        # one padded length: a band's run of tiles ends inside the sequence
+        pq = pk = max(pq, pk)
 
     def heads_first(x, pad):                           # -> [B, H, T + pad, D]
         x = x.transpose(0, 2, 1, 3)

@@ -3,6 +3,8 @@ interpreter against an explicitly masked dense attention (query ``t`` sees
 the keys ``0 <= t - j < W``), output and the three gradients; the lax forms
 behind ``kernel_impl``; the telemetry of the band; and what a window that
 reaches over the whole sequence compiles to."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,8 +12,12 @@ import pytest
 
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops.pallas import flash_attention
-from mxnet_tpu.ops.pallas.flash_attention import _inner_map, _note_tiles
+from mxnet_tpu.ops.pallas.flash_attention import (_inner_axis, _inner_map,
+                                                  _note_tiles)
 from mxnet_tpu.parallel.ring_attention import blockwise_attention
+
+# the package's attribute of that name is the function
+fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
 
 
 def _rand(key, shape, dtype=jnp.float32):
@@ -44,10 +50,10 @@ def both(T, window, bq, bk, fn):
 # (T, W, block_q, block_k): a window under a tile; between tiles (not a
 # multiple of either side); a tile wide; T not a multiple of the tile (the
 # last tile holds padded keys *and* the band's edge); unequal sides both
-# ways; one position
+# ways; one position; unequal sides that pad T to different lengths
 CASES = [(128, 5, 32, 32), (128, 40, 32, 32), (128, 32, 32, 32),
          (100, 40, 32, 32), (128, 48, 32, 16), (128, 48, 16, 32),
-         (128, 1, 32, 32), (100, 70, 32, 32)]
+         (128, 1, 32, 32), (100, 70, 32, 32), (100, 48, 32, 16)]
 
 
 @pytest.mark.parametrize("T,W,bq,bk", CASES)
@@ -102,40 +108,102 @@ def test_no_window_traces_the_kernels_it_traced_before():
         assert name in with_w
 
 
-@pytest.mark.parametrize("T,W,bq,bk", [(16384, 4096, 1024, 1024),
-                                        (16384, 4096, 512, 1024),
-                                        (128, 40, 32, 32), (128, 48, 16, 32),
-                                        (128, 1, 32, 32)])
-def test_a_tile_outside_the_band_is_neither_fetched_nor_run(T, W, bq, bk):
-    """The index maps and ``_visit``'s condition by brute force over the
-    grid: a step that touches the band fetches its own tile, a step that
-    does not keeps the index of one that does (no DMA), both ways round."""
+def _touches(qi, ki, W, bq, bk):
+    """``_visit``'s two conditions: some pair of the tile is in the band."""
+    return ki * bk <= qi * bq + bq - 1 and ki * bk + bk - 1 + W > qi * bq
+
+
+def _runs(T, W, bq, bk, inner_is_k):
+    """By brute force: the inner tiles each outer tile's band touches."""
     nq, nk = T // bq, T // bk
+    if inner_is_k:
+        return [[ki for ki in range(nk) if _touches(qi, ki, W, bq, bk)]
+                for qi in range(nq)]
+    return [[qi for qi in range(nq) if _touches(qi, ki, W, bq, bk)]
+            for ki in range(nk)]
 
-    def touches(qi, ki):                       # any pair of the tile in band
-        return (ki * bk <= qi * bq + bq - 1
-                and ki * bk + bk - 1 + W > qi * bq)
 
-    k_map = _inner_map(True, bq, bk, nk, True, W)
-    q_map = _inner_map(True, bq, bk, nq, False, W)
-    for qi in range(nq):
-        run = [ki for ki in range(nk) if touches(qi, ki)]
-        assert run == list(range(run[0], run[-1] + 1))
-        for ki in range(nk):
-            j = int(k_map(0, 0, qi, ki)[2])
-            assert j == (ki if ki in run else
-                         run[0] if ki < run[0] else run[-1])
-    for ki in range(nk):
-        run = [qi for qi in range(nq) if touches(qi, ki)]
-        for qi in range(nq):
-            j = int(q_map(0, 0, ki, qi)[2])
-            assert j == (qi if qi in run else
-                         run[0] if qi < run[0] else run[-1])
+LONG = [(16384, 4096, 1024, 1024), (16384, 4096, 512, 1024)]
+
+
+def _padded(T, bq, bk):
+    """``T`` as ``_attend`` pads it: to whole tiles of the larger side."""
+    return -(-T // max(bq, bk)) * max(bq, bk)
+
+
+@pytest.mark.parametrize("T,W,bq,bk", LONG + [(128, 40, 32, 32),
+                                              (128, 48, 16, 32),
+                                              (128, 1, 32, 32)])
+def test_a_tile_outside_the_band_is_neither_fetched_nor_run(T, W, bq, bk):
+    """The index maps, the kernels' tile of a step and ``_visit``'s condition
+    by brute force over the short grid: every tile that touches the band is
+    visited exactly once and in rising order, every other step runs nothing
+    and keeps the index of a tile that does (no DMA), both ways round."""
+    nq, nk = T // bq, T // bk
+    for inner_is_k, n_outer, n_inner in ((True, nq, nk), (False, nk, nq)):
+        steps, first = _inner_axis(bq, bk, n_outer, n_inner, inner_is_k, W)
+        index_map = _inner_map(True, bq, bk, n_inner, inner_is_k, W, first)
+        for i, run in enumerate(_runs(T, W, bq, bk, inner_is_k)):
+            assert run == list(range(run[0], run[-1] + 1))
+            tiles = [int(first(i)) + j for j in range(steps)]
+            visited = [t for t in tiles if
+                       (_touches(i, t, W, bq, bk) if inner_is_k
+                        else _touches(t, i, W, bq, bk))]
+            assert visited == run
+            for j, t in enumerate(tiles):
+                fetched = int(index_map(0, 0, i, j)[2])
+                assert fetched == (t if t in run else
+                                   run[0] if t < run[0] else run[-1])
     # the pairs of the band all lie in tiles that run
     if T <= 128:
         gap = np.arange(T)[:, None] - np.arange(T)[None, :]
         for t, j in zip(*np.nonzero((gap >= 0) & (gap < W))):
-            assert touches(t // bq, j // bk)
+            assert _touches(t // bq, j // bk, W, bq, bk)
+
+
+@pytest.mark.parametrize("T,W,bq,bk", CASES + LONG)
+def test_the_inner_axis_is_as_long_as_the_longest_run(T, W, bq, bk):
+    T = _padded(T, bq, bk)
+    nq, nk = T // bq, T // bk
+    for inner_is_k, n_outer, n_inner in ((True, nq, nk), (False, nk, nq)):
+        steps, _ = _inner_axis(bq, bk, n_outer, n_inner, inner_is_k, W)
+        assert steps == max(map(len, _runs(T, W, bq, bk, inner_is_k)))
+        assert steps <= n_inner
+        for whole in (T, T + 1, 4 * T):        # the whole causal prefix
+            assert _inner_axis(bq, bk, n_outer, n_inner, inner_is_k,
+                               whole)[0] == n_inner
+        assert _inner_axis(bq, bk, n_outer, n_inner, inner_is_k,
+                           None) == (n_inner, None)
+    if (T, W, bq, bk) == LONG[0]:
+        assert steps == 5
+
+
+@pytest.mark.parametrize("T,W,bq,bk", CASES)
+def test_the_short_axis_sums_what_the_long_axis_summed(T, W, bq, bk,
+                                                       monkeypatch):
+    """Output and the three gradients to the last bit against the schedule
+    before: an inner axis as long as the sequence, step ``j`` tile ``j``,
+    the index held between the band's ends — the same pairs enter the same
+    sums in the same order."""
+    def fn(q, k, v):
+        return flash_attention(q, k, v, window=W, block_q=bq, block_k=bk,
+                               interpret=True)
+
+    got, _ = both(T, W, bq, bk, fn)
+
+    def long_axis(block_q, block_k, n_outer, n_inner, inner_is_k, window):
+        return n_inner, lambda i: 0
+
+    grids = []
+    real_call = fa.pl.pallas_call
+    monkeypatch.setattr(fa, "_inner_axis", long_axis)
+    monkeypatch.setattr(fa.pl, "pallas_call", lambda *a, **kw: (
+        grids.append(kw["grid"]), real_call(*a, **kw))[1])
+    want, _ = both(T, W, bq, bk, fn)
+    Tp = _padded(T, bq, bk)
+    assert {g[2] * g[3] for g in grids} == {(Tp // bq) * (Tp // bk)}
+    for a, b, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
 
 
 def test_the_band_is_counted_beside_the_causal_share():
@@ -153,6 +221,29 @@ def test_the_band_is_counted_beside_the_causal_share():
     _note_tiles("fwd", 1024, 1024, 16, 16, True)
     assert reg.snapshot()["counters"]["pallas.flash.window.fwd.4096"] == \
         before + 1
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_the_inner_axis_is_counted_and_the_grid_steps_that_run(kernel):
+    reg = telemetry.registry()
+
+    def count(name):
+        return reg.snapshot()["counters"].get(
+            "pallas.flash.inner_steps.%s.%s" % (kernel, name), 0)
+
+    short, whole = count("5of16"), count("16of16")
+    _note_tiles(kernel, 1024, 1024, 16, 16, True, 4096)
+    assert (count("5of16"), count("16of16")) == (short + 1, whole)
+    # 16 outer tiles of 5 steps; 1 + 2 + 3 + 4 + 12 x 5 = 70 of the 80 run
+    assert reg.snapshot()["gauges"]["pallas.flash.grid_steps_run_share"] \
+        == pytest.approx(0.875)
+    _note_tiles(kernel, 1024, 1024, 16, 16, True)
+    assert (count("5of16"), count("16of16")) == (short + 1, whole + 1)
+    assert reg.snapshot()["gauges"]["pallas.flash.grid_steps_run_share"] \
+        == pytest.approx(136 / 256)
+    # the shares of the nq x nk tiles keep their meaning
+    assert reg.snapshot()["gauges"]["pallas.flash.band_tiles_run_share"] \
+        == pytest.approx(70 / 256)
 
 
 def test_a_window_is_over_a_causal_self_attention():

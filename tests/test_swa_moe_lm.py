@@ -100,7 +100,8 @@ def test_loss_and_every_gradient_leaf_are_the_references(mode, monkeypatch):
         np.testing.assert_allclose(g_got[name], g_want[name], rtol=2e-4,
                                    atol=2e-5 * scale, err_msg=name)
     moved = {k: v - before.get(k, 0)
-             for k, v in counters("pallas.flash.window.").items()}
+             for k, v in counters("pallas.flash.window.").items()
+             if v != before.get(k, 0)}      # other files' windows stand still
     if mode == "kernels":
         # one run of three window layers, traced once (the forward once
         # more as the rule's primal)
@@ -108,7 +109,7 @@ def test_loss_and_every_gradient_leaf_are_the_references(mode, monkeypatch):
                          "pallas.flash.window.dq.16": 1,
                          "pallas.flash.window.dkv.16": 1}
     else:
-        assert not any(moved.values())
+        assert not moved
 
 
 def test_three_train_steps_are_the_references(monkeypatch):
