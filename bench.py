@@ -1972,14 +1972,15 @@ def transformer_bench(batch=8, seq=1024, steps=10, quick=False):
 def _kernel_breakdown(step, state, data, steps=3):
     """Per-HLO-category device ms/step from a short jax.profiler trace
     (VERDICT r2 next #6 'publish a per-kernel breakdown in BENCH
-    extras').  State threads through the loop, as in the timed loops."""
+    extras'): ``profiler.device_time_by_scope``'s rows summed by their
+    category.  State threads through the loop, as in the timed loops."""
     import shutil
     import tempfile
 
     import jax
     import numpy as np
 
-    from mxnet_tpu.profiler import hlo_category_breakdown
+    from mxnet_tpu.profiler import device_time_by_scope
 
     outdir = tempfile.mkdtemp(prefix="benchprof")
     try:
@@ -1988,13 +1989,15 @@ def _kernel_breakdown(step, state, data, steps=3):
             for _ in range(steps):
                 params, velocity, loss = step(params, velocity, *data)
             float(np.asarray(loss))
-        cats = hlo_category_breakdown(outdir, steps=steps)
+        rows = device_time_by_scope(outdir, steps=steps)["rows"]
     finally:
         shutil.rmtree(outdir, ignore_errors=True)
-    return {cat: round(d["ms_per_step"], 3)
-            for cat, d in sorted(cats.items(),
-                                 key=lambda kv: -kv[1]["ms_per_step"])
-            if d["ms_per_step"] >= 0.01}
+    cats = {}
+    for _leaf, _pass, ms, _share, _calls, category in rows:
+        cats[category] = cats.get(category, 0.0) + ms
+    return {cat: round(ms, 3)
+            for cat, ms in sorted(cats.items(), key=lambda kv: -kv[1])
+            if ms >= 0.01}
 
 
 def _exit_code():

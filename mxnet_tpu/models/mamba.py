@@ -106,19 +106,23 @@ def mamba_mixer(bp, h, cfg):
         return jnp.einsum("btf,fg->btg", x, w,
                           preferred_element_type=jnp.float32)
 
-    u, z = jnp.split(checkpoint_name(proj(h, bp["in_proj"]).astype(dt),
-                                     IN_PROJ_NAME), 2, axis=-1)
+    with jax.named_scope("ssm.in_proj"):
+        u, z = jnp.split(checkpoint_name(proj(h, bp["in_proj"]).astype(dt),
+                                         IN_PROJ_NAME), 2, axis=-1)
     with jax.named_scope("conv"):
         u = jax.nn.silu(_causal_conv(u, bp["conv_w"], bp["conv_b"])
                         ).astype(dt)
-    d, B, C = jnp.split(proj(u, bp["x_proj"]).astype(dt), [R, R + N],
-                        axis=-1)
-    d = rms(d, bp["dt_norm_scale"], 1e-6)
-    B = rms(B, bp["b_norm_scale"], 1e-6)
-    C = rms(C, bp["c_norm_scale"], 1e-6)
-    delta = jax.nn.softplus(proj(d, bp["dt_proj"])
-                            + bp["dt_bias"].astype(jnp.float32))
-    A = -jnp.exp(bp["A_log"].astype(jnp.float32))
+    with jax.named_scope("ssm.x_proj"):
+        d, B, C = jnp.split(proj(u, bp["x_proj"]).astype(dt), [R, R + N],
+                            axis=-1)
+    with jax.named_scope("ssm.dt"):
+        d = rms(d, bp["dt_norm_scale"], 1e-6)
+        B = rms(B, bp["b_norm_scale"], 1e-6)
+        C = rms(C, bp["c_norm_scale"], 1e-6)
+        delta = jax.nn.softplus(proj(d, bp["dt_proj"])
+                                + bp["dt_bias"].astype(jnp.float32))
+        A = -jnp.exp(bp["A_log"].astype(jnp.float32))
     with jax.named_scope("scan"):
         y = selective_scan(u, delta, A, B, C, bp["D"], z)
-    return proj(y, bp["out_proj"]).astype(dt)
+    with jax.named_scope("ssm.out_proj"):
+        return proj(y, bp["out_proj"]).astype(dt)

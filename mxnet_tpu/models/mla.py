@@ -73,7 +73,8 @@ def mla_mixer(bp, h, cfg, attend):
             proj(h, bp["wq"]).reshape(B, T, H, dn + dr), [dn], axis=-1)
     with jax.named_scope("mla.kv_a"):
         c, k_pe = jnp.split(proj(h, bp["wkv_a"]), [r], axis=-1)
-        c = fused_rmsnorm(c, bp["kv_norm_scale"].astype(c.dtype))
+        with jax.named_scope("norm"):
+            c = fused_rmsnorm(c, bp["kv_norm_scale"].astype(c.dtype))
     with jax.named_scope("mla.kv_b"):
         k_nope, v = jnp.split(
             proj(c, bp["wkv_b"]).reshape(B, T, H, dn + dv), [dn], axis=-1)
@@ -84,7 +85,9 @@ def mla_mixer(bp, h, cfg, attend):
         q = jnp.concatenate([q_nope, q_pe], axis=-1)
         # the one rotary key head, broadcast to the query heads (the
         # backward sums its gradient over them by autodiff)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_pe, (B, T, H, dr))], axis=-1)
+        with jax.named_scope("attn.kv_broadcast"):
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_pe, (B, T, H, dr))], axis=-1)
     o = attend(q, k, v, softmax_scale(cfg))
-    return proj(o.reshape(B, T, H * dv), bp["wo"])
+    with jax.named_scope("attn.out"):
+        return proj(o.reshape(B, T, H * dv), bp["wo"])

@@ -415,10 +415,12 @@ class TransformerLM:
         with jax.named_scope(scope):
             if self.cfg.moe_router_pre_attention and "gate" in bp:
                 routing = self._route(bp, x)
-            h = self._rmsnorm(x, bp["ln1_scale"])
+            with jax.named_scope("norm"):
+                h = self._rmsnorm(x, bp["ln1_scale"])
             o, state = mixer(bp, h)
-            x = x + constraint(checkpoint_name(o, MIXER_OUT),
-                               "dp", "sp", None)
+            with jax.named_scope("residual"):
+                x = x + constraint(checkpoint_name(o, MIXER_OUT),
+                                   "dp", "sp", None)
         with jax.named_scope("mlp"):
             x, aux = self._mlp_half(bp, x, routing)
         return x, aux, state
@@ -445,9 +447,11 @@ class TransformerLM:
         cfg = self.cfg
         B, T, _ = h.shape
         H, D, KV = cfg.n_heads, cfg.head_dim, cfg.kv_heads
-        qkv = jnp.einsum("bte,ef->btf", h, bp["wqkv"],
-                         preferred_element_type=jnp.float32).astype(h.dtype)
-        qkv = constraint(qkv, "dp", "sp", "tp")
+        with jax.named_scope("attn.qkv"):
+            qkv = jnp.einsum("bte,ef->btf", h, bp["wqkv"],
+                             preferred_element_type=jnp.float32
+                             ).astype(h.dtype)
+            qkv = constraint(qkv, "dp", "sp", "tp")
 
         tables = rope_tables(cfg, D, T) if rope else None
 
@@ -459,15 +463,17 @@ class TransformerLM:
             x = x.transpose(0, 2, 1, 3)
             return checkpoint_name(x, QKV_NAME).transpose(0, 2, 1, 3)
 
-        q, k, v = jnp.split(qkv, [H * D, (H + KV) * D], axis=-1)
-        return heads(q, H, rope), heads(k, KV, rope), heads(v, KV)
+        with jax.named_scope("attn.qkv"):
+            q, k, v = jnp.split(qkv, [H * D, (H + KV) * D], axis=-1)
+            return heads(q, H, rope), heads(k, KV, rope), heads(v, KV)
 
     def _attn_out(self, bp, attn):
         """The output projection of ``attn`` [B, T, H, D]."""
         B, T = attn.shape[:2]
-        return jnp.einsum("btf,fe->bte", attn.reshape(B, T, -1), bp["wo"],
-                          preferred_element_type=jnp.float32
-                          ).astype(attn.dtype)
+        with jax.named_scope("attn.out"):
+            return jnp.einsum("btf,fe->bte", attn.reshape(B, T, -1),
+                              bp["wo"], preferred_element_type=jnp.float32
+                              ).astype(attn.dtype)
 
     def _self_attention(self, bp, h, use_ring=False, window=0, rope=False):
         """The mixer of training and prefill: causal self-attention over
@@ -486,8 +492,9 @@ class TransformerLM:
             # three flash kernels stay as the equal-head models run them,
             # at the cost of K and V read once a query head, which the
             # kernels' grid does anyway.
-            kh = jnp.repeat(k, H // KV, axis=2)
-            vh = jnp.repeat(v, H // KV, axis=2)
+            with jax.named_scope("attn.kv_broadcast"):
+                kh = jnp.repeat(k, H // KV, axis=2)
+                vh = jnp.repeat(v, H // KV, axis=2)
         return self._attn_out(bp, self._attend(q, kh, vh, None, use_ring,
                                                window or None)), (k, v)
 
@@ -496,16 +503,17 @@ class TransformerLM:
         the implementation the shape and the mesh call for; ``window``:
         over the keys ``0 <= t - j < window`` alone."""
         B, T, H, _ = q.shape
-        if use_ring:
-            assert window is None, \
-                "ring attention over sp with a window: not built (a " \
-                "shard would pass on only the blocks its band reaches)"
-            return ring_self_attention(q, k, v, causal=True)
-        if B * H * T * T * 4 / 1e6 <= self.cfg.dense_attn_max_score_mb:
-            return _dense_self_attention(q, k, v, causal=True, scale=scale,
-                                         window=window)
-        return flash_attention(q, k, v, causal=True, scale=scale,
-                               window=window)
+        with jax.named_scope("attn.core"):
+            if use_ring:
+                assert window is None, \
+                    "ring attention over sp with a window: not built (a " \
+                    "shard would pass on only the blocks its band reaches)"
+                return ring_self_attention(q, k, v, causal=True)
+            if B * H * T * T * 4 / 1e6 <= self.cfg.dense_attn_max_score_mb:
+                return _dense_self_attention(q, k, v, causal=True,
+                                             scale=scale, window=window)
+            return flash_attention(q, k, v, causal=True, scale=scale,
+                                   window=window)
 
     def _ssm(self, bp, h):
         """The mixer of a state-space layer (`models/mamba.py`)."""
@@ -519,17 +527,22 @@ class TransformerLM:
         """``down(gelu(up(h)))``, or with ``w_gate`` ``down(act(gate(h)) *
         up(h))``, ``act`` the gated form's that ``mlp`` names: a dense
         layer's MLP and an expert layer's shared expert."""
-        up = jnp.einsum("bte,ef->btf", h, w_up,
-                        preferred_element_type=jnp.float32)
-        if w_gate is not None:
-            gate = jnp.einsum("bte,ef->btf", h, w_gate,
-                              preferred_element_type=jnp.float32)
-            up = _ACT[self.cfg.mlp](gate) * up
-        else:
-            up = jax.nn.gelu(up)
-        up = constraint(up.astype(h.dtype), "dp", "sp", "tp")
-        return jnp.einsum("btf,fe->bte", up, w_down,
-                          preferred_element_type=jnp.float32).astype(h.dtype)
+        with jax.named_scope("mlp.up"):
+            up = jnp.einsum("bte,ef->btf", h, w_up,
+                            preferred_element_type=jnp.float32)
+            if w_gate is not None:
+                gate = jnp.einsum("bte,ef->btf", h, w_gate,
+                                  preferred_element_type=jnp.float32)
+        with jax.named_scope("mlp.act"):
+            if w_gate is not None:
+                up = _ACT[self.cfg.mlp](gate) * up
+            else:
+                up = jax.nn.gelu(up)
+            up = constraint(up.astype(h.dtype), "dp", "sp", "tp")
+        with jax.named_scope("mlp.down"):
+            return jnp.einsum("btf,fe->bte", up, w_down,
+                              preferred_element_type=jnp.float32
+                              ).astype(h.dtype)
 
     def _experts(self, bp, h, routing=None):
         """An expert layer's MLP on the normed ``h``: the routed experts
@@ -566,14 +579,16 @@ class TransformerLM:
         return ff, jnp.stack([aux, held])
 
     def _mlp_half(self, bp, x, routing=None):
-        h = self._rmsnorm(x, bp["ln2_scale"])
+        with jax.named_scope("norm"):
+            h = self._rmsnorm(x, bp["ln2_scale"])
         if "gate" in bp:                    # the router: an expert layer
             ff, aux = self._experts(bp, h, routing)
         else:
             ff = self._mlp(h, bp["w_up"], bp["w_down"],
                            bp["w_gate"] if "w_gate" in bp else None)
             aux = jnp.float32(0.0)
-        return x + constraint(ff, "dp", "sp", None), aux
+        with jax.named_scope("residual"):
+            return x + constraint(ff, "dp", "sp", None), aux
 
     # -- generative decode (paged KV cache) ----------------------------
     #
@@ -732,8 +747,9 @@ class TransformerLM:
         MLP halves handed back, summed over the layers: 0.0, or in a model
         with expert layers ``[balance term, pairs on held experts]``."""
         cfg = self.cfg
-        x = params["embed"][tokens]
-        x = constraint(x, "dp", "sp", None)
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]
+            x = constraint(x, "dp", "sp", None)
 
         block_names = [k for k in params if k.startswith("blocks.")]
         stacked = {k.split(".", 1)[1]: params[k] for k in block_names}
@@ -790,16 +806,21 @@ class TransformerLM:
                                     ("dense", "dense."),
                                     ("moe", "moe."))}
         for (mixer, setting, mlp), lo, hi, at in cfg.layer_runs():
-            run = {k: v[lo:hi] for k, v in stacked.items()}
-            for kind in (mixer, mlp):
-                klo, khi = at[kind]
-                run.update({k: v[klo:khi] for k, v in own[kind].items()})
             if mixer == "attention" and cfg.attention == "mha":
                 counter("lm.attn.%s.%s.%d" % (
                     "window" if setting[0] else "full",
                     "rope" if setting[1] else "nope", hi - lo)).inc()
-            carry = layers(carry, body_of(mixer, setting), run,
-                           mlp if cfg.mlp_types else mixer)
+            # what is a layer's lies under the layer's own scopes; what is
+            # left here is the run's slice of the stacks and the scan's own
+            # traffic (a layer's leaves in, the kept values out)
+            with jax.named_scope("layers"):
+                run = {k: v[lo:hi] for k, v in stacked.items()}
+                for kind in (mixer, mlp):
+                    klo, khi = at[kind]
+                    run.update({k: v[klo:khi]
+                                for k, v in own[kind].items()})
+                carry = layers(carry, body_of(mixer, setting), run,
+                               mlp if cfg.mlp_types else mixer)
         return carry
 
     def held_slot_share(self, params, tokens):
@@ -817,20 +838,23 @@ class TransformerLM:
         if cfg.has_experts:
             aux = aux[0]
 
-        x = self._rmsnorm(x, params["final_ln_scale"])
-        if cfg.tie_embeddings:
-            logits = jnp.einsum("bte,ve->btv", x, params["embed"],
-                                preferred_element_type=jnp.float32)
-        else:
-            logits = jnp.einsum("bte,ev->btv", x, params["unembed"],
-                                preferred_element_type=jnp.float32)
+        with jax.named_scope("norm"):
+            x = self._rmsnorm(x, params["final_ln_scale"])
+        with jax.named_scope("head"):
+            if cfg.tie_embeddings:
+                logits = jnp.einsum("bte,ve->btv", x, params["embed"],
+                                    preferred_element_type=jnp.float32)
+            else:
+                logits = jnp.einsum("bte,ev->btv", x, params["unembed"],
+                                    preferred_element_type=jnp.float32)
         return logits, aux
 
     def loss(self, params, tokens, targets):
         """Causal LM loss: mean token cross-entropy (+ MoE aux loss)."""
         logits, aux = self.apply(params, tokens)
-        nll = fused_softmax_xent(logits, targets).mean()
-        return nll + self.cfg.moe_aux_weight * aux
+        with jax.named_scope("loss"):
+            nll = fused_softmax_xent(logits, targets).mean()
+            return nll + self.cfg.moe_aux_weight * aux
 
 
 def make_train_step(model: TransformerLM, lr=1e-2, momentum=0.9, rules=None):
@@ -859,9 +883,9 @@ def make_train_step(model: TransformerLM, lr=1e-2, momentum=0.9, rules=None):
                 for k, v in tree.items()}
 
     def step(params, velocity, tokens, targets):
-        with jax.named_scope("loss"):
-            loss, grads = jax.value_and_grad(model.loss)(params, tokens,
-                                                         targets)
+        # no scope round this: every op of the step would lie under it, and
+        # the pass (``jvp`` / ``transpose``) is in each op's path already
+        loss, grads = jax.value_and_grad(model.loss)(params, tokens, targets)
         with jax.named_scope("optimizer"):
             grads = pin(grads)
             new_v = pin(jax.tree_util.tree_map(

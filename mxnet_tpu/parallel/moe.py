@@ -138,28 +138,33 @@ def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
             routing = route(tokens, router_w, k, renormalize, (B, T))
     weights, experts, aux = routing
     with jax.named_scope("moe.dispatch"):
-        # a pair's key is its expert's place in the stack; an absent
-        # expert's pairs sort behind every group and are never computed
-        place = np.full((n,), n_held, np.int32)
-        place[list(held)] = np.arange(n_held, dtype=np.int32)
-        key = jnp.asarray(place)[experts.reshape(P)]
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inverse = jnp.argsort(order).astype(jnp.int32)
-        sizes = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=jnp.int32),
-                        axis=0, dtype=jnp.int32)
-        rows = _to_sorted(tokens, order, inverse, k)
+        with jax.named_scope("moe.dispatch.sort"):
+            # a pair's key is its expert's place in the stack; an absent
+            # expert's pairs sort behind every group and are never computed
+            place = np.full((n,), n_held, np.int32)
+            place[list(held)] = np.arange(n_held, dtype=np.int32)
+            key = jnp.asarray(place)[experts.reshape(P)]
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            inverse = jnp.argsort(order).astype(jnp.int32)
+            sizes = jnp.sum(
+                key[:, None] == jnp.arange(n_held, dtype=jnp.int32),
+                axis=0, dtype=jnp.int32)
+        with jax.named_scope("moe.dispatch.gather"):
+            rows = _to_sorted(tokens, order, inverse, k)
     with jax.named_scope("moe.experts"):
         up = grouped_matmul(rows, w_up, sizes).astype(jnp.float32)
         if w_gate is not None:
             gate = grouped_matmul(rows, w_gate, sizes).astype(jnp.float32)
-            up = act(gate) * up
-        else:
-            up = act(up)
-        out = grouped_matmul(up.astype(x.dtype), w_down, sizes)
+        with jax.named_scope("moe.experts.act"):
+            up = (act(up) if w_gate is None else act(gate) * up
+                  ).astype(x.dtype)
+        out = grouped_matmul(up, w_down, sizes)
     with jax.named_scope("moe.combine"):
         # rows past the groups are zero, so an absent expert's slot adds 0
-        slots = _from_sorted(out, order, inverse).reshape(S, k, E)
-        y = jnp.einsum("ske,sk->se", slots.astype(jnp.float32), weights)
+        with jax.named_scope("moe.combine.gather"):
+            slots = _from_sorted(out, order, inverse).reshape(S, k, E)
+        with jax.named_scope("moe.combine.sum"):
+            y = jnp.einsum("ske,sk->se", slots.astype(jnp.float32), weights)
     return (y.astype(x.dtype).reshape(B, T, E), aux,
             jnp.sum(sizes).astype(jnp.float32))
 

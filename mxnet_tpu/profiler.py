@@ -24,16 +24,19 @@ device scheduling.  Two layers instead:
   ``jax.profiler.TraceAnnotation`` so that under any ``jax.profiler`` trace
   the same interval lies beside the device's operations.
 * **Device traces** — ``set_config(tensorboard_dir=...)`` brackets the run
-  with ``jax.profiler.start_trace/stop_trace`` (XLA's own profiler:
-  per-HLO timing, HBM usage — the TPU analogue of the reference's kernel
-  spans), and every op dispatch carries a ``jax.profiler.TraceAnnotation``
-  so op names appear on the device timeline.
+  with ``jax.profiler.start_trace/stop_trace``; :func:`device_time_by_scope`
+  sums that trace by the ``jax.named_scope`` each operation was traced under
+  (:data:`DEVICE_SCOPES`) and by pass (``fwd`` / ``bwd`` / ``remat``), and
+  :func:`dumps` prints it under the host's table.
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import json
 import os
+import re
+import sys
 import threading
 import time
 
@@ -42,7 +45,8 @@ __all__ = ["set_config", "set_state", "start", "stop", "pause", "resume",
            "Counter", "Frame", "Marker", "dispatch_count", "dispatch_stats",
            "dispatch_value", "record_span", "record_event", "now_us",
            "set_max_events", "recent_events", "span", "spans",
-           "record_interval", "spans_lost_before", "SPANS", "SPAN_RING"]
+           "record_interval", "spans_lost_before", "SPANS", "SPAN_RING",
+           "DEVICE_SCOPES", "device_time_by_scope", "device_table", "live_hlo"]
 
 _lock = threading.Lock()
 _config = {
@@ -502,7 +506,12 @@ def _maybe_start_device_trace():
     tb = _config.get("tensorboard_dir")
     if tb:
         import jax
-        jax.profiler.start_trace(tb)
+        # as the benchmark traces: the host's TraceAnnotations (the layer
+        # spans) and no Python tracer, which would slow the host it times
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(tb, profiler_options=opts)
         _device_trace_on = True
 
 
@@ -530,7 +539,9 @@ def dump(finished=True, profile_process="worker"):
 
 def dumps(reset=False, format="table"):
     """Aggregate per-op stats table (reference :291 over
-    aggregate_stats.cc).  Requires ``set_config(aggregate_stats=True)``."""
+    aggregate_stats.cc).  Requires ``set_config(aggregate_stats=True)``.
+    Where the session took a device trace (``tensorboard_dir``), the device's
+    table follows the host's: :func:`device_time_by_scope` of that trace."""
     with _lock:
         rows = sorted(_agg.items(), key=lambda kv: -kv[1][1])
         out = ["%-40s %8s %12s %12s %12s %12s" %
@@ -542,6 +553,12 @@ def dumps(reset=False, format="table"):
                         mx / 1e3))
         if reset:
             _agg.clear()
+    tb = _config.get("tensorboard_dir")
+    if tb and _config.get("aggregate_stats") and not _device_trace_on:
+        try:
+            out += ["", device_table(device_time_by_scope(tb))]
+        except (FileNotFoundError, ValueError):
+            pass                    # no trace taken, or one with no device
     return "\n".join(out)
 
 
@@ -648,47 +665,570 @@ class Marker:
 
 
 # ---------------------------------------------------------------------------
-# XLA kernel-level attribution (below the op spans above): parse the
-# chrome trace jax.profiler emits into per-HLO-category device time.
-# Shared by bench.py's published breakdown and tools/profile_train.py.
+# Device time by named scope: where the work happens.  One reducer in the
+# program (the benchmark keeps its own, ``benchmarks/harness/trace_reduce.py``,
+# as the yardstick's copy: nothing here is imported from it or by it).
 # ---------------------------------------------------------------------------
-def device_trace_events(trace_dir):
-    """Device-lane events (with args) from the newest jax.profiler trace
-    under ``trace_dir``."""
+# Every literal the package gives ``jax.named_scope`` (a test greps for
+# them).  A scope is a string in the HLO's metadata and costs nothing at run
+# time; :func:`device_time_by_scope` sums a device trace by the last of these
+# in each operation's path.  The LM train step's (`models/`, `parallel/moe.py`,
+# every ``pallas_call``'s own) first, so that no operation of that step lies
+# outside a leaf; then the Gluon step's and the mesh's.  A collective's scope
+# is ``<what>.<mesh axes>`` (``allreduce.dp``) and this holds ``<what>``.
+DEVICE_SCOPES = (
+    # the LM step, outside the layers
+    "embed", "layers", "norm", "residual", "head", "loss", "optimizer",
+    # the attention half: the container, then its leaves
+    "attn", "attn.qkv", "attn.rope", "attn.kv_broadcast", "attn.core",
+    "attn.out",
+    "mla.q", "mla.kv_a", "mla.kv_b", "mla.rope",
+    "ssm", "ssm.in_proj", "conv", "ssm.x_proj", "ssm.dt", "scan",
+    "ssm.out_proj",
+    # the MLP half
+    "mlp", "mlp.up", "mlp.act", "mlp.down",
+    "moe.route", "moe.dispatch", "moe.dispatch.sort", "moe.dispatch.gather",
+    "moe.experts", "moe.experts.act", "moe.combine", "moe.combine.gather",
+    "moe.combine.sum", "moe.shared",
+    # the kernels (`ops/pallas/`): a call's ``name=`` is its scope
+    "flash_fwd", "flash_dq", "flash_dkv", "rmsnorm_fwd", "xent_fwd",
+    "xent_bwd", "ssm_scan_fwd", "ssm_scan_bwd", "gmm_fwd", "gmm_dx",
+    "gmm_dw", "int8_matmul", "int8_matmul_dequant",
+    # the Gluon step (`gluon/contrib/fused.py`)
+    "forward", "backward", "update",
+    # the mesh (`parallel/`)
+    "ring_kv", "pp_activations", "pp_cotangents", "pp_outputs", "pp_loss",
+    "dp_loss", "dp_grads",
+    "allreduce", "allgather", "reduce_scatter", "ppermute", "all_to_all",
+    "broadcast",
+)
+_SCOPE_SET = frozenset(DEVICE_SCOPES)
+_COLLECTIVE_SCOPES = frozenset(("allreduce", "allgather", "reduce_scatter",
+                                "ppermute", "all_to_all", "broadcast"))
+_DEVICE_PLANE = re.compile(r"^/device:[A-Za-z]+:(\d+)$")
+_OPS_LINE, _MODULES_LINE = "XLA Ops", "XLA Modules"
+UNSCOPED = "unscoped"
+# under this share of a fusion's weighed bytes in one (leaf, pass) the
+# fusion is also counted as mixed
+_AGREE = 0.8
+
+
+def _neutral_trace(profile):
+    """``jax.profiler.ProfileData`` -> ``{"planes": [{"name", "lines":
+    [{"name", "events": [[name, start_ns, dur_ns, {stats}]]}]}]}``: the
+    device planes' op and module lines and the host's ``engine.*`` spans.
+    The same form ``benchmarks/harness/trace_reduce.py`` works on, so one
+    recorded trace can be checked in as JSON for either."""
+    planes = []
+    for plane in profile.planes:
+        is_dev = _DEVICE_PLANE.match(plane.name) is not None
+        lines = []
+        for line in plane.lines:
+            if is_dev and line.name not in (_OPS_LINE, _MODULES_LINE):
+                continue
+            events = []
+            for e in line.events:
+                if not is_dev and not e.name.startswith("engine."):
+                    continue
+                stats = {}
+                if is_dev:
+                    stats = {k: v for k, v in e.stats
+                             if isinstance(v, (str, int, float))}
+                events.append([e.name, float(e.start_ns),
+                               float(e.duration_ns), stats])
+            if events:
+                lines.append({"name": line.name, "events": events})
+        if lines:
+            planes.append({"name": plane.name, "lines": lines})
+    return {"planes": planes}
+
+
+def _load_trace(trace_dir):
+    """The newest trace under ``trace_dir`` in the neutral form: an
+    ``*.xplane.pb`` as ``jax.profiler`` leaves it (``plugins/profile/<time>/``),
+    or a neutral form recorded from one (``*.neutral.json``, gzipped or not:
+    what the tests hold and ``tools/profile_train.py --save`` keeps)."""
     import glob
     import gzip
-    import json as _json
 
-    traces = sorted(glob.glob(
-        os.path.join(trace_dir, "plugins/profile/*/*.trace.json.gz")))
-    if not traces:
-        raise FileNotFoundError("no jax.profiler trace under %s"
-                                % trace_dir)
-    with gzip.open(traces[-1]) as f:
-        tr = _json.load(f)
-    dev_pids = {e["pid"] for e in tr["traceEvents"]
-                if e.get("ph") == "M" and e.get("name") == "process_name"
-                and "device:" in e["args"].get("name", "").lower()}
-    return [e for e in tr["traceEvents"]
-            if e.get("ph") == "X" and e.get("pid") in dev_pids
-            and "args" in e]
+    pbs = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if pbs:
+        from jax.profiler import ProfileData
+
+        return _neutral_trace(ProfileData.from_file(pbs[-1]))
+    recorded = sorted(
+        glob.glob(os.path.join(trace_dir, "*.neutral.json*"))
+        + glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                 "*.neutral.json*")))
+    if not recorded:
+        raise FileNotFoundError("no jax.profiler trace under %s" % trace_dir)
+    opener = gzip.open if recorded[-1].endswith(".gz") else open
+    with opener(recorded[-1], "rt") as f:
+        return json.load(f)
 
 
-def hlo_category_breakdown(trace_dir, steps=1):
-    """{hlo_category: {ms_per_step, kernels, tflops, gb_s}} from a
-    trace capturing ``steps`` executions."""
-    agg = {}
-    for e in device_trace_events(trace_dir):
-        cat = e["args"].get("hlo_category")
-        if not cat:
+# -- the compiled module's text ---------------------------------------------
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+) = (.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+) \(.*\{\s*$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([^\s,)]+)")
+_KIND = re.compile(r"\bkind=k([A-Za-z]+)")
+_BRACES = re.compile(r"\{[^{}]*\}")
+_ARRAY = re.compile(r"\b([a-z]+)(\d*)[a-z0-9]*\[([0-9,]*)\]")
+_MODULE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
+_OPERAND = re.compile(r"%([^\s,()]+)")
+# how far an instruction the compiler made looks for a neighbour's place
+_INHERIT_HOPS = 4
+# no device time of their own, and no say in a fusion's vote
+_NO_VOTE = frozenset(("parameter", "constant", "tuple", "get-tuple-element",
+                      "bitcast", "iota"))
+_PRODUCTS = ("convolution", "dot")
+
+
+def _bytes_of(shapes):
+    total = 0.0
+    for dtype, bits, dims in _ARRAY.findall(shapes):
+        n = 1
+        for d in dims.split(","):
+            if d:
+                n *= int(d)
+        total += n * (int(bits) if bits else 8) / 8.0
+    return total
+
+
+def _group_end(text):
+    """Index of the parenthesis that closes the one ``text`` opens with."""
+    depth = 0
+    for i, ch in enumerate(text):
+        depth += ch == "("
+        depth -= ch == ")"
+        if depth == 0:
+            return i
+    return len(text) - 1
+
+
+def _split_instruction(rest):
+    """``<result shape> opcode(operands), attributes`` -> (result, opcode,
+    operand names)."""
+    while _BRACES.search(rest):
+        rest = _BRACES.sub("", rest)
+    if rest.startswith("("):
+        i = _group_end(rest)
+        result, after = rest[:i + 1], rest[i + 1:].lstrip()
+    else:
+        result, _, after = rest.partition(" ")
+    opcode, paren, operands = after.partition("(")
+    operands = operands[:_group_end(paren + operands)]
+    return result, opcode.strip(), tuple(_OPERAND.findall(operands))
+
+
+class _Module:
+    """What the join needs of one compiled module's text: for every
+    instruction its opcode, its ``op_name``, its operands and, inside fused
+    computations, its result's bytes."""
+
+    def __init__(self, text):
+        m = _MODULE.search(text)
+        self.name = m.group(1) if m else ""
+        # name -> (opcode, op_name, calls, kind, operand names)
+        self.inst = {}
+        # computation -> [(opcode, op_name, result bytes, calls)]
+        self.body = {}
+        self._placed = {}
+        self._users = None
+        comp = None
+        for line in text.splitlines():
+            m = _INSTRUCTION.match(line)
+            if m is None:
+                c = _COMPUTATION.match(line)
+                if c:
+                    comp = c.group(1)
+                    self.body[comp] = []
+                continue
+            name, rest = m.groups()
+            op = _OP_NAME.search(rest)
+            op_name = op.group(1) if op else ""
+            meta = rest.find(", metadata=")
+            head = rest if meta < 0 else rest[:meta]
+            result, opcode, operands = _split_instruction(head)
+            calls = _CALLS.search(head) if opcode == "fusion" else None
+            kind = _KIND.search(head) if opcode == "fusion" else None
+            self.inst[name] = (opcode, op_name,
+                               calls.group(1) if calls else None,
+                               kind.group(1) if kind else None, operands)
+            if comp is not None:
+                self.body[comp].append((opcode, op_name, _bytes_of(result),
+                                        calls.group(1) if calls else None))
+
+    def place(self, instance):
+        """``(leaf, pass, category, mixed, inherited)`` of one instruction:
+        ``mixed`` is None, or the fusion's two largest ``((leaf, pass),
+        share)``.  An instruction the compiler made, with no metadata on it
+        or in it (a prefetch of a layer's weights, a constant's broadcast),
+        has the place of the nearest of its users that has one, else of its
+        operands, and ``inherited`` says so."""
+        if instance not in self._placed:
+            placed = self._own(instance)
+            if placed is None:
+                placed = self._inherit(instance) or (
+                    UNSCOPED, "-", self.category(instance), None, False)
+            self._placed[instance] = placed
+        return self._placed[instance]
+
+    def fused(self, calls, depth=3):
+        """The instructions of a fused computation, a fusion nested in it
+        giving its own in its place."""
+        for row in self.body.get(calls, ()):
+            if row[0] == "fusion" and depth and row[3] in self.body:
+                yield from self.fused(row[3], depth - 1)
+            else:
+                yield row
+
+    def category(self, instance):
+        """The opcode; a fusion's kind, ``convolution fusion`` where it
+        holds a product."""
+        opcode, _name, calls, kind, _operands = self.inst[instance]
+        if opcode != "fusion":
+            return opcode
+        if any(row[0] in _PRODUCTS for row in self.fused(calls)):
+            return "convolution fusion"
+        return "%s fusion" % (kind or "").lower()
+
+    def _inherit(self, instance):
+        if self._users is None:
+            self._users = users = {}
+            for name, found in self.inst.items():
+                for operand in found[4]:
+                    users.setdefault(operand, []).append(name)
+        for step in (lambda n: self._users.get(n, ()),
+                     lambda n: self.inst[n][4] if n in self.inst else ()):
+            front, seen = [instance], {instance}
+            for _ in range(_INHERIT_HOPS):
+                front = [m for n in front for m in step(n)
+                         if m not in seen and not seen.add(m)]
+                for n in front:
+                    placed = self._own(n)
+                    if placed is not None and placed[0] != UNSCOPED:
+                        return (placed[0], placed[1],
+                                self.category(instance), None, True)
+        return None
+
+    def _own(self, instance):
+        """The instruction's place by its own metadata: None where it has
+        none at all, ``unscoped`` where its path names no scope."""
+        found = self.inst.get(instance)
+        if found is None:
+            return UNSCOPED, "-", "", None, False
+        opcode, op_name, calls, _kind, _operands = found
+        category = self.category(instance)
+        own_leaf, own_pass = _leaf_and_pass(op_name)
+        votes, products, named = {}, {}, bool(op_name)
+        for o, name, size, _calls in self.fused(calls):
+            if o in _NO_VOTE or not name:
+                continue
+            named = True
+            leaf, pas = _leaf_and_pass(name)
+            if pas == "-" and not name.startswith(("jit(", "pjit(")):
+                pas = own_pass      # a path cut short: the fusion's says
+            if leaf is not None:
+                size = max(size, 1.0)
+                votes[leaf, pas] = votes.get((leaf, pas), 0.0) + size
+                if o in _PRODUCTS:
+                    products[leaf, pas] = products.get((leaf, pas), 0) + size
+        if not named:
+            return None
+        if not votes:
+            return (own_leaf or UNSCOPED, own_pass if own_leaf else "-",
+                    category, None, False)
+        ranked = sorted(votes.items(), key=lambda kv: -kv[1])
+        total = sum(votes.values())
+        # a product's time is the product's, whatever rides along with it
+        (leaf, pas) = max(products, key=products.get) if products \
+            else ranked[0][0]
+        mixed = None
+        if votes[leaf, pas] < _AGREE * total:
+            mixed = [((leaf, pas), votes[leaf, pas] / total)] + [
+                (k, v / total) for k, v in ranked if k != (leaf, pas)][:1]
+        return leaf, pas, category, mixed, False
+
+
+
+_PATH_TOKEN = re.compile(r"p?jit\([^()]*\)|([^/()]+)")
+
+
+def _leaf_and_pass(op_name):
+    """An operation's path -> (the last component that is in
+    :data:`DEVICE_SCOPES` or None, its pass).  ``jit(<function>)`` names a
+    function, not a scope; ``jvp(attn)`` and ``transpose(jvp(attn))`` wrap
+    the scope ``attn``.  The pass: ``remat`` under ``rematted_computation``
+    (a checkpointed layer's second forward), else ``bwd`` under
+    ``transpose(``, else ``fwd`` under ``jvp(``, else ``-`` (the optimizer,
+    and whatever no autodiff touched)."""
+    tokens = [t for t in _PATH_TOKEN.findall(op_name) if t]
+    leaf = None
+    for t in reversed(tokens):
+        if t in _SCOPE_SET:
+            leaf = t
+            break
+        if t.partition(".")[0] in _COLLECTIVE_SCOPES:   # allreduce.dp
+            leaf = t.partition(".")[0]
+            break
+    if "rematted_computation" in tokens:
+        return leaf, "remat"
+    if "transpose(" in op_name:     # the transform, not the primitive
+        return leaf, "bwd"
+    if "jvp(" in op_name:
+        return leaf, "fwd"
+    return leaf, "-"
+
+
+
+def live_hlo():
+    """The compiled text of every program this process holds loaded on the
+    default backend (none before jax is imported): what
+    :func:`device_time_by_scope` joins a trace to where it is given no
+    ``hlo``, and what to keep beside a trace that is read elsewhere."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return []
+    return [m.to_string()
+            for exe in jax.devices()[0].client.live_executables()
+            for m in exe.hlo_modules()]
+
+
+def _modules_of(hlo):
+    """``hlo`` as :func:`device_time_by_scope` takes it -> {module name:
+    [_Module]} (two programs of one function share a name)."""
+    if hlo is None:
+        texts = live_hlo()
+    elif isinstance(hlo, str):
+        texts = [hlo]
+    elif isinstance(hlo, dict):
+        texts = list(hlo.values())
+    elif isinstance(hlo, (list, tuple)):
+        texts = list(hlo)
+    else:                                   # a compiled executable
+        texts = [hlo.as_text()]
+    out = {}
+    for text in texts:
+        m = _Module(text)
+        out.setdefault(m.name, []).append(m)
+    return out
+
+
+def _instance(event_name):
+    """On the TPU an op's event is named by its whole HLO line, ``%name =
+    ...``; elsewhere by the bare name."""
+    return event_name.split(" = ", 1)[0].strip().lstrip("%")
+
+
+def _self_times(events):
+    """Each event's time less what the events nested inside it on the same
+    line cover: a ``while`` spans its body's operations, and a loop of 24
+    trips would else count its layers twice."""
+    order = sorted(range(len(events)),
+                   key=lambda i: (events[i][1], -events[i][2]))
+    own = [e[2] for e in events]
+    open_ = []
+    for i in order:
+        start, dur = events[i][1], events[i][2]
+        while open_ and events[open_[-1]][1] + events[open_[-1]][2] <= start:
+            open_.pop()
+        if open_:
+            own[open_[-1]] -= dur
+        open_.append(i)
+    return [max(t, 0.0) for t in own]
+
+
+def _merged(intervals):
+    out = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def device_time_by_scope(trace_dir, hlo=None, steps=1):
+    """Where the device's time went, by named scope and by pass.
+
+    Reads the newest ``jax.profiler`` trace under ``trace_dir`` (its
+    ``*.xplane.pb``, through ``jax.profiler.ProfileData``) and joins every
+    event of the first device's ``XLA Ops`` line to its instruction in the
+    compiled module's text, where the ``op_name`` metadata holds the path of
+    ``jax.named_scope`` s and autodiff passes the operation was traced
+    under.  ``hlo``: a compiled executable, its ``as_text()``, a ``{module
+    name: text}`` map or a list of texts; left out, the event's own line
+    where it carries the metadata, else :func:`live_hlo`.  ``steps``: how
+    many executions the trace holds; every ``*_ms`` below is a step's.
+
+    Returns a dict: ``rows`` -- ``(leaf, pass, ms, share of busy, calls a
+    step, HLO category)`` by falling ms, the leaf being the last component
+    of the path that is in :data:`DEVICE_SCOPES` (``unscoped`` where there
+    is none, or no instruction of that name: never dropped) and the pass
+    ``fwd`` / ``bwd`` / ``remat`` / ``-``; a fusion goes to the (leaf, pass)
+    most of its fused instructions lie under by result bytes -- to its
+    product's where it holds one (``convolution fusion``: the time is the
+    product's, whatever XLA fused round it) -- and where under 80 % of the
+    bytes agree it is counted in ``mixed_ms`` too and listed under ``mixed``
+    as ``(instance, ms, [((leaf, pass), share), ...two])``;
+    times are self times (an event's less the events nested in it).
+    ``busy_ms`` (the union of the op intervals), ``window_ms``,
+    ``unscoped_ms``, ``mixed_ms``, ``inherited_ms`` (of instructions the
+    compiler made, placed by a neighbour: ``_Module.place``); ``gaps`` --
+    the device's idle time by the shortest ``engine.*`` span covering each
+    gap's middle, ``(name, ms)``, and ``longest_gap`` ``(name, ms)``, one
+    gap whole, not a step's share."""
+    trace = _load_trace(trace_dir)
+    steps = max(int(steps), 1)
+    devices = sorted((int(_DEVICE_PLANE.match(p["name"]).group(1)), i)
+                     for i, p in enumerate(trace["planes"])
+                     if _DEVICE_PLANE.match(p["name"]))
+    if not devices:
+        raise ValueError("the trace under %s has no device plane (a CPU "
+                         "run records none)" % trace_dir)
+    plane = trace["planes"][devices[0][1]]
+    ops = [e for ln in plane["lines"] if ln["name"] == _OPS_LINE
+           for e in ln["events"]]
+    runs = sorted((e[1], e[1] + e[2], e[0].split("(", 1)[0])
+                  for ln in plane["lines"] if ln["name"] == _MODULES_LINE
+                  for e in ln["events"])
+    starts = [r[0] for r in runs]
+    modules = _modules_of(hlo)
+
+    def module_at(t):
+        i = bisect.bisect_right(starts, t) - 1
+        return runs[i][2] if i >= 0 and t < runs[i][1] else None
+
+    # the text of each module that ran: of several programs of one name,
+    # the one that knows the most of the instances seen under that name
+    ran = [module_at(e[1]) for e in ops]
+    seen = {}
+    for e, name in zip(ops, ran):
+        seen.setdefault(name, set()).add(_instance(e[0]))
+    chosen = {}
+    for name, instances in seen.items():
+        known = modules.get(name) or (
+            [m for ms in modules.values() for m in ms] if name is None
+            else [])
+        if known:
+            chosen[name] = max(known, key=lambda m: len(
+                instances.intersection(m.inst)))
+
+    rows, mixed, unscoped, inherited = {}, {}, {}, 0.0
+    for e, own, module in zip(ops, _self_times(ops), map(chosen.get, ran)):
+        name = e[0]
+        instance = _instance(name)
+        placed = None
+        if "op_name=" in name:                  # the line holds its metadata
+            own_name = _OP_NAME.search(name)
+            leaf, pas = _leaf_and_pass(own_name.group(1) if own_name else "")
+            if leaf:
+                placed = (leaf, pas, _split_instruction(
+                    name.split(" = ", 1)[-1])[1], None, False)
+        if placed is None and module is not None:
+            placed = module.place(instance)
+        if placed is None:
+            placed = (UNSCOPED, "-", "", None, False)
+        leaf, pas, category, split, lent = placed
+        inherited += own * lent
+        if not category:
+            category = str(e[3].get("hlo_category", "")) or (
+                _split_instruction(name.split(" = ", 1)[1])[1]
+                if " = " in name else "")
+        row = rows.setdefault((leaf, pas, category), [0.0, 0])
+        row[0] += own
+        row[1] += 1
+        if leaf == UNSCOPED:
+            unscoped[instance] = unscoped.get(instance, 0.0) + own
+        if split:
+            m = mixed.setdefault(instance, [0.0, split])
+            m[0] += own
+    lo = min(e[1] for e in ops) if ops else 0.0
+    hi = max(e[1] + e[2] for e in ops) if ops else 0.0
+    union = _merged((e[1], e[1] + e[2]) for e in ops)
+    busy = sum(e - s for s, e in union)
+    ms = 1e-6 / steps
+    gaps, longest = _gaps(union, _host_spans(trace))
+    return {
+        "steps": steps,
+        "rows": [(leaf, pas, t * ms, t / busy if busy else 0.0,
+                  n / steps, category)
+                 for (leaf, pas, category), (t, n) in sorted(
+                     rows.items(), key=lambda kv: -kv[1][0])],
+        "busy_ms": busy * ms, "window_ms": (hi - lo) * ms,
+        "unscoped_ms": sum(unscoped.values()) * ms,
+        "unscoped": [(k, v * ms) for k, v in sorted(
+            unscoped.items(), key=lambda kv: -kv[1])],
+        "inherited_ms": inherited * ms,
+        "mixed_ms": sum(t for t, _ in mixed.values()) * ms,
+        "mixed": [(k, t * ms, split) for k, (t, split) in sorted(
+            mixed.items(), key=lambda kv: -kv[1][0])],
+        "gaps": [(k, v * ms) for k, v in gaps],
+        "longest_gap": longest and (longest[0], longest[1] * 1e-6),
+    }
+
+
+def _host_spans(trace):
+    return [(name, start, start + dur)
+            for p in trace["planes"] if not _DEVICE_PLANE.match(p["name"])
+            for ln in p["lines"] for name, start, dur, _ in ln["events"]
+            if name.startswith("engine.")]
+
+
+def _gaps(union, spans):
+    """The idle time between the first and the last operation, by what the
+    host was in: each gap goes to the shortest ``engine.*`` span that
+    covers its middle.  Returns ``([(name, ns)] by falling ns, the longest
+    single gap as (name, ns) or None)``."""
+    by, longest = {}, None
+    for (_, end), (start, _) in zip(union, union[1:]):
+        mid = (end + start) / 2
+        best = None
+        for name, a, b in spans:
+            if a <= mid <= b and (best is None or b - a < best[1]):
+                best = (name, b - a)
+        key = best[0] if best else "host(unattributed)"
+        by[key] = by.get(key, 0.0) + start - end
+        if longest is None or start - end > longest[1]:
+            longest = (key, start - end)
+    return sorted(by.items(), key=lambda kv: -kv[1]), longest
+
+
+def device_table(result, min_share=0.0):
+    """:func:`device_time_by_scope`'s result as the table ``dumps()``
+    prints; rows under ``min_share`` of busy time are summed into one."""
+    out = ["device time %.3f ms a step busy of %.3f (%d step%s); unscoped "
+           "%.3f ms (%.2f %%), mixed %.3f ms, placed by a neighbour %.3f ms"
+           % (result["busy_ms"], result["window_ms"], result["steps"],
+              "" if result["steps"] == 1 else "s", result["unscoped_ms"],
+              100 * result["unscoped_ms"] / result["busy_ms"]
+              if result["busy_ms"] else 0.0, result["mixed_ms"],
+              result["inherited_ms"]),
+           "%-22s %-6s %10s %7s %8s  %s" % ("Scope", "Pass", "ms/step",
+                                            "busy%", "calls", "HLO category")]
+    rest = [0.0, 0.0, 0.0]
+    for leaf, pas, t, share, calls, category in result["rows"]:
+        if share < min_share:
+            rest = [rest[0] + t, rest[1] + share, rest[2] + calls]
             continue
-        d = agg.setdefault(cat, [0.0, 0, 0.0, 0.0])
-        d[0] += e["dur"]
-        d[1] += 1
-        d[2] += float(e["args"].get("model_flops", 0) or 0)
-        d[3] += float(e["args"].get("raw_bytes_accessed", 0) or 0)
-    return {cat: {"ms_per_step": dur / 1e3 / steps,
-                  "kernels": n // steps,
-                  "tflops": dur and fl / (dur * 1e6) or 0.0,
-                  "gb_s": dur and by / (dur * 1e3) or 0.0}
-            for cat, (dur, n, fl, by) in agg.items()}
+        out.append("%-22s %-6s %10.3f %6.2f%% %8.1f  %s"
+                   % (leaf, pas, t, 100 * share, calls, category))
+    if rest[0]:
+        out.append("%-22s %-6s %10.3f %6.2f%% %8.1f  %s"
+                   % ("(rows under %.1f %%)" % (100 * min_share), "", rest[0],
+                      100 * rest[1], rest[2], ""))
+    for instance, t, split in result["mixed"][:10]:
+        if min_share and t < min_share * result["busy_ms"]:
+            break
+        out.append("mixed %-28s %8.3f ms  %s" % (instance, t, ", ".join(
+            "%s %s %.0f %%" % (leaf, pas, 100 * share)
+            for (leaf, pas), share in split)))
+    for name, t in result["gaps"][:5]:
+        out.append("idle  %-28s %8.3f ms a step" % (name, t))
+    if result["longest_gap"]:
+        out.append("idle  longest single gap %.3f ms under %s"
+                   % (result["longest_gap"][1], result["longest_gap"][0]))
+    return "\n".join(out)
