@@ -41,6 +41,7 @@ from ..ops.pallas.flash_attention import SAVED_NAMES as _FLASH_KEPT
 from ..ops.pallas.selective_scan import SAVED_NAMES as _SCAN_KEPT
 from ..parallel.sharding import ShardingRules, constraint, PartitionSpec as P
 from ..parallel.ring_attention import ring_self_attention
+from ..parallel.moe import SAVED_NAMES as _MOE_KEPT
 from ..parallel.moe import expert_layer, moe_layer, route
 from .mamba import IN_PROJ_NAME, mamba_mixer
 from .mla import mla_leaf_shapes, mla_mixer
@@ -294,13 +295,15 @@ def _dense_self_attention(q, k, v, causal=True, scale=None, window=None):
 # ``checkpoint_name``: the results whose second forward costs most a byte
 # kept (PERF.md §6, PR 31, PR 33) -- the selective scan's output and chunk
 # boundaries, flash attention's ``o`` and ``lse``, the Mamba ``in_proj``'s
-# output, ``wqkv``'s output split into heads and every mixer's output before
-# the residual add.  Everything else (the norms, the convolution, the MLP up
-# to ``w_down``) the backward re-makes.  A name no layer of a model produces
-# costs nothing.
+# output, ``wqkv``'s output split into heads, every mixer's output before
+# the residual add and the routed experts' down product, the rows the
+# combine's backward reads (PR 37).  Everything else (the norms, the
+# convolution, the MLP up to ``w_down``, the experts up to theirs) the
+# backward re-makes.  A name no layer of a model produces costs nothing.
 MIXER_OUT = "mixer_out"
 QKV_NAME = "attn_qkv"
-KEPT = _SCAN_KEPT + _FLASH_KEPT + (IN_PROJ_NAME, QKV_NAME, MIXER_OUT)
+KEPT = (_SCAN_KEPT + _FLASH_KEPT + (IN_PROJ_NAME, QKV_NAME, MIXER_OUT)
+        + _MOE_KEPT)
 
 # the activation ``TransformerConfig.mlp`` names: of ``up(h)`` in the first
 # form, of ``gate(h)`` in the gated ones

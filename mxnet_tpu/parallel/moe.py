@@ -9,7 +9,12 @@ absent).  Two layers live here:
   sorted by expert, the held experts' pairs first, group after group; each
   group's rows go through its expert's matrices as one grouped product
   (`ops/pallas/grouped_matmul.py`), and the rows come back to their tokens
-  weighted by the router.  No token is dropped whatever the imbalance, no
+  weighted by the router: the combine, one operation (``_combine``) whose
+  backward is written in the sorted rows' space -- a row's gradient is its
+  weight times its token's, read by token as the dispatch's forward reads
+  -- and whose residual, the down product's rows, carries a
+  ``checkpoint_name`` (``SAVED_NAMES``) so that a rematerialised layer
+  keeps them.  No token is dropped whatever the imbalance, no
   product is computed for a pair whose expert is not held (beyond a row
   tile's rounding), and what the absent experts would have added is left
   out: with ``experts_held`` a chip's share of an expert-parallel layer,
@@ -28,11 +33,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.pallas.grouped_matmul import grouped_matmul
 from .sharding import constraint
 
-__all__ = ["expert_layer", "moe_layer", "route"]
+__all__ = ["expert_layer", "moe_layer", "route", "SAVED_NAMES"]
+
+# the ``checkpoint_name`` of the down product's rows as the combine's
+# residual: what a rematerialised layer keeps of an expert layer, so that it
+# re-makes neither the down product nor a gather of its rows
+# (``models.transformer.KEPT``)
+SAVED_NAMES = ("moe_down_rows",)
 
 
 def route(tokens, router_w, top_k, renormalize=False, seq_shape=None):
@@ -61,6 +73,30 @@ def route(tokens, router_w, top_k, renormalize=False, seq_shape=None):
     return weights, experts, aux
 
 
+def _slots(rows, inverse, k):
+    """``rows[inverse]`` [P, E] slot-major, [k, S, E]: ``[j, s]`` is the
+    sorted row of pair ``s k + j``.  With the slots leading, a tiled layout
+    pads nothing and ``[P, E]`` to ``[k, S, E]`` moves no byte; token-major,
+    ``[S, k, E]``, a ``k`` of 6 is padded to the tile's rows and every
+    reshape from or to ``[P, E]`` is a copy (PERF.md §6, PR 37)."""
+    return rows[inverse.reshape(-1, k).T]
+
+
+def _sum_slots(slots, weights=None):
+    """The float32 sum over the slots of ``slots`` [k, S, E], each times its
+    weight (``weights`` [S, k]; None: 1) -> [S, E] float32.  Term by term:
+    one elementwise pass over ``k`` slices, where a reduction over the
+    leading axis left the float32 copy of ``slots`` a pass of its own
+    (PERF.md §6, PR 37)."""
+    total = None
+    for j in range(slots.shape[0]):
+        term = slots[j].astype(jnp.float32)
+        if weights is not None:
+            term = term * weights[:, j, None]
+        total = term if total is None else total + term
+    return total
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _to_sorted(tokens, order, inverse, k):
     """tokens [S, E] -> the row of each (token, slot) pair in sorted order
@@ -69,35 +105,60 @@ def _to_sorted(tokens, order, inverse, k):
 
 
 def _to_sorted_fwd(tokens, order, inverse, k):
-    return tokens[order // k], (order, inverse, tokens.shape[0])
+    return tokens[order // k], inverse
 
 
-def _to_sorted_bwd(k, res, g):
+def _to_sorted_bwd(k, inverse, g):
     # the transpose of a permutation is a gather by its inverse, not a
-    # scatter; a token's gradient is the sum over its slots
-    order, inverse, S = res
-    back = g[inverse].reshape(S, k, g.shape[-1])
-    return back.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+    # scatter; a token's gradient is the sum over its slots: the combine's
+    # forward with unit weights
+    return _sum_slots(_slots(g, inverse, k)).astype(g.dtype), None, None
 
 
 _to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
 
 
 @jax.custom_vjp
-def _from_sorted(rows, order, inverse):
-    """Sorted rows [P, E] -> the pairs' own order [P, E]."""
-    return rows[inverse]
+def _combine(out, weights, order, inverse):
+    """The sorted rows back at their tokens, weighted: ``y[s] = sum_j
+    weights[s, j] * out[inverse[s k + j]]`` in float32, in ``out``'s dtype
+    [S, E].  Its backward stays in the sorted rows' space (no ``[S, k, E]``
+    gradient is built): the gradient of sorted row ``r`` is ``w[r] *
+    g[token(r)]``, a gather from ``g`` [S, E] by the index the dispatch's
+    forward uses, and a weight's is its row's product with that."""
+    k = weights.shape[1]
+    with jax.named_scope("moe.combine.gather"):
+        slots = _slots(out, inverse, k)
+    with jax.named_scope("moe.combine.sum"):
+        return _sum_slots(slots, weights).astype(out.dtype)
 
 
-def _from_sorted_fwd(rows, order, inverse):
-    return rows[inverse], (order,)
+def _combine_fwd(out, weights, order, inverse):
+    # the rows are named as the residual alone: a kept value that the
+    # forward reads too gets a ``reduce_precision`` from ``jax.checkpoint``,
+    # which behind a kernel is a copy of the rows
+    return _combine(out, weights, order, inverse), (
+        checkpoint_name(out, SAVED_NAMES[0]), weights, order, inverse)
 
 
-def _from_sorted_bwd(res, g):
-    return g[res[0]], None, None
+def _combine_bwd(res, g):
+    out, weights, order, inverse = res
+    with jax.named_scope("moe.combine.gather"):
+        g_rows = _to_sorted(g, order, inverse, weights.shape[1]
+                            ).astype(jnp.float32)
+        w_rows = weights.reshape(-1)[order]
+    with jax.named_scope("moe.combine.sum"):
+        # rows past the groups are zero in ``out``: an absent expert's slot
+        # gets a zero weight gradient, and what ``d_out`` holds there is
+        # never read (the grouped product's tail and row mask)
+        d_out = (w_rows[:, None] * g_rows).astype(out.dtype)
+        d_w_rows = jnp.sum(out.astype(jnp.float32) * g_rows, axis=-1)
+        d_weights = d_w_rows[inverse].reshape(weights.shape
+                                              ).astype(weights.dtype)
+    return d_out, d_weights, None, None
 
 
-_from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
@@ -129,6 +190,7 @@ def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
     reg = _telemetry.registry()
     reg.counter("moe.experts_held.%dof%d" % (n_held, n)).inc()
     reg.counter("moe.buffer_rows.%d" % P).inc()
+    reg.counter("moe.combine.rows_kept.%dx%d" % (P, E)).inc()
 
     tokens = x.reshape(S, E)
     if act is None:
@@ -161,12 +223,8 @@ def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
         out = grouped_matmul(up, w_down, sizes)
     with jax.named_scope("moe.combine"):
         # rows past the groups are zero, so an absent expert's slot adds 0
-        with jax.named_scope("moe.combine.gather"):
-            slots = _from_sorted(out, order, inverse).reshape(S, k, E)
-        with jax.named_scope("moe.combine.sum"):
-            y = jnp.einsum("ske,sk->se", slots.astype(jnp.float32), weights)
-    return (y.astype(x.dtype).reshape(B, T, E), aux,
-            jnp.sum(sizes).astype(jnp.float32))
+        y = _combine(out, weights, order, inverse)
+    return y.reshape(B, T, E), aux, jnp.sum(sizes).astype(jnp.float32)
 
 
 def moe_layer(x, gate_w, w_up, w_down, ep_axis="ep", capacity_factor=1.25,

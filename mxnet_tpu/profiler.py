@@ -774,6 +774,7 @@ _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+) \(.*\{\s*$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"\bcalls=%?([^\s,)]+)")
 _KIND = re.compile(r"\bkind=k([A-Za-z]+)")
+_TO_APPLY = re.compile(r"\bto_apply=%?([^\s,)]+)")
 _BRACES = re.compile(r"\{[^{}]*\}")
 _ARRAY = re.compile(r"\b([a-z]+)(\d*)[a-z0-9]*\[([0-9,]*)\]")
 _MODULE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
@@ -837,6 +838,9 @@ class _Module:
         self.body = {}
         self._placed = {}
         self._users = None
+        # a sort's comparator, a reduction's region: instruction ->
+        # computation it stands in, computation -> instruction applying it
+        self._comp_of, self._applied_by = {}, {}
         comp = None
         for line in text.splitlines():
             m = _INSTRUCTION.match(line)
@@ -857,7 +861,11 @@ class _Module:
             self.inst[name] = (opcode, op_name,
                                calls.group(1) if calls else None,
                                kind.group(1) if kind else None, operands)
+            applies = _TO_APPLY.search(head)
+            if applies:
+                self._applied_by[applies.group(1)] = name
             if comp is not None:
+                self._comp_of[name] = comp
                 self.body[comp].append((opcode, op_name, _bytes_of(result),
                                         calls.group(1) if calls else None))
 
@@ -867,7 +875,8 @@ class _Module:
         share)``.  An instruction the compiler made, with no metadata on it
         or in it (a prefetch of a layer's weights, a constant's broadcast),
         has the place of the nearest of its users that has one, else of its
-        operands, and ``inherited`` says so."""
+        operands, else of the instruction that applies the computation it
+        stands in (a sort's fused comparator), and ``inherited`` says so."""
         if instance not in self._placed:
             placed = self._own(instance)
             if placed is None:
@@ -912,6 +921,11 @@ class _Module:
                     if placed is not None and placed[0] != UNSCOPED:
                         return (placed[0], placed[1],
                                 self.category(instance), None, True)
+        # what the compiler fused inside a comparator has its sort's place
+        applier = self._applied_by.get(self._comp_of.get(instance))
+        if applier is not None:
+            placed = self.place(applier)
+            return placed[0], placed[1], self.category(instance), None, True
         return None
 
     def _own(self, instance):

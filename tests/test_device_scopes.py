@@ -216,6 +216,33 @@ def test_every_op_of_the_lm_step_lies_under_a_leaf(config_name):
     assert {"fwd", "bwd", "remat", "-"} <= passes, passes
 
 
+def test_a_comparators_fusion_has_its_sorts_place():
+    """XLA's CPU compiler fuses a sort's comparator and leaves the fusion
+    no metadata; it has no user either.  It takes the place of the sort
+    that applies its computation."""
+    module = profiler._Module("""HloModule jit_step
+
+%fused_computation.1 (param_0: f32[], param_1: f32[]) -> pred[] {
+  %param_0 = f32[] parameter(0)
+  %param_1 = f32[] parameter(1)
+  ROOT %compare.1 = pred[] compare(%param_0, %param_1), direction=GT
+}
+
+%compare-greater-than.1 (p.0.lhs: f32[], p.0.rhs: f32[]) -> pred[] {
+  %p.0.lhs = f32[] parameter(0)
+  %p.0.rhs = f32[] parameter(1)
+  ROOT %select_compare_fusion = pred[] fusion(%p.0.rhs, %p.0.lhs), kind=kLoop, calls=%fused_computation.1
+}
+
+ENTRY %main (x: f32[64]) -> f32[64] {
+  %x = f32[64]{0} parameter(0)
+  ROOT %sort.1 = f32[64]{0} sort(%x), dimensions={0}, to_apply=%compare-greater-than.1, metadata={op_name="jit(step)/transpose(jvp(layers))/checkpoint/rematted_computation/mlp/moe.route/sort"}
+}
+""")
+    assert module.place("select_compare_fusion") == (
+        "moe.route", "remat", "loop fusion", None, True)
+
+
 def test_the_wrapper_round_value_and_grad_is_no_scope():
     """``loss`` used to wrap the whole ``value_and_grad``, so every op of
     the step lay under it; now it holds the loss's own arithmetic."""

@@ -12,6 +12,7 @@ import pytest
 
 from mxnet_tpu.models import TransformerConfig, TransformerLM
 from mxnet_tpu.models import transformer
+from mxnet_tpu.parallel import moe
 
 # widths that tell the weights apart by their shapes: wqkv [E, 96], wo
 # [E, E], w_up [E, 80], in_proj [E, 128], out_proj [64, E], w_down [80, E],
@@ -24,6 +25,9 @@ MODELS = {
     "hybrid": dict(TOY, layer_types=("mamba", "attention"), ssm_state=8,
                    ssm_dt_rank=4),
     "moe": dict(TOY, use_moe=True, n_experts=4),
+    # gated experts, two slots a token, in every layer
+    "experts": dict(TOY, mlp="swiglu", n_experts=4, moe_top_k=2,
+                    mlp_types=("moe", "moe")),
     # runs of 5; of 3, 1, 2; of 1, 3
     "dense5": dict(TOY, n_layers=5),
     "hybrid6": dict(TOY, n_layers=6, ssm_state=8, ssm_dt_rank=4,
@@ -103,19 +107,70 @@ def test_wqkvs_product_alone_is_kept_by_its_name(monkeypatch):
         "w_down": 1}
 
 
+def expert_work():
+    """Of the jaxpr of ``jax.grad(model.loss)`` of the gated expert toy:
+    the grouped product's forward kernels and the gathers whose operand has
+    the down product's shape, ``[P, E]``."""
+    model, p, x, y = build("experts")
+    jaxpr = jax.make_jaxpr(jax.grad(model.loss))(p, x, y).jaxpr
+    seen = {"gmm_fwd": 0, "gathers_of_P_E_rows": 0}
+    for eqn in equations(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            seen["gmm_fwd"] += eqn.params["name"] == "gmm_fwd"
+        elif eqn.primitive.name == "gather":
+            seen["gathers_of_P_E_rows"] += (
+                eqn.invars[0].aval.shape == (B * T * 2, E))
+    return seen
+
+
+@pytest.mark.parametrize("kept,products", [
+    ("KEPT", 5), ("down_rows", 5), ("nothing", 6)])
+def test_the_experts_down_rows_are_kept_and_gathered_once_a_pass(
+        kept, products, monkeypatch):
+    """One body stands for both expert layers.  The forward runs gate, up
+    and down; the backward's body re-makes gate and up, and the down
+    product too unless its rows are kept by name.  Kept or not, only two
+    gathers read ``[P, E]`` rows: the combine's forward (``out[inverse]``)
+    and the dispatch's transpose (``g[inverse]``) -- the second forward
+    gathers none for the combine, whose backward reads ``g`` by token."""
+    monkeypatch.setenv("MXTPU_PALLAS", "interpret")
+    monkeypatch.setattr(transformer, "KEPT", {
+        "KEPT": transformer.KEPT, "nothing": (),
+        "down_rows": moe.SAVED_NAMES}[kept])
+    assert expert_work() == {"gmm_fwd": products, "gathers_of_P_E_rows": 2}
+
+
+def test_the_expert_lms_gradient_builds_no_token_major_slots():
+    """Neither pass of ``jax.grad(model.loss)`` of the expert toy in
+    bfloat16 holds a value of shape ``[S, k, E]``: the slots are gathered
+    slot-major, ``[k, S, E]``, summed slice by slice (no float32 copy of
+    them either), and the combine's backward stays in the sorted rows."""
+    model, p, x, y = build("experts", dtype="bfloat16")
+    jaxpr = jax.make_jaxpr(jax.grad(model.loss))(p, x, y).jaxpr
+    shapes = {(v.aval.shape, str(v.aval.dtype))
+              for eqn in equations(jaxpr) for v in eqn.outvars}
+    assert ((2, B * T, E), "bfloat16") in shapes         # both gathers
+    assert ((2, B * T, E), "float32") not in shapes
+    assert not [s for s in shapes if s[0] == (B * T, 2, E)]
+
+
 @pytest.mark.parametrize("kind,over,kept", [
     ("dense", {}, None), ("hybrid", {}, None), ("moe", {}, None),
     ("dense", dict(scan_unroll=False), None),
     ("hybrid", dict(scan_unroll=False), None),
     ("dense", {}, "wqkv"), ("hybrid", {}, "wqkv"), ("moe", {}, "wqkv"),
+    ("experts", {}, None), ("experts", {}, "down_rows"),
+    ("moe", {}, "down_rows"),
 ], ids=["dense", "hybrid", "moe", "dense_rolled", "hybrid_rolled",
-        "dense_wqkv_alone", "hybrid_wqkv_alone", "moe_wqkv_alone"])
+        "dense_wqkv_alone", "hybrid_wqkv_alone", "moe_wqkv_alone",
+        "experts", "experts_down_rows_alone", "moe_down_rows_alone"])
 def test_every_gradient_is_the_bare_checkpoints_to_the_bit(kind, over, kept,
                                                            monkeypatch):
     """A kept value is the value the second forward would re-make: loss and
     every gradient leaf equal those of a policy that keeps nothing (the
     parent's dense branch), kernels through the interpreter; so with all of
-    ``KEPT`` and with ``wqkv``'s product alone.  In a rolled
+    ``KEPT``, with ``wqkv``'s product alone and with the experts' down rows
+    alone.  In a rolled
     layer loop the kept values are a scan's stacked residuals, and there
     XLA's CPU backend re-makes ``x + o`` fused otherwise than it made it
     the first time: with the mixer's output kept the backward sees the
@@ -125,7 +180,9 @@ def test_every_gradient_is_the_bare_checkpoints_to_the_bit(kind, over, kept,
         monkeypatch.setenv("MXTPU_PALLAS", "interpret")
     model, p, x, y = build(kind, **over)
     if kept:
-        monkeypatch.setattr(transformer, "KEPT", (transformer.QKV_NAME,))
+        monkeypatch.setattr(transformer, "KEPT", {
+            "wqkv": (transformer.QKV_NAME,),
+            "down_rows": moe.SAVED_NAMES}[kept])
     loss, grads = jax.jit(jax.value_and_grad(model.loss))(p, x, y)
     monkeypatch.setattr(transformer, "KEPT", ())
     loss0, grads0 = jax.jit(jax.value_and_grad(model.loss))(p, x, y)
@@ -180,8 +237,9 @@ def test_the_names_are_one_tuple():
         for name in ("flash_attention", "selective_scan"))
     assert transformer.KEPT == (
         selective_scan.SAVED_NAMES + flash_attention.SAVED_NAMES
-        + (mamba.IN_PROJ_NAME, transformer.QKV_NAME, transformer.MIXER_OUT))
-    assert len(set(transformer.KEPT)) == 7
+        + (mamba.IN_PROJ_NAME, transformer.QKV_NAME, transformer.MIXER_OUT)
+        + moe.SAVED_NAMES)
+    assert len(set(transformer.KEPT)) == 8
 
 
 # -- the layer loop ---------------------------------------------------------

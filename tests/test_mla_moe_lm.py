@@ -154,6 +154,8 @@ def test_three_train_steps_descend_and_count_the_kernels(monkeypatch):
     assert grew("pallas.select.flash_attention.interpret") >= 1
     assert grew("moe.experts_held.4of8") >= 1
     assert grew("moe.buffer_rows.%d" % (2 * 32 * 2)) >= 1
+    assert grew("moe.combine.rows_kept.%dx%d" % (2 * 32 * 2,
+                                                 TOY["d_model"])) >= 1
     assert any(k.startswith("pallas.gmm.tile.gmm_dw.") and grew(k)
                for k in after)
 
