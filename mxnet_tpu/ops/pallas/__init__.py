@@ -17,10 +17,12 @@ from .int8_matmul import int8_matmul, int8_matmul_lax  # noqa: F401
 from .layers import fused_rmsnorm, fused_softmax_xent  # noqa: F401
 from .grouped_matmul import grouped_matmul, grouped_matmul_lax  # noqa: F401
 from .selective_scan import selective_scan, selective_scan_lax  # noqa: F401
+from .short_conv import gated_short_conv, gated_short_conv_lax  # noqa: F401
 
 __all__ = ["flash_attention", "flash_attention_lse",
            "fused_rmsnorm", "fused_softmax_xent",
            "grouped_matmul", "grouped_matmul_lax",
            "int8_matmul", "int8_matmul_lax",
            "selective_scan", "selective_scan_lax",
+           "gated_short_conv", "gated_short_conv_lax",
            "kernel_impl", "kernel_unit", "kernel_units"]

@@ -47,25 +47,42 @@ __all__ = ["expert_layer", "moe_layer", "route", "SAVED_NAMES"]
 SAVED_NAMES = ("moe_down_rows",)
 
 
-def route(tokens, router_w, top_k, renormalize=False, seq_shape=None):
+def route(tokens, router_w, top_k, renormalize=False, seq_shape=None,
+          scoring="softmax", bias=None):
     """The router, in float32 whatever the model's dtype: ``s = softmax(
     tokens . router_w)`` over all experts, the ``top_k`` largest of each
     token (greedy) as ``(weights [S, k], experts [S, k])``, and the
     load-balancing term of each sequence, averaged: ``sum_i f_i P_i`` with
     ``f_i`` the slots routed to expert ``i`` times ``n / (k T)`` (a count:
     no gradient) and ``P_i`` the mean of ``s_i`` over the sequence.
-    ``seq_shape`` is ``(B, T)`` of the flattened ``tokens`` [S, E]."""
+    ``seq_shape`` is ``(B, T)`` of the flattened ``tokens`` [S, E].
+
+    ``scoring="sigmoid"``: ``s = sigmoid(tokens . router_w)``, each expert's
+    score its own.  ``bias`` [n] (float32): the experts are the ``top_k``
+    largest of ``s + bias``, their weights the unbiased ``s``; renormalised
+    they are divided by their sum plus 1e-6.  The bias takes no gradient."""
     n = router_w.shape[1]
     S = tokens.shape[0]
     B, T = seq_shape or (1, S)
     logits = jnp.einsum("se,en->sn", tokens.astype(jnp.float32),
                         router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    s = jax.nn.softmax(logits, axis=-1)
-    weights, experts = lax.top_k(s, top_k)
-    if renormalize and top_k > 1:
-        weights = weights / jnp.maximum(
-            weights.sum(-1, keepdims=True), 1e-9)
+    if scoring == "softmax" and bias is None:
+        s = jax.nn.softmax(logits, axis=-1)
+        weights, experts = lax.top_k(s, top_k)
+        if renormalize and top_k > 1:
+            weights = weights / jnp.maximum(
+                weights.sum(-1, keepdims=True), 1e-9)
+    else:
+        assert scoring in ("softmax", "sigmoid"), scoring
+        s = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+        choose = s if bias is None else s + lax.stop_gradient(
+            bias.astype(jnp.float32))
+        _, experts = lax.top_k(choose, top_k)
+        weights = jnp.take_along_axis(s, experts, axis=-1)
+        if renormalize and top_k > 1:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
     counts = jax.nn.one_hot(experts.reshape(B, T * top_k), n,
                             dtype=jnp.float32).sum(axis=1)       # [B, n]
     f = lax.stop_gradient(counts) * (n / (top_k * T))

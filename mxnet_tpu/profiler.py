@@ -680,20 +680,22 @@ DEVICE_SCOPES = (
     # the LM step, outside the layers
     "embed", "layers", "norm", "residual", "head", "loss", "optimizer",
     # the attention half: the container, then its leaves
-    "attn", "attn.qkv", "attn.rope", "attn.kv_broadcast", "attn.core",
-    "attn.out",
+    "attn", "attn.qkv", "attn.qk_norm", "attn.rope", "attn.kv_broadcast",
+    "attn.core", "attn.out",
     "mla.q", "mla.kv_a", "mla.kv_b", "mla.rope",
     "ssm", "ssm.in_proj", "conv", "ssm.x_proj", "ssm.dt", "scan",
     "ssm.out_proj",
+    "sconv", "sconv.in_proj", "sconv.out_proj",
     # the MLP half
     "mlp", "mlp.up", "mlp.act", "mlp.down",
     "moe.route", "moe.dispatch", "moe.dispatch.sort", "moe.dispatch.gather",
     "moe.experts", "moe.experts.act", "moe.combine", "moe.combine.gather",
-    "moe.combine.sum", "moe.shared",
+    "moe.combine.sum", "moe.shared", "moe.bias_update",
     # the kernels (`ops/pallas/`): a call's ``name=`` is its scope
     "flash_fwd", "flash_dq", "flash_dkv", "rmsnorm_fwd", "xent_fwd",
     "xent_bwd", "ssm_scan_fwd", "ssm_scan_bwd", "gmm_fwd", "gmm_dx",
-    "gmm_dw", "int8_matmul", "int8_matmul_dequant",
+    "gmm_dw", "int8_matmul", "int8_matmul_dequant", "short_conv_fwd",
+    "short_conv_bwd",
     # the Gluon step (`gluon/contrib/fused.py`)
     "forward", "backward", "update",
     # the mesh (`parallel/`)

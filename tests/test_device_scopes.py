@@ -191,7 +191,7 @@ def _lm_step_text(config_name, batch=2, seq=64):
 
 @pytest.mark.parametrize("config_name", [
     "pythia-1.4b-sizes", "jamba2-3b-l14", "deepseek-v2-lite-l5-e32",
-    "smallthinker-21b-l4-e16"])
+    "smallthinker-21b-l4-e16", "lfm2-8b-a1b-l5-e8"])
 def test_every_op_of_the_lm_step_lies_under_a_leaf(config_name):
     """Compile the family's train step at its rehearsal size: every fusion,
     product, custom call, sort, gather and scatter of the module resolves to
@@ -209,8 +209,13 @@ def test_every_op_of_the_lm_step_lies_under_a_leaf(config_name):
     assert leaves <= set(profiler.DEVICE_SCOPES)
     # the leaves the issue asked for are really there
     want = {"embed", "head", "optimizer", "mlp.up" if config_name in (
-        "pythia-1.4b-sizes", "jamba2-3b-l14", "deepseek-v2-lite-l5-e32")
-        else "moe.combine.sum"}
+        "pythia-1.4b-sizes", "jamba2-3b-l14", "deepseek-v2-lite-l5-e32",
+        "lfm2-8b-a1b-l5-e8") else "moe.combine.sum"}
+    if config_name == "lfm2-8b-a1b-l5-e8":
+        # the convolution mixer (its lax form here, under the container),
+        # the per-head norms, the bias's own update
+        want |= {"sconv", "sconv.in_proj", "sconv.out_proj", "attn.qk_norm",
+                 "moe.bias_update"}
     assert want <= leaves, sorted(leaves)
     passes = {module.place(n)[1] for n in held}
     assert {"fwd", "bwd", "remat", "-"} <= passes, passes

@@ -58,10 +58,12 @@ def test_runs_of_layers_and_their_index_into_each_stack():
 
 @pytest.mark.parametrize("bad", [
     dict(layer_types=("mamba",)),                 # names 1 layer of 4
-    dict(layer_types=("mamba", "conv", "mamba", "mamba")),
+    dict(layer_types=("mamba", "lstm", "mamba", "mamba")),
     dict(n_kv_heads=3),                           # 4 heads in groups of 3
     dict(mlp="relu"),
-    dict(layer_types=("mamba",) * 4, use_moe=True),
+    # beside experts Mamba layers build since PR 38; an expert bias without
+    # an expert layer does not
+    dict(layer_types=("mamba",) * 4, moe_expert_bias=True),
 ])
 def test_a_configuration_that_cannot_be_built_is_refused(bad):
     with pytest.raises(AssertionError):

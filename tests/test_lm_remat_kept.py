@@ -231,15 +231,15 @@ def test_both_entry_points_of_flash_name_their_forward(with_lse):
 
 
 def test_the_names_are_one_tuple():
-    from mxnet_tpu.models import mamba
+    from mxnet_tpu.models import mamba, short_conv
     flash_attention, selective_scan = (
         importlib.import_module("mxnet_tpu.ops.pallas." + name)
         for name in ("flash_attention", "selective_scan"))
     assert transformer.KEPT == (
         selective_scan.SAVED_NAMES + flash_attention.SAVED_NAMES
         + (mamba.IN_PROJ_NAME, transformer.QKV_NAME, transformer.MIXER_OUT)
-        + moe.SAVED_NAMES)
-    assert len(set(transformer.KEPT)) == 8
+        + moe.SAVED_NAMES + (short_conv.IN_PROJ_NAME,))
+    assert len(set(transformer.KEPT)) == 9
 
 
 # -- the layer loop ---------------------------------------------------------

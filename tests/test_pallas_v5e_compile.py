@@ -17,6 +17,7 @@ import pytest
 
 fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
 ss = importlib.import_module("mxnet_tpu.ops.pallas.selective_scan")
+sc = importlib.import_module("mxnet_tpu.ops.pallas.short_conv")
 
 
 @pytest.fixture(scope="module")
@@ -48,6 +49,9 @@ CASES = [
                  id="pythia69_tp4_shard"),
     pytest.param(1, 8192, 8192, 16, 64, jnp.bfloat16, True, (1024, 1024),
                  id="chip_smoke_long_d64"),
+    # 8 key/value heads broadcast to the 32 query heads before the kernels
+    pytest.param(4, 8192, 8192, 32, 64, jnp.bfloat16, True, (1024, 1024),
+                 id="lfm2_8b_train_s8k_d64"),
     pytest.param(2, 1024, 1024, 2, 128, jnp.float32, True, (1024, 1024),
                  id="f32_top_rung"),
     pytest.param(2, 1000, 1000, 2, 64, jnp.float32, False, (1024, 1024),
@@ -214,6 +218,13 @@ LM_CASES = [
                   "flash_fwd_window": 1, "flash_dq_window": 1,
                   "flash_dkv_window": 1},
                  id="smallthinker_train_s16k_full_and_window"),
+    # the convolution's forward runs again in the second forward: its
+    # result is not kept, ``in_proj``'s output is
+    pytest.param("lfm2-8b-a1b-l5-e8", "pretrain_b4_s8192_ep4",
+                 ("conv", "attention"),
+                 {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1,
+                  "short_conv_fwd": 2, "short_conv_bwd": 1},
+                 id="lfm2_8b_train_s8k_conv_and_attention"),
 ]
 
 
@@ -238,9 +249,9 @@ def test_lm_train_step_runs_each_kept_kernel_once_on_v5e(
         m.update(layer_types=layers, n_layers=len(layers))
     else:
         m.update(n_layers=layers)
-        for key in ("attn_windows", "attn_rope"):       # an entry a layer
-            if key in m:
-                m[key] = m[key][:layers]
+    for key in ("attn_windows", "attn_rope", "mlp_types"):  # one a layer
+        if key in m:
+            m[key] = m[key][:m["n_layers"]]
     # the kernel rule sees the CPU this test runs on: tell it the backend
     # the program is compiled for
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -346,3 +357,34 @@ def test_grouped_product_kernels_compile_for_v5e(one_chip, M, K, N, G, tiles):
             lowering_platforms=("tpu",)).compile().as_text()
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
                           text)) == 3
+
+
+@pytest.mark.parametrize("B,T,D,K,dtype,tiles", [
+    pytest.param(4, 8192, 2048, 3, jnp.bfloat16, (512, 512),
+                 id="lfm2_8b_train_s8k"),
+    pytest.param(2, 1000, 256, 3, jnp.float32, (8, 256),
+                 id="f32_1000_rows_of_8"),
+    pytest.param(1, 37, 64, 9, jnp.float32, (8, 64),
+                 id="padded_37_taps_9_narrow"),
+])
+def test_short_conv_kernels_compile_for_v5e(one_chip, B, T, D, K, dtype,
+                                            tiles):
+    """The gated convolution's forward and backward at the LFM2 cell's
+    shape and where the tiles are a sublane tile, the halo whole, the
+    channels narrower than the lanes: the halo blocks' index maps, the
+    taps' loads at offsets off the sublane tile and the partial ``dw``
+    rows taken by Mosaic."""
+    assert sc._choose_tiles(-(-T // 8) * 8, D) == tiles
+    x = jax.ShapeDtypeStruct((B, T, D), dtype, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((K, D), dtype, sharding=one_chip)
+
+    def fwd_and_grads(b, c, u, w, dy):
+        out, vjp = jax.vjp(lambda *a: sc.gated_short_conv(
+            *a, interpret=False), b, c, u, w)
+        return out, vjp(dy)
+
+    text = jax.jit(fwd_and_grads).trace(x, x, x, w, x).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    for name in ("short_conv_fwd", "short_conv_bwd"):
+        assert re.search(r"%%%s(\.\d+)? = [^\n]*tpu_custom_call" % name,
+                         text), name
