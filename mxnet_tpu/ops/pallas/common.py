@@ -21,7 +21,7 @@ def _round_up(x, m):
     return -(-x // m) * m
 
 
-def kernel_impl(name, sharded=False, per_device=False):
+def kernel_impl(name, sharded=False, per_device=False, count=True):
     """Which implementation of kernel ``name`` runs here: ``'pallas'`` (the
     kernel, on a single-device TPU), ``'interpret'`` (the kernel through the
     Pallas interpreter: any backend, parity testing), ``'sharded'`` (the
@@ -44,8 +44,9 @@ def kernel_impl(name, sharded=False, per_device=False):
     shard: it passes ``per_device`` and is answered by the left column.
 
     Runs at trace time.  Each answer bumps the ``pallas.select.<name>.<impl>``
-    telemetry counter, except a ``per_device`` one: whoever made the body
-    made the selection.
+    telemetry counter, except a ``per_device`` one (whoever made the body
+    made the selection) and one asked with ``count=False`` (a caller that
+    may overrule the answer counts its own).
     """
     from ...dispatch import pallas_mode
     from ...parallel.mesh import current_mesh
@@ -61,7 +62,7 @@ def kernel_impl(name, sharded=False, per_device=False):
         impl = "sharded" if sharded else "fallback"
     else:
         impl = "pallas"
-    if not per_device:
+    if count and not per_device:
         from ... import telemetry as _telemetry
         _telemetry.registry().counter(
             "pallas.select.%s.%s" % (name, impl)).inc()

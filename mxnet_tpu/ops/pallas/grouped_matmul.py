@@ -293,14 +293,29 @@ def _dw_kernel(offsets, group_of, tile_of, n_steps, lhs_ref, dy_ref, out_ref,
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(live & (offsets[group + 1] > offsets[group]))
-    def _():
-        x = lhs_ref[:]
-        mask = _row_mask(offsets, group, tile, tm, x.shape)
-        x = jnp.where(mask, x, jnp.zeros_like(x))
+    def add(x, dy):
         acc_ref[:] += lax.dot_general(
-            x, dy_ref[:], (((0,), (0,)), ((), ())),
+            x, dy, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    runs = live & (offsets[group + 1] > offsets[group])
+    whole = ((tile * tm >= offsets[group])
+             & ((tile + 1) * tm <= offsets[group + 1]))
+
+    @pl.when(runs & whole)
+    def _():
+        add(lhs_ref[:], dy_ref[:])
+
+    # a tile a group's boundary crosses: both operands' other rows are
+    # masked, so that a row no group owns may hold anything (the expert
+    # layer's gathers leave rows past the live ones unwritten)
+    @pl.when(runs & jnp.logical_not(whole))
+    def _():
+        x, dy = lhs_ref[:], dy_ref[:]
+        add(jnp.where(_row_mask(offsets, group, tile, tm, x.shape), x,
+                      jnp.zeros_like(x)),
+            jnp.where(_row_mask(offsets, group, tile, tm, dy.shape), dy,
+                      jnp.zeros_like(dy)))
 
     @pl.when(live & closes)
     def _():

@@ -14,9 +14,11 @@ absent).  Two layers live here:
   weight times its token's, read by token as the dispatch's forward reads
   -- and whose residual, the down product's rows, carries a
   ``checkpoint_name`` (``SAVED_NAMES``) so that a rematerialised layer
-  keeps them.  No token is dropped whatever the imbalance, no
-  product is computed for a pair whose expert is not held (beyond a row
-  tile's rounding), and what the absent experts would have added is left
+  keeps them.  On the chip the dispatch's and the combine's row gathers
+  move only the held experts' pairs (``ops/pallas/moe_gather.py``, where
+  ``moe_gather.select`` says so).  No token is dropped whatever the
+  imbalance, no product is computed for a pair whose expert is not held
+  (beyond a row tile's rounding), and what the absent experts would have added is left
   out: with ``experts_held`` a chip's share of an expert-parallel layer,
   that partial result is what the chip has before the exchange.  The
   exchange itself is not written (ROADMAP R-m3).
@@ -35,6 +37,7 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops.pallas import moe_gather
 from ..ops.pallas.grouped_matmul import grouped_matmul
 from .sharding import constraint
 
@@ -114,65 +117,98 @@ def _sum_slots(slots, weights=None):
     return total
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _to_sorted(tokens, order, inverse, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 5))
+def _to_sorted(tokens, order, inverse, k, live=None, impl="fallback"):
     """tokens [S, E] -> the row of each (token, slot) pair in sorted order
-    [S k, E]: pair ``p`` is token ``p // k``."""
-    return tokens[order // k]
+    [S k, E]: pair ``p`` is token ``p // k``.  ``live`` int32 [1]: the pairs
+    whose expert is held, which sort first; with the kernels (``impl``, from
+    ``moe_gather.select``) only the blocks that hold them are written."""
+    if impl == "fallback":
+        return tokens[order // k]
+    return moe_gather.sorted_rows(tokens, order // k, live,
+                                  interpret=impl == "interpret")
 
 
-def _to_sorted_fwd(tokens, order, inverse, k):
-    return tokens[order // k], inverse
+def _to_sorted_fwd(tokens, order, inverse, k, live, impl):
+    return _to_sorted(tokens, order, inverse, k, live, impl), (inverse, live)
 
 
-def _to_sorted_bwd(k, inverse, g):
+def _to_sorted_bwd(k, impl, res, g):
     # the transpose of a permutation is a gather by its inverse, not a
     # scatter; a token's gradient is the sum over its slots: the combine's
     # forward with unit weights
-    return _sum_slots(_slots(g, inverse, k)).astype(g.dtype), None, None
+    inverse, live = res
+    if impl == "fallback":
+        d = _sum_slots(_slots(g, inverse, k))
+    else:
+        d = moe_gather.slot_sum(g, inverse, live, None, k,
+                                interpret=impl == "interpret")
+    return d.astype(g.dtype), None, None, None
 
 
 _to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
 
 
-@jax.custom_vjp
-def _combine(out, weights, order, inverse):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine(out, weights, order, inverse, live=None, impl="fallback"):
     """The sorted rows back at their tokens, weighted: ``y[s] = sum_j
     weights[s, j] * out[inverse[s k + j]]`` in float32, in ``out``'s dtype
     [S, E].  Its backward stays in the sorted rows' space (no ``[S, k, E]``
     gradient is built): the gradient of sorted row ``r`` is ``w[r] *
     g[token(r)]``, a gather from ``g`` [S, E] by the index the dispatch's
-    forward uses, and a weight's is its row's product with that."""
+    forward uses, and a weight's is its row's product with that.  With the
+    kernels both read only the first ``live`` rows."""
     k = weights.shape[1]
+    if impl != "fallback":
+        with jax.named_scope("moe.combine.sum"):
+            return moe_gather.slot_sum(out, inverse, live, weights, k,
+                                       interpret=impl == "interpret")
     with jax.named_scope("moe.combine.gather"):
         slots = _slots(out, inverse, k)
     with jax.named_scope("moe.combine.sum"):
         return _sum_slots(slots, weights).astype(out.dtype)
 
 
-def _combine_fwd(out, weights, order, inverse):
+def _combine_fwd(out, weights, order, inverse, live, impl):
     # the rows are named as the residual alone: a kept value that the
     # forward reads too gets a ``reduce_precision`` from ``jax.checkpoint``,
     # which behind a kernel is a copy of the rows
-    return _combine(out, weights, order, inverse), (
-        checkpoint_name(out, SAVED_NAMES[0]), weights, order, inverse)
+    return _combine(out, weights, order, inverse, live, impl), (
+        checkpoint_name(out, SAVED_NAMES[0]), weights, order, inverse, live)
 
 
-def _combine_bwd(res, g):
-    out, weights, order, inverse = res
+def _combine_bwd(impl, res, g):
+    out, weights, order, inverse, live = res
+    k = weights.shape[1]
     with jax.named_scope("moe.combine.gather"):
-        g_rows = _to_sorted(g, order, inverse, weights.shape[1]
-                            ).astype(jnp.float32)
-        w_rows = weights.reshape(-1)[order]
+        # the weights to the sorted rows, and below their gradients back:
+        # each a permutation by a sort on the other index (a gather of P
+        # scalars costs XLA about seven times a sort's time on the chip);
+        # the keys are distinct, and a stable sort would hold an iota of P
+        # from the forward on (the step's peak)
+        w_rows = lax.sort_key_val(inverse, weights.reshape(-1),
+                                  is_stable=False)[1]
+        if impl == "fallback":
+            g_rows = _to_sorted(g, order, inverse, k, live, impl
+                                ).astype(jnp.float32)
+        else:
+            d_out, d_w_rows = moe_gather.sorted_rows_grad(
+                g, order // k, live, out, w_rows,
+                interpret=impl == "interpret")
     with jax.named_scope("moe.combine.sum"):
-        # rows past the groups are zero in ``out``: an absent expert's slot
-        # gets a zero weight gradient, and what ``d_out`` holds there is
-        # never read (the grouped product's tail and row mask)
-        d_out = (w_rows[:, None] * g_rows).astype(out.dtype)
-        d_w_rows = jnp.sum(out.astype(jnp.float32) * g_rows, axis=-1)
-        d_weights = d_w_rows[inverse].reshape(weights.shape
-                                              ).astype(weights.dtype)
-    return d_out, d_weights, None, None
+        if impl == "fallback":
+            # rows past the groups are zero in ``out``: an absent expert's
+            # slot gets a zero weight gradient, and what ``d_out`` holds
+            # there is never read (the grouped product's tail and row mask)
+            d_out = (w_rows[:, None] * g_rows).astype(out.dtype)
+            d_w_rows = jnp.sum(out.astype(jnp.float32) * g_rows, axis=-1)
+        d_w = lax.sort_key_val(order, d_w_rows, is_stable=False)[1]
+        if impl != "fallback":
+            # the kernel leaves ``d_w`` unwritten past the live rows: an
+            # absent expert's slot gets a zero weight gradient
+            d_w = jnp.where(inverse < live[0], d_w, 0.0)
+        d_weights = d_w.reshape(weights.shape).astype(weights.dtype)
+    return d_out, d_weights, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -228,8 +264,10 @@ def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
             sizes = jnp.sum(
                 key[:, None] == jnp.arange(n_held, dtype=jnp.int32),
                 axis=0, dtype=jnp.int32)
+            live = jnp.sum(sizes)[None]
+        impl = moe_gather.select(S, k, E, x.dtype)
         with jax.named_scope("moe.dispatch.gather"):
-            rows = _to_sorted(tokens, order, inverse, k)
+            rows = _to_sorted(tokens, order, inverse, k, live, impl)
     with jax.named_scope("moe.experts"):
         up = grouped_matmul(rows, w_up, sizes).astype(jnp.float32)
         if w_gate is not None:
@@ -240,8 +278,8 @@ def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
         out = grouped_matmul(up, w_down, sizes)
     with jax.named_scope("moe.combine"):
         # rows past the groups are zero, so an absent expert's slot adds 0
-        y = _combine(out, weights, order, inverse)
-    return y.reshape(B, T, E), aux, jnp.sum(sizes).astype(jnp.float32)
+        y = _combine(out, weights, order, inverse, live, impl)
+    return y.reshape(B, T, E), aux, live[0].astype(jnp.float32)
 
 
 def moe_layer(x, gate_w, w_up, w_down, ep_axis="ep", capacity_factor=1.25,

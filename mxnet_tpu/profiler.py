@@ -695,7 +695,8 @@ DEVICE_SCOPES = (
     "flash_fwd", "flash_dq", "flash_dkv", "rmsnorm_fwd", "xent_fwd",
     "xent_bwd", "ssm_scan_fwd", "ssm_scan_bwd", "gmm_fwd", "gmm_dx",
     "gmm_dw", "int8_matmul", "int8_matmul_dequant", "short_conv_fwd",
-    "short_conv_bwd",
+    "short_conv_bwd", "moe_pack", "moe_gather_rows", "moe_gather_grad",
+    "moe_slot_sum",
     # the Gluon step (`gluon/contrib/fused.py`)
     "forward", "backward", "update",
     # the mesh (`parallel/`)

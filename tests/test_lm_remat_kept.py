@@ -109,35 +109,54 @@ def test_wqkvs_product_alone_is_kept_by_its_name(monkeypatch):
 
 def expert_work():
     """Of the jaxpr of ``jax.grad(model.loss)`` of the gated expert toy:
-    the grouped product's forward kernels and the gathers whose operand has
-    the down product's shape, ``[P, E]``."""
+    the grouped product's forward kernels, the row-gather kernels by name
+    (``ops/pallas/moe_gather.py``) and the lax gathers whose operand has the
+    down product's shape, ``[P, E]``."""
     model, p, x, y = build("experts")
     jaxpr = jax.make_jaxpr(jax.grad(model.loss))(p, x, y).jaxpr
-    seen = {"gmm_fwd": 0, "gathers_of_P_E_rows": 0}
+    seen = {"gmm_fwd": 0, "moe_gather_rows": 0, "moe_slot_sum": 0,
+            "moe_gather_grad": 0, "gathers_of_P_E_rows": 0}
     for eqn in equations(jaxpr):
         if eqn.primitive.name == "pallas_call":
-            seen["gmm_fwd"] += eqn.params["name"] == "gmm_fwd"
+            name = eqn.params["name"]
+            if name in seen:
+                seen[name] += 1
         elif eqn.primitive.name == "gather":
             seen["gathers_of_P_E_rows"] += (
                 eqn.invars[0].aval.shape == (B * T * 2, E))
     return seen
 
 
+@pytest.mark.parametrize("gathers", ["kernels", "lax"])
 @pytest.mark.parametrize("kept,products", [
     ("KEPT", 5), ("down_rows", 5), ("nothing", 6)])
 def test_the_experts_down_rows_are_kept_and_gathered_once_a_pass(
-        kept, products, monkeypatch):
+        kept, products, gathers, monkeypatch):
     """One body stands for both expert layers.  The forward runs gate, up
     and down; the backward's body re-makes gate and up, and the down
     product too unless its rows are kept by name.  Kept or not, only two
-    gathers read ``[P, E]`` rows: the combine's forward (``out[inverse]``)
-    and the dispatch's transpose (``g[inverse]``) -- the second forward
-    gathers none for the combine, whose backward reads ``g`` by token."""
+    gathers read ``[P, E]`` rows by slot: the combine's forward and the
+    dispatch's transpose -- the second forward gathers none for the
+    combine, whose backward reads ``g`` by token.  With the row-gather
+    kernels these are ``moe_slot_sum``, each pass gathers the dispatch's
+    rows once (``moe_gather_rows``: the forward and the second forward) and
+    the combine's backward is ``moe_gather_grad``; no lax gather is left.
+    The lax forms (the CPU, a mesh, a buffer that fits the fast memory)
+    gather ``out[inverse]`` and ``g[inverse]``."""
     monkeypatch.setenv("MXTPU_PALLAS", "interpret")
     monkeypatch.setattr(transformer, "KEPT", {
         "KEPT": transformer.KEPT, "nothing": (),
         "down_rows": moe.SAVED_NAMES}[kept])
-    assert expert_work() == {"gmm_fwd": products, "gathers_of_P_E_rows": 2}
+    if gathers == "lax":
+        monkeypatch.setattr(moe.moe_gather, "select",
+                            lambda *shape: "fallback")
+        assert expert_work() == {
+            "gmm_fwd": products, "moe_gather_rows": 0, "moe_slot_sum": 0,
+            "moe_gather_grad": 0, "gathers_of_P_E_rows": 2}
+    else:
+        assert expert_work() == {
+            "gmm_fwd": products, "moe_gather_rows": 2, "moe_slot_sum": 2,
+            "moe_gather_grad": 1, "gathers_of_P_E_rows": 0}
 
 
 def test_the_expert_lms_gradient_builds_no_token_major_slots():

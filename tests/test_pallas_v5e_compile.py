@@ -213,17 +213,24 @@ LM_CASES = [
                  {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1,
                   "ssm_scan_fwd": 1, "ssm_scan_bwd": 1},
                  id="jamba2_3b_train_mamba_and_attention"),
+    # an expert layer's row gathers: its sources packed (the tokens twice,
+    # ``g``, the down rows, the dispatch's gradient), the dispatch's rows
+    # made again in the second forward, a slot sum each way, one backward
     pytest.param("smallthinker-21b-l4-e16", "pretrain_b1_s16384_ep4", 2,
                  {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1,
                   "flash_fwd_window": 1, "flash_dq_window": 1,
-                  "flash_dkv_window": 1},
+                  "flash_dkv_window": 1, "moe_pack": 10,
+                  "moe_gather_rows": 4, "moe_slot_sum": 4,
+                  "moe_gather_grad": 2},
                  id="smallthinker_train_s16k_full_and_window"),
     # the convolution's forward runs again in the second forward: its
     # result is not kept, ``in_proj``'s output is
     pytest.param("lfm2-8b-a1b-l5-e8", "pretrain_b4_s8192_ep4",
                  ("conv", "attention"),
                  {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1,
-                  "short_conv_fwd": 2, "short_conv_bwd": 1},
+                  "short_conv_fwd": 2, "short_conv_bwd": 1, "moe_pack": 5,
+                  "moe_gather_rows": 2, "moe_slot_sum": 2,
+                  "moe_gather_grad": 1},
                  id="lfm2_8b_train_s8k_conv_and_attention"),
 ]
 
@@ -388,3 +395,38 @@ def test_short_conv_kernels_compile_for_v5e(one_chip, B, T, D, K, dtype,
     for name in ("short_conv_fwd", "short_conv_bwd"):
         assert re.search(r"%%%s(\.\d+)? = [^\n]*tpu_custom_call" % name,
                          text), name
+
+
+# (tokens, slots, width): the three expert cells' [P, E] and [S, E]
+@pytest.mark.parametrize("S,k,E", [
+    pytest.param(32768, 4, 2048, id="lfm2_8b_train_s8k"),
+    pytest.param(16384, 6, 2560, id="smallthinker_train_s16k"),
+    pytest.param(4096, 6, 2048, id="dsv2_lite_train")])
+def test_row_gather_kernels_compile_for_v5e(one_chip, S, k, E):
+    """The expert layer's four row-gather kernels at the cells' shapes:
+    a row of a packed source as one DMA, the scalar-prefetched index
+    vectors (``[P]`` int32), the blocks' scratch within VMEM."""
+    mg = importlib.import_module("mxnet_tpu.ops.pallas.moe_gather")
+    assert mg._takes(S, k, E, jnp.bfloat16, False)
+    P = S * k
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def gathers(tokens, idx, inverse, live, out, weights, g, w_rows):
+        return (mg.sorted_rows(tokens, idx, live),
+                mg.slot_sum(out, inverse, live, weights, k),
+                mg.sorted_rows_grad(g, idx, live, out, w_rows))
+
+    text = jax.jit(gathers).trace(
+        spec((S, E), jnp.bfloat16), spec((P,), jnp.int32),
+        spec((P,), jnp.int32), spec((1,), jnp.int32),
+        spec((P, E), jnp.bfloat16), spec((S, k), jnp.float32),
+        spec((S, E), jnp.bfloat16), spec((P,), jnp.float32)).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    found = {}
+    for name in re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*custom-call\([^\n]*"
+                           r"tpu_custom_call", text):
+        found[name] = found.get(name, 0) + 1
+    assert found == {"moe_pack": 3, "moe_gather_rows": 1, "moe_slot_sum": 1,
+                     "moe_gather_grad": 1}, found
