@@ -79,7 +79,7 @@ def mla_mixer(bp, h, cfg, attend):
         k_nope, v = jnp.split(
             proj(c, bp["wkv_b"]).reshape(B, T, H, dn + dv), [dn], axis=-1)
     with jax.named_scope("mla.rope"):
-        cos, sin = rope_tables(cfg, dr, T)
+        cos, sin = rope_tables(cfg.rope, dr, T)
         q_pe = rotate_half(q_pe, cos, sin)
         k_pe = rotate_half(k_pe[:, :, None, :], cos, sin)
         q = jnp.concatenate([q_nope, q_pe], axis=-1)

@@ -47,7 +47,7 @@ from ..parallel.moe import expert_layer, moe_layer, route
 from ..ops.pallas.layers import _rmsnorm_lax
 from .mamba import IN_PROJ_NAME, mamba_mixer
 from .mla import mla_leaf_shapes, mla_mixer
-from .rope import rope_tables, rotate_half
+from .rope import RopeSetting, rope_tables, rotate_half
 from .short_conv import IN_PROJ_NAME as _SCONV_IN_PROJ
 from .short_conv import short_conv_init, short_conv_mixer
 
@@ -145,11 +145,21 @@ class TransformerConfig:
     # The fused-QKV mixer's attention setting, one entry a layer (() is 0
     # everywhere): ``attn_windows`` the window ``W`` (query t sees the keys
     # ``0 <= t - j < W``; 0: the whole causal prefix), ``attn_rope`` 1
-    # where q and k are rotated (rotate-half over the whole head, positions
-    # 0 .. T - 1).  Both are static: a run of equal layers is cut where
-    # either changes.  Training path only (ROADMAP R-m4).
+    # where q and k are rotated by the model-wide ``rope_*`` keys, or a
+    # dict of `models/rope.py::RopeSetting`'s fields, a rotary term of the
+    # layer's own (positions 0 .. T - 1; each entry is held as a
+    # ``RopeSetting``, or None where nothing turns), ``attn_heads`` the
+    # layer's query heads (0: ``n_heads``; with it the mixer's leaves stack
+    # one stack a head count, under ``attn<H>.``).  All are static: a run
+    # of equal layers is cut where one changes.  Training path only
+    # (ROADMAP R-m4).
     attn_windows: tuple = ()
     attn_rope: tuple = ()
+    attn_heads: tuple = ()
+    # "per_head": head j's attention output times ``sigmoid(h . w_j)``
+    # before ``wo`` (``h`` the layer's normed input, ``head_gate`` [E, H]
+    # a layer): the head-wise output gate of arXiv:2505.06708
+    attn_gate: str = "none"
     # RMSNorm over each head of q and of k (scales ``q_norm_scale``,
     # ``k_norm_scale``), before the rotary term
     qk_norm: bool = False
@@ -167,32 +177,40 @@ class TransformerConfig:
     moe_router: str = "softmax"
     moe_expert_bias: bool = False
     moe_bias_rate: float = 1e-3
+    # the routed experts' weighted sum times this (the shared expert's not)
+    moe_routed_scale: float = 1.0
 
     def __post_init__(self):
-        # a configuration read from JSON brings a list
+        # a configuration read from JSON brings a list, and a rotary
+        # setting of a layer's own a dict
         for key in ("layer_types", "mlp_types", "experts_held",
-                    "attn_windows", "attn_rope"):
+                    "attn_windows", "attn_rope", "attn_heads"):
             object.__setattr__(self, key, tuple(getattr(self, key)))
+        object.__setattr__(self, "attn_rope", tuple(
+            v if v is None or isinstance(v, RopeSetting)
+            else RopeSetting(**v) if isinstance(v, dict)
+            else self.rope if v else None for v in self.attn_rope))
         if not self.ssm_dt_rank:
             object.__setattr__(self, "ssm_dt_rank", -(-self.d_model // 16))
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.n_heads)
-        for key in ("attn_windows", "attn_rope"):
+        for key in ("attn_windows", "attn_rope", "attn_heads"):
             per_layer = getattr(self, key)
             if not per_layer:
                 continue
             assert len(per_layer) == self.n_layers, \
                 "%s names %d layers, n_layers is %d" % (
                     key, len(per_layer), self.n_layers)
-            assert all(int(v) == v and v >= 0 for v in per_layer), key
+            assert key == "attn_rope" or all(
+                int(v) == v and v >= 0 for v in per_layer), key
             assert self.attention == "mha" or not any(per_layer), \
                 "%s beside latent attention: its rotary part and its " \
                 "scores are `models/mla.py`'s own" % key
             for v, kind in zip(per_layer, self.layer_types):
                 assert not v or kind == "attention", \
-                    "%s names a %s layer: a window or a rotary term is " \
-                    "an attention layer's" % (
+                    "%s names a %s layer: a window, a rotary term or a " \
+                    "head count is an attention layer's" % (
                         key, {"mamba": "Mamba"}.get(kind, kind))
         if self.layer_types:
             assert len(self.layer_types) == self.n_layers, \
@@ -212,7 +230,12 @@ class TransformerConfig:
             assert all(0 <= e < self.n_experts for e in self.experts_held)
         assert self.attention in ("mha", "mla")
         assert self.mlp in _ACT
-        assert self.n_heads % self.kv_heads == 0
+        assert all(h % self.kv_heads == 0
+                   for h in (self.n_heads,) + self.attn_heads)
+        assert self.attn_gate in ("none", "per_head"), self.attn_gate
+        assert self.attn_gate == "none" or self.attention == "mha", \
+            "attn_gate beside latent attention: not built"
+        assert self.moe_routed_scale > 0
         assert self.has_experts or not self.moe_router_pre_attention, \
             "moe_router_pre_attention without an expert layer"
         assert self.moe_router in ("softmax", "sigmoid")
@@ -237,36 +260,70 @@ class TransformerConfig:
     def n_held(self):
         return len(self.experts_held) or self.n_experts
 
+    @property
+    def rope(self):
+        """The model-wide rotary term, from the ``rope_*`` keys."""
+        return RopeSetting(self.rope_theta, self.rope_factor,
+                           self.rope_orig_len, self.rope_beta_fast,
+                           self.rope_beta_slow, self.rope_mscale,
+                           self.rope_mscale_all_dim)
+
     def layer_keys(self):
-        """One key a layer, ``(mixer kind, (window, rotary), MLP kind)``:
-        what the layers of one scanned run have in common."""
+        """One key a layer, ``(mixer kind, (window, rotary, query heads),
+        MLP kind)``: what the layers of one scanned run have in common.
+        ``rotary`` is the layer's ``RopeSetting``, or None."""
         L = self.n_layers
         mixers = self.layer_types or ("attention",) * L
         mlps = self.mlp_types or ("moe" if self.use_moe else "dense",) * L
         windows = self.attn_windows or (0,) * L
-        ropes = self.attn_rope or (0,) * L
-        return [(mixers[i], (int(windows[i]), bool(ropes[i])), mlps[i])
+        ropes = self.attn_rope or (None,) * L
+        heads = self.attn_heads or (0,) * L
+        return [(mixers[i], (int(windows[i]), ropes[i],
+                             heads[i] or self.n_heads), mlps[i])
                 for i in range(L)]
+
+    def stacks_of(self, key):
+        """The two stacks a layer of ``key`` reads beside ``blocks.``: its
+        mixer kind's -- with ``attn_heads`` a fused-QKV layer's is its head
+        count's, ``attention<H>`` -- and its MLP kind's."""
+        mixer, (_window, _rope, heads), mlp = key
+        if self.attn_heads and mixer == "attention":
+            mixer = "attention%d" % heads
+        return mixer, mlp
+
+    def attn_stacks(self):
+        """``[(kind, prefix, query heads, layers)]``: the fused-QKV mixer's
+        stacks -- under ``blocks.`` (every layer) or, with ``layer_types``,
+        ``attn.``; with ``attn_heads`` one a head count, under
+        ``attn<H>.``."""
+        keys = [k for k in self.layer_keys() if k[0] == "attention"]
+        if not self.attn_heads:
+            return [("attention", "attn." if self.layer_types else "blocks.",
+                     self.n_heads, len(keys))]
+        counts = sorted({k[1][2] for k in keys})
+        return [("attention%d" % h, "attn%d." % h, h,
+                 sum(k[1][2] == h for k in keys)) for h in counts]
 
     def layer_runs(self):
         """``[(key, lo, hi, own)]``: maximal runs of layers of one key
         (``layer_keys``), as a range over all layers and, in ``own``, over
-        the layers of the run's mixer kind and of its MLP kind (the index
-        into the ``attn.`` / ``ssm.`` and ``dense.`` / ``moe.`` stacks)."""
-        return _runs(self.layer_keys())
+        the layers of each stack the run reads beside ``blocks.``
+        (``stacks_of``: the index into the ``attn.`` / ``attn<H>.`` /
+        ``ssm.`` / ``sconv.`` and ``dense.`` / ``moe.`` stacks)."""
+        return _runs(self.layer_keys(), self.stacks_of)
 
 
-def _runs(keys):
+def _runs(keys, stacks_of):
     """Maximal runs of equal entries of ``keys`` (see ``layer_runs``)."""
     runs, seen = [], {}
     for i, key in enumerate(keys):
-        mixer, _setting, mlp = key
+        kinds = stacks_of(key)
         if runs and runs[-1][0] == key:
             runs[-1][2] = i + 1
         else:
             runs.append([key, i, i + 1,
-                         {kind: seen.get(kind, 0) for kind in (mixer, mlp)}])
-        for kind in (mixer, mlp):
+                         {kind: seen.get(kind, 0) for kind in kinds}])
+        for kind in kinds:
             seen[kind] = seen.get(kind, 0) + 1
     return [(key, lo, hi, {kind: (at, at + hi - lo)
                            for kind, at in own.items()})
@@ -367,13 +424,10 @@ class TransformerLM:
     def init(self, rng) -> dict:
         cfg = self.cfg
         L, E, F = cfg.n_layers, cfg.d_model, cfg.d_ff
-        HD = cfg.n_heads * cfg.head_dim
-        QKV = HD + 2 * cfg.kv_heads * cfg.head_dim
+        D, KV = cfg.head_dim, cfg.kv_heads
         dt = jnp.dtype(cfg.dtype)
         keys = jax.random.split(rng, 8)
         # with layer_types the mixers' leaves stack over their own layers
-        n_attn = cfg.layer_types.count("attention") if cfg.layer_types else L
-        attn = "attn." if cfg.layer_types else "blocks."
         n_ssm, n_conv = (cfg.layer_types.count(kind)
                          for kind in ("mamba", "conv"))
 
@@ -395,13 +449,18 @@ class TransformerLM:
                     norm(jax.random.fold_in(keys[1], i), (L,) + shape,
                          fan_in))
         else:
-            p[attn + "wqkv"] = norm(keys[1], (n_attn, E, QKV), E)
-            p[attn + "wo"] = norm(keys[2], (n_attn, HD, E), HD)
-            if cfg.qk_norm:
-                p[attn + "q_norm_scale"] = jnp.ones((n_attn, cfg.head_dim),
-                                                    dt)
-                p[attn + "k_norm_scale"] = jnp.ones((n_attn, cfg.head_dim),
-                                                    dt)
+            for _kind, attn, H, n_attn in cfg.attn_stacks():
+                kq, ko, kg = ((keys[1], keys[2], keys[7]) if not cfg.attn_heads
+                              else (jax.random.fold_in(k, H)
+                                    for k in (keys[1], keys[2], keys[7])))
+                p[attn + "wqkv"] = norm(kq, (n_attn, E, (H + 2 * KV) * D), E)
+                p[attn + "wo"] = norm(ko, (n_attn, H * D, E), H * D)
+                if cfg.qk_norm:
+                    p[attn + "q_norm_scale"] = jnp.ones((n_attn, D), dt)
+                    p[attn + "k_norm_scale"] = jnp.ones((n_attn, D), dt)
+                if cfg.attn_gate == "per_head":
+                    p[attn + "head_gate"] = norm(
+                        jax.random.fold_in(kg, 300), (n_attn, E, H), E)
         if not cfg.tie_embeddings:
             p["unembed"] = norm(keys[3], (E, cfg.vocab_size), E)
         if n_ssm:
@@ -482,24 +541,26 @@ class TransformerLM:
                          cfg.moe_renormalize, (B, T), scoring=cfg.moe_router,
                          bias=bp.get("expert_bias"))
 
-    def _qkv(self, bp, h, rope=False):
+    def _qkv(self, bp, h, rope=None):
         """The fused projection of ``h`` [B, T, E], split into heads:
-        q [B, T, H, D] and k, v [B, T, KV, D]; with ``rope`` q and k turned
+        q [B, T, H, D] and k, v [B, T, KV, D] (``H`` the layer's, as its
+        ``wqkv`` has it); with ``rope`` (a ``RopeSetting``) q and k turned
         by their positions 0 .. T - 1.  Each is named (``QKV_NAME``) heads
         first, [B, heads, T, D], as the flash kernels take it, q and k
         after the rotation: a rematerialised layer that keeps the name
-        hands the backward kernels the stored value as it lies (PERF.md §6,
-        PR 33)."""
+        hands the backward kernels the stored value as it lies (PERF.md
+        §6)."""
         cfg = self.cfg
         B, T, _ = h.shape
-        H, D, KV = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+        D, KV = cfg.head_dim, cfg.kv_heads
         with jax.named_scope("attn.qkv"):
             qkv = jnp.einsum("bte,ef->btf", h, bp["wqkv"],
                              preferred_element_type=jnp.float32
                              ).astype(h.dtype)
             qkv = constraint(qkv, "dp", "sp", "tp")
+        H = qkv.shape[-1] // D - 2 * KV
 
-        tables = rope_tables(cfg, D, T) if rope else None
+        tables = rope_tables(rope, rope.dims(D), T) if rope else None
 
         def heads(x, n, turn=False, norm=None):
             x = x.reshape(B, T, n, D)
@@ -526,15 +587,16 @@ class TransformerLM:
                               bp["wo"], preferred_element_type=jnp.float32
                               ).astype(attn.dtype)
 
-    def _self_attention(self, bp, h, use_ring=False, window=0, rope=False):
+    def _self_attention(self, bp, h, use_ring=False, window=0, rope=None):
         """The mixer of training and prefill: causal self-attention over
         ``h``, over a ``window`` of it where that is not 0, q and k rotated
-        with ``rope``; its state is the layer's ``(k, v)``, for the page
+        with ``rope``, each head's output gated where the layer has a
+        ``head_gate``; its state is the layer's ``(k, v)``, for the page
         write."""
         cfg = self.cfg
         B, T, _ = h.shape
-        H, KV = cfg.n_heads, cfg.kv_heads
         q, k, v = self._qkv(bp, h, rope)
+        H, KV = q.shape[2], cfg.kv_heads
         kh, vh = k, v
         if KV != H:
             # Shared key/value heads are broadcast to their query heads
@@ -546,8 +608,21 @@ class TransformerLM:
             with jax.named_scope("attn.kv_broadcast"):
                 kh = jnp.repeat(k, H // KV, axis=2)
                 vh = jnp.repeat(v, H // KV, axis=2)
-        return self._attn_out(bp, self._attend(q, kh, vh, None, use_ring,
-                                               window or None)), (k, v)
+        o = self._attend(q, kh, vh, None, use_ring, window or None)
+        if "head_gate" in bp:
+            o = self._head_gate(bp, h, o)
+        return self._attn_out(bp, o), (k, v)
+
+    def _head_gate(self, bp, h, o):
+        """The per-head output gate (``attn_gate``): head ``j`` of ``o``
+        [B, T, H, D] times ``sigmoid(h . head_gate[:, j])``, ``h`` [B, T,
+        E] the layer's normed input; the gate and the product in
+        float32."""
+        with jax.named_scope("attn.gate"):
+            g = jax.nn.sigmoid(jnp.einsum(
+                "bte,eh->bth", h, bp["head_gate"],
+                preferred_element_type=jnp.float32))
+            return (o.astype(jnp.float32) * g[..., None]).astype(o.dtype)
 
     def _attend(self, q, k, v, scale=None, use_ring=False, window=None):
         """Causal attention of q, k [B, T, H, D] and v [B, T, H, Dv] by
@@ -620,6 +695,8 @@ class TransformerLM:
             assert cfg.moe_router == "softmax" and not cfg.moe_expert_bias, \
                 "the capacity dispatch over ep with a sigmoid router or an " \
                 "expert bias: its router is the softmax"
+            assert cfg.moe_routed_scale == 1, \
+                "the capacity dispatch over ep with a routed scale: not built"
             ff, aux = moe_layer(h, bp["gate"], bp["moe_up"], bp["moe_down"],
                                 top_k=cfg.moe_top_k,
                                 renormalize=cfg.moe_renormalize,
@@ -634,7 +711,7 @@ class TransformerLM:
                 bp.get("moe_gate"), top_k=cfg.moe_top_k,
                 experts_held=cfg.experts_held or None,
                 renormalize=cfg.moe_renormalize, act=_ACT[cfg.mlp],
-                routing=routing)
+                routing=routing, routed_scale=cfg.moe_routed_scale)
         if "shared_up" in bp:
             with jax.named_scope("moe.shared"):
                 ff = ff + self._mlp(h, bp["shared_up"], bp["shared_down"],
@@ -710,6 +787,11 @@ class TransformerLM:
                 "paged decode does not support per-layer windows or a "
                 "rotary term yet: the pool gives every layer every page "
                 "and a decode step knows no position (ROADMAP R-m4)")
+        if cfg.attn_heads or cfg.attn_gate != "none":
+            raise NotImplementedError(
+                "paged decode does not support query heads set by layer or "
+                "a per-head output gate yet: the pool holds one head count "
+                "for every layer and decode has no gate (ROADMAP R-m4)")
         if (cfg.kv_heads != cfg.n_heads or cfg.mlp != "gelu"
                 or cfg.tie_embeddings):
             raise NotImplementedError(
@@ -721,7 +803,8 @@ class TransformerLM:
         cfg = self.cfg
         if (cfg.layer_types or cfg.kv_heads != cfg.n_heads
                 or cfg.attention == "mla" or any(cfg.attn_windows)
-                or any(cfg.attn_rope) or cfg.qk_norm):
+                or any(cfg.attn_rope) or cfg.qk_norm or cfg.attn_heads
+                or cfg.attn_gate != "none"):
             self._refuse_serving()
         shape = (cfg.n_layers, num_pages, page_size, cfg.n_heads,
                  cfg.head_dim)
@@ -861,7 +944,7 @@ class TransformerLM:
                 assert not use_ring, "latent attention over sp: not built"
                 mix, scope = self._mla, "attn"
             else:
-                window, rope = setting
+                window, rope, _heads = setting
                 mix, scope = functools.partial(
                     self._self_attention, use_ring=use_ring, window=window,
                     rope=rope), "attn"
@@ -891,25 +974,24 @@ class TransformerLM:
                  else jnp.float32(0.0))
         # one scan a run of equal layers, over that run's slice of the
         # common stack and of its mixer's and its MLP kind's own
+        prefixes = [("attention", "attn."), ("mamba", "ssm."),
+                    ("conv", "sconv."), ("dense", "dense."), ("moe", "moe.")]
+        if cfg.attn_heads:
+            prefixes += [stack[:2] for stack in cfg.attn_stacks()]
         own = {kind: {k.split(".", 1)[1]: v for k, v in params.items()
                       if k.startswith(prefix)}
-               for kind, prefix in (("attention", "attn."),
-                                    ("mamba", "ssm."),
-                                    ("conv", "sconv."),
-                                    ("dense", "dense."),
-                                    ("moe", "moe."))}
+               for kind, prefix in prefixes}
         loads = []
-        for (mixer, setting, mlp), lo, hi, at in cfg.layer_runs():
+        for key, lo, hi, at in cfg.layer_runs():
+            mixer, setting, mlp = key
             if mixer == "attention" and cfg.attention == "mha":
-                counter("lm.attn.%s.%s.%d" % (
-                    "window" if setting[0] else "full",
-                    "rope" if setting[1] else "nope", hi - lo)).inc()
+                self._count_attention(setting, hi - lo)
             # what is a layer's lies under the layer's own scopes; what is
             # left here is the run's slice of the stacks and the scan's own
             # traffic (a layer's leaves in, the kept values out)
             with jax.named_scope("layers"):
                 run = {k: v[lo:hi] for k, v in stacked.items()}
-                for kind in (mixer, mlp):
+                for kind in cfg.stacks_of(key):
                     klo, khi = at[kind]
                     run.update({k: v[klo:khi]
                                 for k, v in own[kind].items()})
@@ -923,6 +1005,27 @@ class TransformerLM:
         if with_load:
             return carry, jnp.concatenate(loads)
         return carry
+
+    def _count_attention(self, setting, layers):
+        """Trace-time counts of a run of ``layers`` fused-QKV layers of
+        ``setting``: its mask and rotary term (rotary columns of the head,
+        YaRN or plain), and where the configuration sets them its query
+        heads and its output gate."""
+        from .. import telemetry as _telemetry
+        cfg = self.cfg
+        counter = _telemetry.registry().counter
+        window, rope, heads = setting
+        mask = "window" if window else "full"
+        counter("lm.attn.%s.%s.%d" % (mask, "rope" if rope else "nope",
+                                      layers)).inc()
+        if cfg.attn_heads:
+            counter("lm.attn.heads.%d.%d" % (heads, layers)).inc()
+        if cfg.attn_gate != "none":
+            counter("lm.attn.gate.%s.%d" % (cfg.attn_gate, layers)).inc()
+        if rope:
+            counter("lm.rope.%s.%dof%d.%s" % (
+                mask, rope.dims(cfg.head_dim), cfg.head_dim,
+                "yarn" if rope.factor > 1 else "plain")).inc(layers)
 
     def held_slot_share(self, params, tokens):
         """Share of the (token, slot) pairs of all expert layers that the

@@ -216,7 +216,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
                  experts_held=None, renormalize=True, act=None,
-                 routing=None):
+                 routing=None, routed_scale=1.0):
     """The held experts' part of a routed feed-forward, no token dropped.
 
     x: [B, T, E]; router_w: [E, n] over all ``n`` experts; w_up (and
@@ -227,7 +227,8 @@ def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
     for the gated one.  ``routing`` is :func:`route`'s result where the
     router did not read the rows dispatched here (one placed before
     attention reads the layer's input, and has run by now); None: the
-    router reads ``x``.  Returns ``(y [B, T, E], aux, held)``: ``y_t = sum
+    router reads ``x``.  ``routed_scale`` multiplies every weight, and so
+    the routed sum.  Returns ``(y [B, T, E], aux, held)``: ``y_t = sum
     over the slots of t whose expert is held of w * expert(x_t)``, the router's
     balance term (over all ``n``, so every share computes it alike), and
     how many of the ``B T k`` pairs landed on held experts."""
@@ -252,6 +253,10 @@ def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
         with jax.named_scope("moe.route"):
             routing = route(tokens, router_w, k, renormalize, (B, T))
     weights, experts, aux = routing
+    if routed_scale != 1:
+        reg.counter("moe.routed_scale.%g" % routed_scale).inc()
+        with jax.named_scope("moe.route"):
+            weights = weights * routed_scale
     with jax.named_scope("moe.dispatch"):
         with jax.named_scope("moe.dispatch.sort"):
             # a pair's key is its expert's place in the stack; an absent

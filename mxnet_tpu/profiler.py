@@ -681,7 +681,7 @@ DEVICE_SCOPES = (
     "embed", "layers", "norm", "residual", "head", "loss", "optimizer",
     # the attention half: the container, then its leaves
     "attn", "attn.qkv", "attn.qk_norm", "attn.rope", "attn.kv_broadcast",
-    "attn.core", "attn.out",
+    "attn.core", "attn.gate", "attn.out",
     "mla.q", "mla.kv_a", "mla.kv_b", "mla.rope",
     "ssm", "ssm.in_proj", "conv", "ssm.x_proj", "ssm.dt", "scan",
     "ssm.out_proj",

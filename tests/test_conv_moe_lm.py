@@ -277,9 +277,9 @@ def test_layer_types_beside_mlp_types_are_cut_together():
     """Runs are cut on (mixer, setting, MLP): the published layers 1-5 are
     three runs, each indexing its mixer's and its MLP kind's own stacks."""
     cfg = TransformerConfig(**TOY)
-    conv_dense = ("conv", (0, False), "dense")
-    attn_moe = ("attention", (0, True), "moe")
-    conv_moe = ("conv", (0, False), "moe")
+    conv_dense = ("conv", (0, None, 4), "dense")
+    attn_moe = ("attention", (0, cfg.rope, 4), "moe")
+    conv_moe = ("conv", (0, None, 4), "moe")
     assert cfg.layer_runs() == [
         (conv_dense, 0, 1, {"conv": (0, 1), "dense": (0, 1)}),
         (attn_moe, 1, 2, {"attention": (0, 1), "moe": (0, 1)}),
@@ -294,7 +294,8 @@ def test_layer_types_beside_mlp_types_are_cut_together():
     assert moved == {"lm.layers.conv.dense.1x1": 1,
                      "lm.layers.attention.moe.1x1": 1,
                      "lm.layers.conv.moe.3x3": 1,
-                     "lm.attn.full.rope.1": 1}
+                     "lm.attn.full.rope.1": 1,
+                     "lm.rope.full.16of16.plain": 1}
     assert [(e.params["length"], e.params["unroll"]) for e in jaxpr.eqns
             if e.primitive.name == "scan"] == [(1, 1), (1, 1), (3, 3)]
 
@@ -308,7 +309,7 @@ def test_qk_norm_is_over_each_head_before_the_rotary_term():
     bp = ref.layer_leaves(p, ref.kinds_of(TOY), 1)
     bp["q_norm_scale"] = 2.0 * bp["q_norm_scale"]
     h = 3.0 * jax.random.normal(jax.random.PRNGKey(5), (1, 24, 64))
-    q, k, v = model._qkv(bp, h, rope=True)
+    q, k, v = model._qkv(bp, h, rope=cfg.rope)
     rms = lambda a: jnp.sqrt(jnp.mean(a * a, axis=-1))
     np.testing.assert_allclose(rms(q), 2.0, rtol=1e-3)
     np.testing.assert_allclose(rms(k), 1.0, rtol=1e-3)

@@ -191,7 +191,7 @@ def _lm_step_text(config_name, batch=2, seq=64):
 
 @pytest.mark.parametrize("config_name", [
     "pythia-1.4b-sizes", "jamba2-3b-l14", "deepseek-v2-lite-l5-e32",
-    "smallthinker-21b-l4-e16", "lfm2-8b-a1b-l5-e8"])
+    "smallthinker-21b-l4-e16", "lfm2-8b-a1b-l5-e8", "laguna-s2.1-l5-e16"])
 def test_every_op_of_the_lm_step_lies_under_a_leaf(config_name):
     """Compile the family's train step at its rehearsal size: every fusion,
     product, custom call, sort, gather and scatter of the module resolves to
@@ -216,6 +216,9 @@ def test_every_op_of_the_lm_step_lies_under_a_leaf(config_name):
         # the per-head norms, the bias's own update
         want |= {"sconv", "sconv.in_proj", "sconv.out_proj", "attn.qk_norm",
                  "moe.bias_update"}
+    if config_name == "laguna-s2.1-l5-e16":
+        # the per-head output gate and the partial rotary term
+        want |= {"attn.gate", "attn.rope"}
     assert want <= leaves, sorted(leaves)
     passes = {module.place(n)[1] for n in held}
     assert {"fwd", "bwd", "remat", "-"} <= passes, passes

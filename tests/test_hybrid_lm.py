@@ -35,8 +35,8 @@ def tokens(batch=2, seq=24, vocab=128, seed=1):
 
 def test_runs_of_layers_and_their_index_into_each_stack():
     cfg = TransformerConfig(**HYBRID)
-    att, ssm = ("attention", (0, False), "dense"), ("mamba", (0, False),
-                                                    "dense")
+    att, ssm = (("attention", (0, None, 4), "dense"),
+                ("mamba", (0, None, 4), "dense"))
     assert cfg.layer_runs() == [
         (ssm, 0, 1, {"mamba": (0, 1), "dense": (0, 1)}),
         (att, 1, 3, {"attention": (0, 2), "dense": (1, 3)}),

@@ -56,7 +56,7 @@ def test_yarn_check_values_of_the_published_rope_scaling():
     assert inv[16] == pytest.approx(
         base[16] * (1 - 6 / 13) + base[16] / 40 * (6 / 13), rel=1e-12)
     # the factor on cos and sin is mscale / mscale_all_dim = 1
-    cos, sin = rope.rope_tables(cfg, cfg.qk_rope_head_dim, 8)
+    cos, sin = rope.rope_tables(cfg.rope, cfg.qk_rope_head_dim, 8)
     np.testing.assert_allclose(cos[0], 1.0)
     np.testing.assert_allclose(sin[1, :32], np.sin(inv), rtol=1e-5)
     np.testing.assert_allclose(ref.yarn_inv_freq(FULL), inv, rtol=1e-12)
@@ -74,7 +74,7 @@ def test_no_scaling_is_plain_rope_and_a_plain_scale():
 
 def test_rotate_half_turns_each_pair_by_its_angle_and_keeps_the_norm():
     cfg = TransformerConfig(**TOY)
-    cos, sin = rope.rope_tables(cfg, cfg.qk_rope_head_dim, 5)
+    cos, sin = rope.rope_tables(cfg.rope, cfg.qk_rope_head_dim, 5)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 5, 3, 8))
     y = rope.rotate_half(x, cos, sin)
     np.testing.assert_allclose(y[:, 0], x[:, 0], rtol=1e-6)  # position 0

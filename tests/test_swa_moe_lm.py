@@ -58,7 +58,7 @@ def test_the_toy_is_the_published_layer_at_toy_widths():
     cfg = TransformerConfig(**TOY)
     assert cfg.n_heads * cfg.head_dim == 128 != cfg.d_model == 64
     assert cfg.attn_windows == (0, 16, 16, 16)
-    assert cfg.attn_rope == (0, 1, 1, 1)
+    assert cfg.attn_rope == (None, cfg.rope, cfg.rope, cfg.rope)
     assert cfg.mlp == "reglu" and cfg.moe_router_pre_attention
     model = TransformerLM(cfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -204,8 +204,8 @@ def test_the_published_layout_is_cut_into_runs_of_one_and_three(periods):
     cfg = TransformerConfig(**dict(
         TOY, n_layers=L, attn_windows=[0, 16, 16, 16] * periods,
         attn_rope=[0, 1, 1, 1] * periods))
-    full = ("attention", (0, False), "moe")
-    window = ("attention", (16, True), "moe")
+    full = ("attention", (0, None, 4), "moe")
+    window = ("attention", (16, cfg.rope, 4), "moe")
     runs = cfg.layer_runs()
     assert [(key, lo, hi) for key, lo, hi, _own in runs] == [
         (key, 4 * i + lo, 4 * i + hi) for i in range(periods)
@@ -225,6 +225,7 @@ def test_the_published_layout_is_cut_into_runs_of_one_and_three(periods):
                      "lm.layers.attention.3x3": periods,
                      "lm.attn.full.nope.1": periods,
                      "lm.attn.window.rope.3": periods,
+                     "lm.rope.window.32of32.plain": 3 * periods,
                      # counted where a body is traced: the second period's
                      # runs share the first one's two bodies
                      "moe.router.pre_attention": 2}
@@ -238,14 +239,14 @@ def test_equal_settings_everywhere_are_one_run_as_before():
     cfg = TransformerConfig(**dict(TOY, attn_windows=[8] * 4,
                                    attn_rope=[1] * 4))
     assert [(k[1], lo, hi) for k, lo, hi, _o in cfg.layer_runs()] == [
-        ((8, True), 0, 4)]
+        ((8, cfg.rope, 4), 0, 4)]
 
 
 def _mixer(window, rope, T):
     model = TransformerLM(TransformerConfig(**TOY))
     bp = block_leaves(bench_weights.init(TOY, 3), 1)
-    return lambda h: model._self_attention(bp, h, window=window,
-                                           rope=rope)[0]
+    return lambda h: model._self_attention(
+        bp, h, window=window, rope=model.cfg.rope if rope else None)[0]
 
 
 def test_a_window_layers_term_is_relative_and_a_full_layer_has_none():
@@ -285,14 +286,14 @@ def test_qkv_names_q_and_k_after_the_rotation():
     bp = block_leaves(bench_weights.init(TOY, 3), 1)
     h = jax.random.normal(jax.random.PRNGKey(5), (1, 24, 64))
     q0, k0, v0 = model._qkv(bp, h)
-    q1, k1, v1 = model._qkv(bp, h, rope=True)
-    cos, sin = rope_module.rope_tables(cfg, 32, 24)
+    q1, k1, v1 = model._qkv(bp, h, rope=cfg.rope)
+    cos, sin = rope_module.rope_tables(cfg.rope, 32, 24)
     np.testing.assert_allclose(q1, rope_module.rotate_half(q0, cos, sin),
                                rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(k1, rope_module.rotate_half(k0, cos, sin),
                                rtol=1e-6, atol=1e-6)
     np.testing.assert_array_equal(v1, v0)
-    text = str(jax.make_jaxpr(lambda h: model._qkv(bp, h, rope=True))(h))
+    text = str(jax.make_jaxpr(lambda h: model._qkv(bp, h, rope=cfg.rope))(h))
     named = [ln for ln in text.splitlines() if QKV_NAME in ln]
     assert len(named) == 3
     # the reference's tables are the program's
