@@ -22,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["RopeSetting", "yarn_inv_freq", "yarn_mscale",
-           "yarn_correction_range", "rope_tables", "rotate_half"]
+           "yarn_correction_range", "rope_tables", "rope_lane_tables",
+           "rotate_half"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,10 +83,8 @@ def yarn_inv_freq(dim, base, factor, orig_len, beta_fast, beta_slow):
     return extra / factor * ramp + extra * (1.0 - ramp)
 
 
-def rope_tables(setting, dim, T):
-    """``(cos, sin)`` [T, dim] float32 for positions 0 .. T - 1 of a rotary
-    part ``dim`` wide, each pair's angle in columns ``i`` and ``i + dim /
-    2``, from ``setting`` (a :class:`RopeSetting`)."""
+def _tables(setting, dim, T):
+    """``rope_tables`` in float64 numpy."""
     s = setting
     inv = yarn_inv_freq(dim, s.theta, s.factor, s.orig_len, s.beta_fast,
                         s.beta_slow)
@@ -93,8 +92,31 @@ def rope_tables(setting, dim, T):
     angle = np.concatenate([angle, angle], axis=-1)
     m = (yarn_mscale(s.factor, s.mscale)
          / yarn_mscale(s.factor, s.mscale_all_dim))
-    return (jnp.asarray(np.cos(angle) * m, jnp.float32),
-            jnp.asarray(np.sin(angle) * m, jnp.float32))
+    return np.cos(angle) * m, np.sin(angle) * m
+
+
+def rope_tables(setting, dim, T):
+    """``(cos, sin)`` [T, dim] float32 for positions 0 .. T - 1 of a rotary
+    part ``dim`` wide, each pair's angle in columns ``i`` and ``i + dim /
+    2``, from ``setting`` (a :class:`RopeSetting`)."""
+    cos, sin = _tables(setting, dim, T)
+    return jnp.asarray(cos, jnp.float32), jnp.asarray(sin, jnp.float32)
+
+
+def rope_lane_tables(setting, head_dim, T, lanes=128):
+    """``(cos, sin)`` [T, lanes] float32, the tables of the Pallas kernels
+    (`ops/pallas/rope.py`) for heads ``head_dim`` wide: ``rope_tables``'s
+    values over a head's columns, the sine negated on the first half of the
+    rotary part (rotate_half's ``-b``), cosine 1 and sine 0 past it, the
+    head's columns repeated for each head a row of ``lanes`` holds."""
+    rot = setting.dims(head_dim)
+    c, s = _tables(setting, rot, T)
+    s[:, :rot // 2] *= -1
+    cos, sin = np.ones((T, head_dim)), np.zeros((T, head_dim))
+    cos[:, :rot], sin[:, :rot] = c, s
+    reps = (1, lanes // head_dim)
+    return (jnp.asarray(np.tile(cos, reps), jnp.float32),
+            jnp.asarray(np.tile(sin, reps), jnp.float32))
 
 
 def rotate_half(x, cos, sin):

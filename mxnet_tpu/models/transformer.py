@@ -38,6 +38,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.pallas import (flash_attention, fused_rmsnorm,
                           fused_softmax_xent)
+from ..ops.pallas import rope as pallas_rope
 from ..ops.pallas.flash_attention import SAVED_NAMES as _FLASH_KEPT
 from ..ops.pallas.selective_scan import SAVED_NAMES as _SCAN_KEPT
 from ..parallel.sharding import ShardingRules, constraint, PartitionSpec as P
@@ -47,7 +48,7 @@ from ..parallel.moe import expert_layer, moe_layer, route
 from ..ops.pallas.layers import _rmsnorm_lax
 from .mamba import IN_PROJ_NAME, mamba_mixer
 from .mla import mla_leaf_shapes, mla_mixer
-from .rope import RopeSetting, rope_tables, rotate_half
+from .rope import RopeSetting, rope_lane_tables, rope_tables, rotate_half
 from .short_conv import IN_PROJ_NAME as _SCONV_IN_PROJ
 from .short_conv import short_conv_init, short_conv_mixer
 
@@ -549,7 +550,8 @@ class TransformerLM:
         first, [B, heads, T, D], as the flash kernels take it, q and k
         after the rotation: a rematerialised layer that keeps the name
         hands the backward kernels the stored value as it lies (PERF.md
-        §6)."""
+        §6).  Where `ops/pallas/rope.py::select` answers the kernels, q and
+        k turn there (``_qkv_kernel``), else by ``rotate_half``."""
         cfg = self.cfg
         B, T, _ = h.shape
         D, KV = cfg.head_dim, cfg.kv_heads
@@ -559,6 +561,10 @@ class TransformerLM:
                              ).astype(h.dtype)
             qkv = constraint(qkv, "dp", "sp", "tp")
         H = qkv.shape[-1] // D - 2 * KV
+        interpret = pallas_rope.select(D, rope.dims(D), (H, KV)) if rope \
+            else None
+        if interpret is not None:
+            return self._qkv_kernel(bp, qkv, H, rope, interpret)
 
         tables = rope_tables(rope, rope.dims(D), T) if rope else None
 
@@ -578,6 +584,40 @@ class TransformerLM:
             return (heads(q, H, rope, bp.get("q_norm_scale")),
                     heads(k, KV, rope, bp.get("k_norm_scale")),
                     heads(v, KV))
+
+    def _qkv_kernel(self, bp, qkv, H, rope, interpret):
+        """``_qkv``'s split with q and k turned by the Pallas kernels
+        (`ops/pallas/rope.py`): the projection's rows ``qkv`` [B, T, F]
+        straight to q, k, v heads first in one call, or, where q and k are
+        normed first, the normed q and k each in one call and v as
+        ``_qkv`` has it.  Named and handed on as ``_qkv`` hands them."""
+        cfg = self.cfg
+        B, T, _ = qkv.shape
+        D, KV = cfg.head_dim, cfg.kv_heads
+        rot = rope.dims(D)
+        cos, sin = rope_lane_tables(rope, D, T)
+
+        def turned(x, *groups):
+            with jax.named_scope("attn.rope"):
+                return pallas_rope.rope_heads(x, cos, sin, groups, D, rot,
+                                              interpret)
+
+        def normed(x, n, scale):
+            with jax.named_scope("attn.qk_norm"):
+                return _rmsnorm_lax(x.reshape(B, T, n, D),
+                                    scale.astype(x.dtype), cfg.norm_eps
+                                    ).reshape(B, T, n * D)
+
+        with jax.named_scope("attn.qkv"):
+            if "q_norm_scale" not in bp:
+                q, k, v = turned(qkv, (H, True), (KV, True), (KV, False))
+            else:
+                q, k, v = jnp.split(qkv, [H * D, (H + KV) * D], axis=-1)
+                (q,) = turned(normed(q, H, bp["q_norm_scale"]), (H, True))
+                (k,) = turned(normed(k, KV, bp["k_norm_scale"]), (KV, True))
+                v = v.reshape(B, T, KV, D).transpose(0, 2, 1, 3)
+            return tuple(checkpoint_name(x, QKV_NAME).transpose(0, 2, 1, 3)
+                         for x in (q, k, v))
 
     def _attn_out(self, bp, attn):
         """The output projection of ``attn`` [B, T, H, D]."""

@@ -696,7 +696,7 @@ DEVICE_SCOPES = (
     "xent_bwd", "ssm_scan_fwd", "ssm_scan_bwd", "gmm_fwd", "gmm_dx",
     "gmm_dw", "int8_matmul", "int8_matmul_dequant", "short_conv_fwd",
     "short_conv_bwd", "moe_pack", "moe_gather_rows", "moe_gather_grad",
-    "moe_slot_sum",
+    "moe_slot_sum", "rope_fwd", "rope_bwd",
     # the Gluon step (`gluon/contrib/fused.py`)
     "forward", "backward", "update",
     # the mesh (`parallel/`)

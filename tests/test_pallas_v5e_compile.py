@@ -215,22 +215,25 @@ LM_CASES = [
                  id="jamba2_3b_train_mamba_and_attention"),
     # an expert layer's row gathers: its sources packed (the tokens twice,
     # ``g``, the down rows, the dispatch's gradient), the dispatch's rows
-    # made again in the second forward, a slot sum each way, one backward
+    # made again in the second forward, a slot sum each way, one backward;
+    # the window layer's q, k and v in one rotary call each way, its
+    # result kept (``QKV_NAME``)
     pytest.param("smallthinker-21b-l4-e16", "pretrain_b1_s16384_ep4", 2,
                  {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1,
                   "flash_fwd_window": 1, "flash_dq_window": 1,
                   "flash_dkv_window": 1, "moe_pack": 10,
                   "moe_gather_rows": 4, "moe_slot_sum": 4,
-                  "moe_gather_grad": 2},
+                  "moe_gather_grad": 2, "rope_fwd": 1, "rope_bwd": 1},
                  id="smallthinker_train_s16k_full_and_window"),
     # the convolution's forward runs again in the second forward: its
-    # result is not kept, ``in_proj``'s output is
+    # result is not kept, ``in_proj``'s output is; the normed q and k a
+    # rotary call each
     pytest.param("lfm2-8b-a1b-l5-e8", "pretrain_b4_s8192_ep4",
                  ("conv", "attention"),
                  {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1,
                   "short_conv_fwd": 2, "short_conv_bwd": 1, "moe_pack": 5,
                   "moe_gather_rows": 2, "moe_slot_sum": 2,
-                  "moe_gather_grad": 1},
+                  "moe_gather_grad": 1, "rope_fwd": 2, "rope_bwd": 2},
                  id="lfm2_8b_train_s8k_conv_and_attention"),
 ]
 
@@ -430,3 +433,46 @@ def test_row_gather_kernels_compile_for_v5e(one_chip, S, k, E):
         found[name] = found.get(name, 0) + 1
     assert found == {"moe_pack": 3, "moe_gather_rows": 1, "moe_slot_sum": 1,
                      "moe_gather_grad": 1}, found
+
+
+# (B, T, q heads, k/v heads, D, rotary columns, q and k normed apart)
+@pytest.mark.parametrize("B,T,H,KV,D,rot,apart", [
+    pytest.param(1, 8192, 72, 8, 128, 128, False,
+                 id="laguna_s21_train_s8k_window"),
+    pytest.param(1, 8192, 48, 8, 128, 64, False,
+                 id="laguna_s21_train_s8k_full_yarn"),
+    pytest.param(1, 16384, 28, 4, 128, 128, False,
+                 id="smallthinker_train_s16k"),
+    pytest.param(4, 8192, 32, 8, 64, 64, True, id="lfm2_8b_train_s8k_d64"),
+    pytest.param(2, 1100, 8, 8, 128, 128, False,
+                 id="ragged_1100_partial_last_block"),
+])
+def test_rope_kernels_compile_for_v5e(one_chip, B, T, H, KV, D, rot, apart):
+    """The rotary kernels at the three cells' shapes and a length off the
+    position block: the groups' clamped index maps, the lane rolls, a
+    64-wide head two to a row, the scoped VMEM."""
+    rp = importlib.import_module("mxnet_tpu.ops.pallas.rope")
+    tables = jax.ShapeDtypeStruct((T, 128), jnp.float32, sharding=one_chip)
+    if apart:                      # q and k normed first: a call each
+        calls = [((H, True),), ((KV, True),)]
+    else:
+        calls = [((H, True), (KV, True), (KV, False))]
+
+    def fwd_and_grads(cos, sin, *xs):
+        outs = []
+        for x, groups in zip(xs, calls):
+            ys, vjp = jax.vjp(lambda x, groups=groups: rp.rope_heads(
+                x, cos, sin, groups, D, rot), x)
+            outs.append((ys, vjp(ys)))
+        return outs
+
+    xs = [jax.ShapeDtypeStruct((B, T, sum(n for n, _ in g) * D),
+                               jnp.bfloat16, sharding=one_chip)
+          for g in calls]
+    text = jax.jit(fwd_and_grads).trace(tables, tables, *xs).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    found = {}
+    for name in re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*custom-call\([^\n]*"
+                           r"tpu_custom_call", text):
+        found[name] = found.get(name, 0) + 1
+    assert found == {"rope_fwd": len(calls), "rope_bwd": len(calls)}, found
