@@ -4,18 +4,25 @@
 group after group (``group_sizes`` [G], summing to at most M), and group
 ``g``'s rows are multiplied by its own matrix ``rhs[g]`` [K, N] -- the
 product of an expert layer whose (token, slot) pairs were sorted by expert
-(`parallel/moe.py`).  Rows past the last group come out zero (what
-``lax.ragged_dot`` states).  Design:
+(`parallel/moe.py`).  What the rows past the last group hold is the
+caller's choice, ``rows_past_groups``: ``"zero"`` (the default, what
+``lax.ragged_dot`` states) or ``"unread"``, for a caller that reads no such
+row of the result or of ``dx``.  Design:
 
 * the row axis is cut into tiles of ``tm`` rows; a tile a group boundary
   crosses is visited once for each group it holds, under a row mask, so
   group sizes need not be multiples of anything and a group may be empty;
 * which (group, row tile) a grid step works on is computed in lax from
   ``group_sizes`` (``_schedule``) and handed to the kernels as scalar
-  prefetch; the step axis has the static length ``M / tm + G`` (every tile
-  once, one more visit a boundary, the rows past the last group as a tile
-  run of their own), steps past the live ones repeat the last block index
-  (no DMA) and are skipped;
+  prefetch; the step axis is as long as the live steps (a grid bound the
+  schedule computes, at most ``M / tm + G``: every tile once, one more
+  visit a boundary), so no grid step is spent on a tile nothing computes;
+* rows past the last group: with ``"zero"`` forward and ``dx`` append them
+  as a run of tiles of their own, each fetched and written as zero; with
+  ``"unread"`` there is no such run -- the rows of the tile that holds the
+  last group's end still come out zero, the tiles past it are neither read
+  nor written (the expert layer with its row-gather kernels, whose
+  consumers read only the groups' rows);
 * forward and ``dx`` are one kernel (``dx = dy . rhs[g]^T`` contracts the
   matrices' last dimension: the block is fetched as stored and the product
   is ``a . b^T``, nothing is transposed in HBM); the whole contraction and
@@ -29,7 +36,9 @@ product of an expert layer whose (token, slot) pairs were sorted by expert
   result takes ``lhs``'s dtype, ``dW`` takes ``rhs``'s;
 * every ``pallas_call`` has a ``name=`` and a ``jax.named_scope``
   (``gmm_fwd``, ``gmm_dx``, ``gmm_dw``); at trace time the counter
-  ``pallas.gmm.tile.<kernel>.<tm>x<tk>x<tn>`` records the tiles;
+  ``pallas.gmm.tile.<kernel>.<tm>x<tk>x<tn>`` records the tiles and
+  ``pallas.gmm.rows_past_groups.<zero|unread>`` each forward and ``dx``,
+  ``pallas.gmm.grid.live`` each kernel whose step axis is the live steps;
 * called with ``interpret=None`` the entry point asks
   ``common.kernel_impl``: the kernels, or ``grouped_matmul_lax`` (the same
   mathematics group by group in lax, which GSPMD shards freely);
@@ -155,10 +164,11 @@ def _schedule(group_sizes, M, tm, tail, visit_empty):
     """The step axis.  Returns ``(offsets, group_of, tile_of, n_steps)``:
     ``offsets`` [G' + 1] the first row of each group and the end of the
     last, ``group_of`` / ``tile_of`` [S] the group and the row tile of each
-    step, ``n_steps`` [1] how many steps are live.  ``tail`` appends the
-    rows past the last group as a group of their own (index G), so that
-    every row tile is visited; ``visit_empty`` gives an empty group one
-    (fully masked) step."""
+    step (their static length ``S`` is the most there can be; the grid
+    takes the first ``n_steps``), ``n_steps`` [1] how many steps are live.
+    ``tail`` appends the rows past the last group as a group of their own
+    (index G), so that every row tile is visited; ``visit_empty`` gives an
+    empty group one (fully masked) step."""
     sizes = group_sizes.astype(jnp.int32)
     tiles_m = M // tm
     if tail:
@@ -180,6 +190,14 @@ def _schedule(group_sizes, M, tm, tail, visit_empty):
     tile_of = jnp.clip(tile_of, 0, tiles_m - 1).astype(jnp.int32)
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
     return offsets, group_of, tile_of, n_steps[None].astype(jnp.int32)
+
+
+def _steps(meta):
+    """The grid's step extent, a bound computed on the device: the live
+    steps, at least one (with none, that step is skipped)."""
+    from ... import telemetry as _telemetry
+    _telemetry.registry().counter("pallas.gmm.grid.live").inc()
+    return jnp.maximum(meta[3][0], 1)
 
 
 def _row_mask(offsets, group, tile, tm, shape):
@@ -204,7 +222,8 @@ def _gmm_kernel(offsets, group_of, tile_of, n_steps, lhs_ref, rhs_ref,
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # the rows past the last group are a run of tiles with nothing to add
+    # with zeros past the groups those rows are a run of tiles (group G)
+    # with nothing to add
     @pl.when(live & (group < n_groups))
     def _():
         contract = ((1,), (1,)) if transpose_rhs else ((1,), (0,))
@@ -222,19 +241,25 @@ def _gmm_kernel(offsets, group_of, tile_of, n_steps, lhs_ref, rhs_ref,
         out_ref[:] = jnp.where(mask, acc_ref[:], rest).astype(out_ref.dtype)
 
 
-def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
-    """lhs [M, K] by rhs [G, K, N] (``transpose_rhs``: [G, N, K])."""
+def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret, rows_past_groups):
+    """lhs [M, K] by rhs [G, K, N] (``transpose_rhs``: [G, N, K]); rows
+    past the last group zero, or with ``"unread"`` zero only in the tile
+    that holds the last group's end."""
+    from ... import telemetry as _telemetry
     M, K = lhs.shape
     G = rhs.shape[0]
     N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     kernel = "gmm_dx" if transpose_rhs else "gmm_fwd"
     tm, tk, tn = _choose_tiles(kernel, M, K, N, jnp.dtype(lhs.dtype).itemsize)
+    _telemetry.registry().counter(
+        "pallas.gmm.rows_past_groups." + rows_past_groups).inc()
     pad = _round_up(M, tm) - M
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     Mp = M + pad
-    meta = _schedule(group_sizes, Mp, tm, tail=True, visit_empty=False)
-    S = meta[1].shape[0]
+    meta = _schedule(group_sizes, Mp, tm, tail=rows_past_groups == "zero",
+                     visit_empty=False)
+    S = _steps(meta)
 
     def lhs_map(n_i, s, k_i, offsets, group_of, tile_of, n_steps):
         return tile_of[s], k_i
@@ -333,7 +358,7 @@ def _dw(lhs, dy, group_sizes, n_groups, dtype, interpret):
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
         dy = jnp.pad(dy, ((0, pad), (0, 0)))
     meta = _schedule(group_sizes, M + pad, tm, tail=False, visit_empty=True)
-    S = meta[1].shape[0]
+    S = _steps(meta)
 
     def lhs_map(k_i, n_i, s, offsets, group_of, tile_of, n_steps):
         return tile_of[s], k_i
@@ -373,19 +398,19 @@ def _dw(lhs, dy, group_sizes, n_groups, dtype, interpret):
 # custom_vjp wrapper and the entry point
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _grouped(lhs, rhs, group_sizes, interpret):
-    return _gmm(lhs, rhs, group_sizes, False, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped(lhs, rhs, group_sizes, interpret, rows_past_groups):
+    return _gmm(lhs, rhs, group_sizes, False, interpret, rows_past_groups)
 
 
-def _grouped_fwd(lhs, rhs, group_sizes, interpret):
-    return (_gmm(lhs, rhs, group_sizes, False, interpret),
+def _grouped_fwd(lhs, rhs, group_sizes, interpret, rows_past_groups):
+    return (_gmm(lhs, rhs, group_sizes, False, interpret, rows_past_groups),
             (lhs, rhs, group_sizes))
 
 
-def _grouped_bwd(interpret, res, dy):
+def _grouped_bwd(interpret, rows_past_groups, res, dy):
     lhs, rhs, group_sizes = res
-    dx = _gmm(dy, rhs, group_sizes, True, interpret)
+    dx = _gmm(dy, rhs, group_sizes, True, interpret, rows_past_groups)
     dw = _dw(lhs, dy, group_sizes, rhs.shape[0], rhs.dtype, interpret)
     return dx, dw, None
 
@@ -393,17 +418,23 @@ def _grouped_bwd(interpret, res, dy):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, interpret=None):
+def grouped_matmul(lhs, rhs, group_sizes, interpret=None,
+                   rows_past_groups="zero"):
     """``out[r] = lhs[r] . rhs[g(r)]`` for lhs [M, K], rhs [G, K, N] and
     ``group_sizes`` [G] (rows ``sum(sizes[:g]) .. sum(sizes[:g + 1])`` are
-    group ``g``'s); rows past the last group are zero.  Differentiable in
-    ``lhs`` and ``rhs``.  With ``interpret=None`` the implementation is
-    ``common.kernel_impl``'s answer (under a mesh the lax form: the
-    kernels have no ``shard_map`` wrapper); ``interpret=True`` forces the
-    kernels through the Pallas interpreter."""
+    group ``g``'s).  Rows past the last group are zero, in the result and
+    in ``dx``; with ``rows_past_groups="unread"`` they are unspecified (the
+    caller reads none of them), and the kernels neither read nor write the
+    row tiles past the last group.  Differentiable in ``lhs`` and ``rhs``.
+    With ``interpret=None`` the implementation is ``common.kernel_impl``'s
+    answer (under a mesh the lax form, zeros past the groups either way:
+    the kernels have no ``shard_map`` wrapper); ``interpret=True`` forces
+    the kernels through the Pallas interpreter."""
+    assert rows_past_groups in ("zero", "unread"), rows_past_groups
     if interpret is None:
         impl = kernel_impl("grouped_matmul")
         if impl in ("fallback", "sharded"):
             return grouped_matmul_lax(lhs, rhs, group_sizes)
         interpret = impl == "interpret"
-    return _grouped(lhs, rhs, group_sizes.astype(jnp.int32), interpret)
+    return _grouped(lhs, rhs, group_sizes.astype(jnp.int32), interpret,
+                    rows_past_groups)

@@ -42,13 +42,17 @@ Design:
   while the next is gathered;
 * **what is left unwritten.**  Rows in a block past the last live one are
   never written (packed sources, sorted rows, ``d_out``; ``d_w``'s entries
-  past the live count).  Every consumer skips or masks them: the grouped
-  product computes no tile past its groups and masks its rows at a group's
-  boundary (its ``dW`` masks both operands), the slot sum reads only live
-  rows, ``d_w`` moved back to the slots is replaced by 0 where a slot's
-  row is past the live count.  The rows of the block that holds the last
-  live one are all written (past ``live`` with the rows their indices
-  name);
+  past the live count), and with these kernels the layer asks the grouped
+  product for ``rows_past_groups="unread"``: its result and its ``dx`` are
+  not written in the row tiles past the last group either.  Every consumer
+  skips or masks them: the grouped product computes no tile past its
+  groups and masks its rows at a group's boundary (its ``dW`` masks both
+  operands), the slot sum reads only live rows, ``d_w`` moved back to the
+  slots is replaced by 0 where a slot's row is past the live count; what
+  the layer does to whole buffers in between (the activation, the add of
+  the two ``dx``) lands in rows nothing reads.  The rows of the block that
+  holds the last live one are all written (past ``live`` with the rows
+  their indices name);
 * blocks of up to ``_ROWS`` sorted rows and ``_TOKENS`` tokens (the largest
   rung of 512 ... 8 that divides the count); sums in float32; results take
   the source's type;

@@ -273,16 +273,21 @@ def expert_layer(x, router_w, w_up, w_down, w_gate=None, top_k=1,
         impl = moe_gather.select(S, k, E, x.dtype)
         with jax.named_scope("moe.dispatch.gather"):
             rows = _to_sorted(tokens, order, inverse, k, live, impl)
+    # the lax combine sums every slot's row, so an absent expert's rows
+    # must be zero; the kernels read only the first ``live`` rows of each
+    # buffer, so the products leave the rest unwritten
+    product = functools.partial(
+        grouped_matmul, group_sizes=sizes,
+        rows_past_groups="zero" if impl == "fallback" else "unread")
     with jax.named_scope("moe.experts"):
-        up = grouped_matmul(rows, w_up, sizes).astype(jnp.float32)
+        up = product(rows, w_up).astype(jnp.float32)
         if w_gate is not None:
-            gate = grouped_matmul(rows, w_gate, sizes).astype(jnp.float32)
+            gate = product(rows, w_gate).astype(jnp.float32)
         with jax.named_scope("moe.experts.act"):
             up = (act(up) if w_gate is None else act(gate) * up
                   ).astype(x.dtype)
-        out = grouped_matmul(up, w_down, sizes)
+        out = product(up, w_down)
     with jax.named_scope("moe.combine"):
-        # rows past the groups are zero, so an absent expert's slot adds 0
         y = _combine(out, weights, order, inverse, live, impl)
     return y.reshape(B, T, E), aux, live[0].astype(jnp.float32)
 

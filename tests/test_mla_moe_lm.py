@@ -281,16 +281,25 @@ def test_flash_tiles_of_an_equal_width_call_are_what_they_were():
 
 
 # -- the grouped product ----------------------------------------------------
-@pytest.mark.parametrize("M,K,N,sizes,tm", [
+_GMM_SHAPES = [
     (40, 16, 24, [5, 0, 11, 7], 8),          # an empty group, a tail
     (37, 16, 24, [5, 0, 11, 7, 14], 8),      # rows off the tile, no tail
     (64, 256, 384, [0, 0, 64, 0], 16),       # one group takes all
     (64, 16, 8, [0, 0, 0, 0], 16),           # nothing live
     (24, 16, 8, [1, 23], 16),
     (96, 32, 16, [30, 31, 32], 512),         # one tile of 96 rows
-])
-def test_grouped_product_is_ragged_dot_forward_dx_and_dw(M, K, N, sizes, tm,
-                                                         monkeypatch):
+]
+
+
+@pytest.mark.parametrize("M,K,N,sizes,tm,rows_past_groups", [
+    pytest.param(*shape, rows, id="%d-%d-%d-sizes%d-%d%s" % (
+        *shape[:3], i, shape[4], "" if rows == "zero" else "-unread"))
+    for rows in ("zero", "unread") for i, shape in enumerate(_GMM_SHAPES)])
+def test_grouped_product_is_ragged_dot_forward_dx_and_dw(
+        M, K, N, sizes, tm, rows_past_groups, monkeypatch):
+    """``"zero"``: every row, those past the groups zero; ``"unread"``: the
+    groups' rows of the forward and of ``dx`` (the interpreter leaves a
+    tile no step writes NaN), and ``dW`` whole."""
     module = sys.modules["mxnet_tpu.ops.pallas.grouped_matmul"]
     monkeypatch.setattr(module, "_TM", tm)
     ks = jax.random.split(jax.random.PRNGKey(M + N), 3)
@@ -300,7 +309,8 @@ def test_grouped_product_is_ragged_dot_forward_dx_and_dw(M, K, N, sizes, tm,
     gs = jnp.asarray(sizes, jnp.int32)
 
     def kernel(x, w):
-        return grouped_matmul(x, w, gs, interpret=True)
+        return grouped_matmul(x, w, gs, interpret=True,
+                              rows_past_groups=rows_past_groups)
 
     def plain(x, w):
         return lax.ragged_dot(x, w, gs)
@@ -309,11 +319,22 @@ def test_grouped_product_is_ragged_dot_forward_dx_and_dw(M, K, N, sizes, tm,
         o, want = kernel(x, w), plain(x, w)
         g = jax.grad(lambda *a: (kernel(*a) * dy).sum(), (0, 1))(x, w)
         g_want = jax.grad(lambda *a: (plain(*a) * dy).sum(), (0, 1))(x, w)
-    np.testing.assert_allclose(o, want, rtol=1e-5, atol=1e-4)
     live = sum(sizes)
-    assert not np.asarray(o[live:]).any()        # rows past the groups
-    np.testing.assert_allclose(g[0], g_want[0], rtol=1e-5, atol=1e-4)
+    rows = M if rows_past_groups == "zero" else live
+    np.testing.assert_allclose(o[:rows], want[:rows], rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(g[0][:rows], g_want[0][:rows], rtol=1e-5,
+                               atol=1e-4)
     np.testing.assert_allclose(g[1], g_want[1], rtol=1e-5, atol=1e-4)
+    if rows_past_groups == "zero":
+        assert not np.asarray(o[live:]).any()    # rows past the groups
+    else:
+        # the tile that holds the last group's end is written, zeros past
+        # it; the tiles after it are not
+        tile = min(tm, -(-M // 8) * 8)
+        end = min(-(-live // tile) * tile, M)
+        for got in (o, g[0]):
+            assert not np.asarray(got[live:end]).any()
+            assert np.isnan(np.asarray(got[end:])).all()
     for i, n in enumerate(sizes):                # an empty group's dW
         if n == 0:
             assert not np.asarray(g[1][i]).any()
@@ -379,6 +400,17 @@ def test_schedule_visits_each_tile_once_more_a_boundary_and_skips_the_rest():
     assert list(zip(group_of.tolist()[:n], tile_of.tolist()[:n])) == [
         (0, 0), (2, 0), (2, 1), (3, 2), (4, 2), (4, 3), (4, 4)]
     assert set(zip(group_of.tolist()[n:], tile_of.tolist()[n:])) <= {(4, 4)}
+    # the forward's and dx's with the rows past the groups unread: no tail,
+    # tiles 3 and 4 never visited, the steps past the live ones repeat the
+    # last group's last tile
+    offsets, group_of, tile_of, n = module._schedule(
+        sizes, 40, 8, tail=False, visit_empty=False)
+    assert offsets.tolist() == [0, 5, 5, 16, 23]
+    assert len(group_of) == 40 // 8 + 4 - 1
+    n = int(n[0])
+    assert list(zip(group_of.tolist()[:n], tile_of.tolist()[:n])) == [
+        (0, 0), (2, 0), (2, 1), (3, 2)]
+    assert set(zip(group_of.tolist()[n:], tile_of.tolist()[n:])) == {(3, 2)}
     _, group_of, tile_of, n = module._schedule(
         sizes, 40, 8, tail=False, visit_empty=True)
     n = int(n[0])

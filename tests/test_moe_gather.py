@@ -218,3 +218,60 @@ def test_the_kernels_take_only_shapes_mosaic_can_tile(monkeypatch):
     assert mg.select(32768, 4, 2048, jnp.bfloat16) == "fallback"
     monkeypatch.setenv("MXTPU_PALLAS", "interpret")
     assert mg.select(64, 4, 32, jnp.float32) == "interpret"
+
+
+def test_the_products_rows_past_the_groups_are_read_by_nothing(monkeypatch):
+    """With the gathers' kernels the grouped products leave their rows past
+    the groups unwritten (``rows_past_groups="unread"``: NaN from the
+    interpreter) in the forward and in ``dx``; with the lax gathers, whose
+    combine sums every slot's row, they write them as zero.  Three of four
+    pairs on absent experts: ``y`` and the gradients of ``x``, the router
+    and the three matrices are finite and the lax layer's either way."""
+    S, k, E, held = 64, 4, 256, 2
+    x, _w, _e, w_up, w_gate, w_down, ids = layer_case(S, k, E, held,
+                                                      "random", seed=5)
+    router_w = (0.5 * jax.random.normal(jax.random.PRNGKey(7), (E, N))
+                ).astype(jnp.bfloat16)
+    probe = jax.random.normal(jax.random.PRNGKey(9), (1, S, E))
+
+    def loss(x, router_w, w_up, w_gate, w_down):
+        y, _aux, live = moe.expert_layer(x, router_w, w_up, w_down, w_gate,
+                                         top_k=k, experts_held=ids)
+        return jnp.sum(y.astype(jnp.float32) * probe), (y, live)
+
+    args = (x, router_w, w_up, w_gate, w_down)
+    reg = telemetry.registry()
+
+    def run():
+        names = ["pallas.gmm.rows_past_groups." + r
+                 for r in ("zero", "unread")]
+        before = [reg.counter(n).value for n in names]
+        (_value, (y, live)), g = grads(loss, args)
+        counted = tuple(reg.counter(n).value - b
+                        for n, b in zip(names, before))
+        return y, live, g, counted
+
+    monkeypatch.setenv("MXTPU_PALLAS", "off")
+    want_y, want_live, want, counted = run()
+    assert counted == (0, 0)                 # the lax product
+    assert 0 < float(want_live) <= S * k // 2
+    monkeypatch.setenv("MXTPU_PALLAS", "interpret")
+    kernels = run()
+    # the lax gathers with the kernels' products: the deepseek cell's path
+    monkeypatch.setattr(moe.moe_gather, "select", lambda *a: "fallback")
+    lax_gathers = run()
+    # three products, each a forward and a dx
+    assert kernels[3] == (0, 6) and lax_gathers[3] == (6, 0)
+    for y, live, got, _counted in (kernels, lax_gathers):
+        assert float(live) == float(want_live)
+        y = np.asarray(y, np.float32)
+        assert np.isfinite(y).all()
+        np.testing.assert_allclose(y, np.asarray(want_y, np.float32),
+                                   rtol=0, atol=2e-2 * np.abs(y).max())
+        for name, a, b in zip(("x", "router", "w_up", "w_gate", "w_down"),
+                              got, want):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert np.isfinite(a).all(), name
+            top = max(float(np.abs(b).max()), 1e-30)
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-2 * top,
+                                       err_msg=name)

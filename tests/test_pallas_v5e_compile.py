@@ -330,21 +330,34 @@ def test_flash_with_a_window_compiles_for_v5e(one_chip, T, W):
         assert name in text, name
 
 
-@pytest.mark.parametrize("M,K,N,G,tiles", [
+@pytest.mark.parametrize("M,K,N,G,tiles,rows_past_groups", [
     pytest.param(24576, 2048, 1408, 32,
                  {"gmm_fwd": (2048, 1408), "gmm_dx": (1408, 2048),
-                  "gmm_dw": (1024, 1408)}, id="dsv2_lite_gate_up"),
+                  "gmm_dw": (1024, 1408)}, "zero", id="dsv2_lite_gate_up"),
     pytest.param(24576, 1408, 2048, 32,
                  {"gmm_fwd": (1408, 2048), "gmm_dx": (2048, 1408),
-                  "gmm_dw": (1408, 1024)}, id="dsv2_lite_down"),
+                  "gmm_dw": (1408, 1024)}, "zero", id="dsv2_lite_down"),
     pytest.param(1000, 96, 40, 3,
                  {"gmm_fwd": (96, 40), "gmm_dx": (40, 96),
-                  "gmm_dw": (96, 40)}, id="rows_and_widths_off_the_tiling"),
+                  "gmm_dw": (96, 40)}, "zero",
+                 id="rows_and_widths_off_the_tiling"),
+    # the cells whose gathers are the kernels: no tail run
+    pytest.param(81920, 3072, 1024, 16,
+                 {"gmm_fwd": (3072, 1024), "gmm_dx": (1024, 3072),
+                  "gmm_dw": (1024, 1024)}, "unread",
+                 id="laguna_gate_up_rows_past_groups_unread"),
+    pytest.param(1000, 96, 40, 3,
+                 {"gmm_fwd": (96, 40), "gmm_dx": (40, 96),
+                  "gmm_dw": (96, 40)}, "unread",
+                 id="off_the_tiling_rows_past_groups_unread"),
 ])
-def test_grouped_product_kernels_compile_for_v5e(one_chip, M, K, N, G, tiles):
+def test_grouped_product_kernels_compile_for_v5e(one_chip, M, K, N, G, tiles,
+                                                rows_past_groups):
     """Forward, ``dx`` and ``dW`` of the grouped product at the expert
-    cell's two shapes (the whole contraction and output width one block in
-    forward and ``dx``), and at sizes nothing divides."""
+    cells' shapes (the whole contraction and output width one block in
+    forward and ``dx``), and at sizes nothing divides; each kernel's step
+    axis as long as its live steps, a bound computed on the device."""
+    from mxnet_tpu import telemetry
     gm = importlib.import_module("mxnet_tpu.ops.pallas.grouped_matmul")
     dtype = jnp.bfloat16 if M > 1000 else jnp.float32
     size = jnp.dtype(dtype).itemsize
@@ -358,15 +371,22 @@ def test_grouped_product_kernels_compile_for_v5e(one_chip, M, K, N, G, tiles):
 
     def fwd_and_grads(x, w, gs, dy):
         out, vjp = jax.vjp(lambda x, w: gm.grouped_matmul(
-            x, w, gs, interpret=False), x, w)
+            x, w, gs, interpret=False, rows_past_groups=rows_past_groups),
+            x, w)
         return out, vjp(dy)
 
+    reg = telemetry.registry()
+    names = ("pallas.gmm.grid.live",
+             "pallas.gmm.rows_past_groups." + rows_past_groups)
+    before = [reg.counter(n).value for n in names]
     with jax.default_matmul_precision(
             "highest" if dtype == jnp.float32 else "default"):
         text = jax.jit(fwd_and_grads).trace(x, w, gs, dy).lower(
             lowering_platforms=("tpu",)).compile().as_text()
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
                           text)) == 3
+    assert [reg.counter(n).value - b for n, b in zip(names, before)] == \
+        [3, 2]
 
 
 @pytest.mark.parametrize("B,T,D,K,dtype,tiles", [
